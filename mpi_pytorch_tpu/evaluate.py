@@ -127,8 +127,9 @@ def evaluate(cfg: Config) -> EvalSummary:
     apply_runtime_flags(cfg)
     logger = init_logger("MPT_EVAL", cfg.eval_log_file)
     tracer = Tracer(cfg.trace_file)
-    # finally-close: a failed evaluation (bad checkpoint, OOM, relay wedge)
-    # is exactly the run whose trace is needed — the buffered spans must
+    writer = MetricsWriter(cfg.metrics_file)
+    # finally-close: a failed evaluation (bad checkpoint, OOM) is exactly
+    # the run whose trace is needed — the buffered spans and records must
     # reach disk on the failure path too.
     try:
         with tracer.span("build"):
@@ -167,7 +168,8 @@ def evaluate(cfg: Config) -> EvalSummary:
             # One pass produces both the metrics and the submission CSV.
             with tracer.span("eval", args={"pass": "predictions"}):
                 acc, mean_loss = evaluate_with_predictions(
-                    cfg, state, mesh, manifests[0], test_manifest, logger
+                    cfg, state, mesh, manifests[0], test_manifest, logger,
+                    writer,
                 )
         else:
             if cfg.fused_head_eval:
@@ -182,18 +184,17 @@ def evaluate(cfg: Config) -> EvalSummary:
             with tracer.span("eval", args={"pass": "metrics"}):
                 acc, mean_loss = evaluate_manifest(cfg, state, mesh, test_manifest)
         wall = time.perf_counter() - t0
+        n = len(test_manifest)
+        # ≙ rank-0 final accuracy log (evaluation_pipeline.py:198-199)
+        logger.info("Accuracy of the network: %.4f (%d images, %.2f s)", acc, n, wall)
+        writer.write(
+            {"kind": "eval", "accuracy": acc, "loss": mean_loss, "images": n, "time_s": wall}
+        )
     finally:
+        writer.close()
         trace_out = tracer.close()
         if trace_out:
             logger.info("host trace spans written to %s (chrome://tracing)", trace_out)
-    n = len(test_manifest)
-    # ≙ rank-0 final accuracy log (evaluation_pipeline.py:198-199)
-    logger.info("Accuracy of the network: %.4f (%d images, %.2f s)", acc, n, wall)
-    writer = MetricsWriter(cfg.metrics_file)
-    writer.write(
-        {"kind": "eval", "accuracy": acc, "loss": mean_loss, "images": n, "time_s": wall}
-    )
-    writer.close()
     return EvalSummary(
         accuracy=acc,
         mean_loss=mean_loss,
@@ -367,6 +368,10 @@ def _make_predict_step_impl(
                 # No int8-kept Dense head (conv classifiers): everything
                 # was dequantized by the apply wrapper and ``out`` is the
                 # real (weight-quantized) logits.
+                _warn_fused_head_fallback(
+                    "the model has no int8-kept Dense 'head' for the int8 "
+                    "kernel to replace; the logits are materialized"
+                )
                 return _plain_from_logits(out, labels, images.shape[0])
             assert out.shape == box["feats"].shape[:-1] + (box["w"].shape[1],), (
                 "intercepted 'head' output shape does not match the model "
@@ -393,7 +398,11 @@ def _make_predict_step_impl(
         if "feats" not in box:
             # Head never matched (e.g. squeezenet's Conv classifier, which
             # is also not the final op): ``out`` is then the model's REAL
-            # logits — take the plain path instead of failing.
+            # logits — take the plain path, and say so.
+            _warn_fused_head_fallback(
+                "the model has no Dense layer named 'head' for the kernel "
+                "to replace; the [B, num_classes] logits are materialized"
+            )
             return _plain_from_logits(out, labels, images.shape[0])
         # The interceptor's dummy return must BE the model output — if an
         # architecture ever routes more layers after its 'head' Dense, the
@@ -435,7 +444,8 @@ def _host_rows(p, host_batch: int):
 
 
 def evaluate_with_predictions(
-    cfg: Config, state, mesh, train_manifest, test_manifest, logger
+    cfg: Config, state, mesh, train_manifest, test_manifest, logger,
+    metrics,
 ) -> tuple[float, float]:
     """One pass over the test manifest: accuracy/loss AND a predictions CSV
     (file_name, predicted_label, predicted_category_id) in manifest order —
@@ -479,12 +489,26 @@ def evaluate_with_predictions(
             "materialized"
         )
     predict = _make_predict_step(mesh, compute_dtype, fused_head=fused_head)
+    compiled = None
     preds: list = []
     loss_sum = correct = count = 0.0
     n_steps = global_step_count(len(test_manifest), host_batch, drop_remainder=False)
     for images, labels in synchronized_batches(loader, 0, n_steps):
         batch = shard_batch(pad_batch(images, labels, host_batch), mesh)
-        m, p = predict(state, batch)
+        if compiled is None:
+            # Every batch is padded to one static shape: compile it once,
+            # ahead of time, and record what the executable carries
+            # (kind="compile": Mosaic calls, input placement).
+            from mpi_pytorch_tpu.utils.hardware import compile_record
+
+            t_compile = time.perf_counter()
+            compiled = predict.lower(state, batch).compile()
+            metrics.write(
+                compile_record(
+                    "predict", compiled, time.perf_counter() - t_compile
+                )
+            )
+        m, p = compiled(state, batch)
         # Global batch rows [pid*hb, (pid+1)*hb) are THIS host's images
         # (shard_batch assembles the global array host-major), and the
         # P(data)-pinned argmax keeps them on this host's devices.

@@ -98,9 +98,14 @@ class FusedStemBNReluPool(nn.Module):
                 )
         a = scale.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps)
         b = bias.astype(jnp.float32) - mean * a
+        # flax init traces ONE dummy image for shapes: nothing to split over
+        # the data axis (found on four chips, PR 21 — a batch of 1 does not
+        # divide 4, which the kernel refuses on a TPU), so init runs the
+        # single un-partitioned call.
+        dp_mesh = None if self.is_initializing() else self.dp_mesh
         # Output in the module's compute dtype, matching what the unfused
         # batch_norm(dtype=...) -> relu -> pool composition produces.
-        return stem_affine_relu_pool(y, a, b, dp_mesh=self.dp_mesh).astype(self.dtype)
+        return stem_affine_relu_pool(y, a, b, dp_mesh=dp_mesh).astype(self.dtype)
 
 
 def max_pool(x: jnp.ndarray, window: int, stride: int, padding: Any = "VALID") -> jnp.ndarray:

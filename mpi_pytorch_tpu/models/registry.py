@@ -133,7 +133,7 @@ def fused_stem_default(model_name: str) -> bool:
     return (
         model_name in MEASURED_FUSED_STEM_MODELS
         and env_flag("MPT_FUSED_STEM", default=True)
-        and jax.devices()[0].platform == "tpu"
+        and jax.default_backend() == "tpu"
     )
 
 

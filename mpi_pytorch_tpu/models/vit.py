@@ -137,7 +137,12 @@ class MultiHeadAttention(nn.Module):
             if self.attn_impl == "flash":
                 out = flash_attention(q, k, v)
             elif self.attn_impl == "fused-small":
-                out = fused_attention_small(q, k, v, dp_mesh=self.dp_mesh)
+                # init traces one dummy image: nothing to split over the
+                # data axis (see models/common.FusedStemBNReluPool).
+                out = fused_attention_small(
+                    q, k, v,
+                    dp_mesh=None if self.is_initializing() else self.dp_mesh,
+                )
             elif self.attn_impl == "full":
                 out = full_attention(q, k, v)
             else:

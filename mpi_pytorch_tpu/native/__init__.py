@@ -9,7 +9,10 @@ decode scales with cores instead of fighting the interpreter lock.
 
 Build-on-demand: the shared library is compiled with g++ the first time it's
 needed and cached next to the source (falling back to a per-user cache dir if
-the package is read-only). Every entry point degrades gracefully: if the
+the package is read-only), under a name that carries a hash of ``decode.cpp``
+— a binary built from any other source (a stale one that travelled with a
+copied tree, whose mtimes a copy does not preserve) has another name and can
+never load. Every entry point degrades gracefully: if the
 toolchain, libjpeg, or the build is unavailable, ``load()`` returns ``None``
 and callers keep using the pure-PIL path; if an individual file fails to
 decode (corrupt, non-JPEG, CMYK), only that item falls back to PIL.
@@ -18,6 +21,8 @@ decode (corrupt, non-JPEG, CMYK), only that item falls back to PIL.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -27,7 +32,7 @@ from typing import Sequence
 import numpy as np
 
 _SRC = os.path.join(os.path.dirname(__file__), "decode.cpp")
-_LIB_NAME = "_mptnative.so"
+_LIB_PREFIX = "_mptnative_"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -39,11 +44,17 @@ _build_error: str | None = None
 # distinguishes zero from nonzero and routes failures to the PIL fallback.
 
 
-def _candidate_paths() -> list[str]:
+def _lib_name() -> str:
+    """``_mptnative_<sha256 of decode.cpp, 12 hex>.so``."""
+    with open(_SRC, "rb") as f:
+        return f"{_LIB_PREFIX}{hashlib.sha256(f.read()).hexdigest()[:12]}.so"
+
+
+def _candidate_paths(lib_name: str) -> list[str]:
     cache = os.path.join(
         os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")), "mpi_pytorch_tpu"
     )
-    return [os.path.join(os.path.dirname(__file__), _LIB_NAME), os.path.join(cache, _LIB_NAME)]
+    return [os.path.join(os.path.dirname(__file__), lib_name), os.path.join(cache, lib_name)]
 
 
 def _build(out_path: str) -> None:
@@ -60,6 +71,13 @@ def _build(out_path: str) -> None:
         if os.path.exists(tmp):
             os.unlink(tmp)
         raise
+    # Builds of older sources can never load again: drop them.
+    for stale in glob.glob(os.path.join(os.path.dirname(out_path), "_mptnative*.so")):
+        if stale != out_path:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
 
 
 def _abi_version(lib: ctypes.CDLL) -> int:
@@ -75,19 +93,19 @@ def _abi_version(lib: ctypes.CDLL) -> int:
 def _try_load() -> ctypes.CDLL | None:
     global _build_error
     try:
-        src_mtime = os.path.getmtime(_SRC)
+        lib_name = _lib_name()
     except OSError as e:  # source not shipped (trimmed install): PIL path
         _build_error = f"native source unavailable: {e}"
         return None
     last_err: str | None = None
-    for path in _candidate_paths():
+    for path in _candidate_paths(lib_name):
         # Two attempts per candidate: a cached library that loads but has the
         # wrong ABI is deleted and rebuilt once, not skipped (a skip would
         # silently run the whole job on the slower PIL path).
         lib = None
         for _ in range(2):
             try:
-                if not os.path.exists(path) or os.path.getmtime(path) < src_mtime:
+                if not os.path.exists(path):
                     _build(path)
                 lib = ctypes.CDLL(path)
             except (OSError, subprocess.SubprocessError) as e:

@@ -73,18 +73,16 @@ def compile_count() -> int:
 
 
 def device_bytes_in_use() -> int | None:
-    """Live HBM bytes on this process's first device, or None where the
-    backend has no ``memory_stats`` (CPU) — the record carries null rather
-    than a confident fake zero."""
-    try:
-        import jax
+    """Live HBM bytes on this process's FULLEST local device (the one that
+    runs out first — not just device 0, which says nothing about its
+    siblings), or None where the backend has no ``memory_stats`` (CPU) —
+    the record carries null rather than a confident fake zero."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        if not stats:
-            return None
-        return int(stats.get("bytes_in_use"))
-    except Exception:
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    if not all(stats):
         return None
+    return max(int(s["bytes_in_use"]) for s in stats)
 
 
 class NonFiniteLossError(RuntimeError):

@@ -1,8 +1,8 @@
 """Declarative SLO rules over the live metrics registry (ISSUE 8).
 
-The bench history argues for IN-RUN detection: rounds r02/r05 died on
-wedged backends discovered post-hoc, and a serve p99 regression today is
-only visible after ``report_run.py`` renders the stream. The monitor
+Failures want IN-RUN detection: a wedged backend discovered post-hoc has
+already cost the run, and a serve p99 regression is otherwise only visible
+after ``report_run.py`` renders the stream. The monitor
 closes that loop: rules are evaluated against ``MetricsRegistry``
 snapshots on the driver's own cadence (per step in the trainer, per flush
 in the serve completion loop — no extra thread, no extra sync), and a

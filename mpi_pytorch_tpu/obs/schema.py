@@ -32,6 +32,7 @@ Record kinds (every record also carries ``ts``, the epoch-seconds stamp
 | timeline  | host, metric, points                                | window_s, clock_offset_ms, resets |
 | hedge     | winner, loser                                       | cancelled, deadline_ms, trace_id |
 | canary    | model, event                                        | agreement_top1, agreement_topk, rank_drift, probes, verdict, mutation, reason, detail |
+| compile   | executable, seconds, mosaic_calls, devices, sharded_inputs |   |
 
 ``serve`` is the per-flush record the online inference server writes
 (serve/server.py: one coalesced batch dispatched to a bucket executable);
@@ -220,7 +221,15 @@ from typing import Any, Mapping
 #      ``pipe_stages`` + ``interstage_bytes``. Traced pipe requests gain
 #      per-stage ``serve/stage{i}`` child spans under ``serve/device``.
 #      All absent off the pipe path — streams stay byte-identical to v15.
-SCHEMA_VERSION = 16
+# v17: the ``compile`` kind (ISSUE 21) — one record per AOT-compiled driver
+#      executable (the trainer's step, the evaluator's predict step;
+#      ``utils/hardware.compile_record``): compile wall seconds, how many
+#      Mosaic custom calls the optimized HLO carries, which local devices
+#      every input has an addressable shard on, and how many inputs are
+#      split rather than replicated. ``chip_smoke.py`` reads it to prove a
+#      requested Pallas kernel is in the program that ran and that every
+#      chip holds its shard.
+SCHEMA_VERSION = 17
 
 _NUM = (int, float)
 _INT = (int,)
@@ -278,6 +287,11 @@ REQUIRED: dict[str, dict[str, tuple]] = {
     # v15: one golden-set canary event per tenant (obs/canary.py):
     # references pinned, a probe cycle scored, or a mutation blocked.
     "canary": {"model": (str,), "event": (str,)},
+    # v17: one AOT-compiled driver executable (utils/hardware.compile_record).
+    "compile": {
+        "executable": (str,), "seconds": _NUM, "mosaic_calls": _INT,
+        "devices": (list,), "sharded_inputs": _INT,
+    },
 }
 
 OPTIONAL: dict[str, dict[str, tuple]] = {
@@ -526,6 +540,7 @@ OPTIONAL: dict[str, dict[str, tuple]] = {
         "probes": _INT, "verdict": (str,), "mutation": (str,),
         "reason": (str,), "detail": (str,),
     },
+    "compile": {},
 }
 
 

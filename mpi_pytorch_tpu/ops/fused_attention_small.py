@@ -57,9 +57,9 @@ reference this kernel is pinned against in
 tests/test_fused_attention_small.py via interpret mode), mirroring
 ``ops/flash_attention.py``'s gating; ``MPT_ATTN_INTERPRET=1`` drives the
 real kernel through the Pallas interpreter on CPU (how the tests run it).
-Sequences outside the tiny-S envelope (S > 128, or Dh > 128) also take
-``full_attention`` — this kernel's domain is exactly the regime where
-flash was measured to lose.
+Sequences outside the tiny-S envelope (S > 128, or Dh > 128) raise on a
+TPU and take ``full_attention`` elsewhere — this kernel's domain is
+exactly the regime where flash was measured to lose.
 
 Multi-chip: pass ``dp_mesh`` (the training/eval mesh) and the public
 wrapper ``shard_map``s the kernel over the mesh's leading (data) axis —
@@ -282,7 +282,7 @@ def fused_attention_small(
     Domain: S ≤ 128, head dim ≤ 128 — the regime where the flash kernel's
     block machinery was measured to LOSE to plain XLA (docs/RESULTS.md §4,
     round 3) and the [B, H, S, S] softmax chain is the byte cost. Outside
-    the envelope the call degrades to ``full_attention`` (identical math).
+    the envelope the call raises on a TPU (``full_attention`` elsewhere).
 
     ``bh_block``: (batch·head) pairs fused per grid step (None = auto /
     ``MPT_ATTN_BH_BLOCK`` — see module docstring, bh-grouping).
@@ -312,8 +312,15 @@ def fused_attention_small(
             n_data = dp_mesh.shape[axis]
     if s > MAX_SEQ or d > MAX_HEAD_DIM or (n_data > 1 and b % n_data):
         # Outside the tiny-S envelope (flash/full own that regime), or a
-        # batch that does not tile the data axis (replicating the Mosaic
-        # call would be strictly worse than XLA's partitioned path).
+        # batch that does not tile the data axis. On a TPU the caller asked
+        # for this kernel and gets it or an error naming the shape.
+        if tpu_backend():
+            raise ValueError(
+                f"fused-small attention: q {q.shape} is outside the kernel's "
+                f"domain (S <= {MAX_SEQ}, head dim <= {MAX_HEAD_DIM}, batch "
+                f"divisible by the {n_data}-device data axis); use "
+                "--attn-impl full or flash for this shape"
+            )
         return full_attention(q, k, v, causal=causal)
     if interpret is None:
         if env_flag("MPT_ATTN_INTERPRET"):

@@ -63,9 +63,25 @@ _BLOCK_V_BWD = 1024
 # [rows, _BLOCK_V] f32 logits block exceeds scoped VMEM (measured at 4096).
 # Larger batches are ROW-TILED: the wrapper runs a (row-block, vocab-block)
 # grid with ≤ this many rows resident per step, so B=4096+ streams through
-# the kernel instead of compile-rejecting (it falls back to the XLA
-# reference only when the batch has no usable row tiling).
+# the kernel instead of compile-rejecting (a batch with no usable row
+# tiling raises on a TPU; off-TPU the XLA reference is the path anyway).
 PREDICT_MAX_ROWS = 1024
+# Scoped VMEM for the predict kernels. A full 1 024-row block needs 17.2 MiB
+# on the row-tiled grid — Mosaic (libtpu 0.0.34, v5e) refused rows=4096
+# under the 16 MiB default, 1.21 MiB short — so the call asks for headroom
+# instead of shrinking the block (v5e has 128 MiB physical VMEM).
+PREDICT_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def predict_compiler_params(interpret: bool):
+    """``compiler_params`` for the predict kernels' ``pallas_call``s (bf16
+    here, int8 in ops/quantize.py): the VMEM headroom above, nothing under
+    the interpreter."""
+    if interpret:
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=PREDICT_VMEM_LIMIT)
 
 
 def _fwd_kernel(labels_ref, feats_ref, w_ref, b_ref, loss_ref, m_ref, l_ref, picked_ref):
@@ -335,23 +351,15 @@ def _predict_kernel(
     )
 
 
-# One warning per (process, reason): a TPU caller asking for the fused
-# predictions kernel but landing on the XLA reference must be told
-# (advisor r5 — the gates here used to be silent). The non-TPU branch stays
-# quiet: CPU is the reference path by design, not a degradation.
-_predict_fallback_warned: set[str] = set()
+def _no_tiling(kernel: str, reason: str) -> None:
+    """A shape the predict kernels cannot tile. On a TPU the caller asked
+    for the kernel and must not quietly get the XLA reference (logits
+    materialized) instead — raise and name the shape; elsewhere the
+    reference is the path anyway and the caller falls through to it."""
+    from mpi_pytorch_tpu.utils.hardware import tpu_backend
 
-
-def _warn_predict_fallback(reason: str) -> None:
-    if reason in _predict_fallback_warned:
-        return
-    _predict_fallback_warned.add(reason)
-    from mpi_pytorch_tpu.utils.logging import run_logger
-
-    run_logger().warning(
-        "head_predict falling back to the XLA reference (logits "
-        "materialized): %s", reason,
-    )
+    if tpu_backend():
+        raise ValueError(f"{kernel}: {reason}")
 
 
 def head_predict_reference(feats, w, b, labels):
@@ -364,7 +372,7 @@ def head_predict_reference(feats, w, b, labels):
 def _predict_row_block(rows: int) -> int | None:
     """Rows resident per grid step: the whole batch when it fits the
     measured per-block envelope, else the largest power-of-two divisor
-    ≤ PREDICT_MAX_ROWS (None = no usable tiling → XLA fallback)."""
+    ≤ PREDICT_MAX_ROWS (None = no usable tiling)."""
     if rows <= PREDICT_MAX_ROWS:
         return rows
     for rb in (1024, 512, 256, 128, 64, 32, 16, 8):
@@ -391,6 +399,7 @@ def _predict_call(labels, feats, wp, bp, *, block_r: int, interpret: bool):
         out_specs=[row_spec] * 6,
         out_shape=[jax.ShapeDtypeStruct((bsz, 1), jnp.float32)] * 6,
         interpret=interpret,
+        compiler_params=predict_compiler_params(interpret),
     )(labels.reshape(bsz, 1), feats, wp, bp.reshape(1, -1))
     return loss[:, 0], pred[:, 0].astype(jnp.int32)
 
@@ -451,15 +460,17 @@ def head_predict(
             n_data = dp_mesh.shape[dp_mesh.axis_names[0]]
     rows = feats.shape[0]
     if rows % n_data:
-        _warn_predict_fallback(
-            f"batch rows {rows} not divisible by the data axis ({n_data})"
+        _no_tiling(
+            "head_predict",
+            f"batch rows {rows} not divisible by the data axis ({n_data})",
         )
         return head_predict_reference(feats, w, b, labels)
     block_r = _predict_row_block(rows // n_data)
     if block_r is None:
-        _warn_predict_fallback(
+        _no_tiling(
+            "head_predict",
             f"no power-of-two row tiling divides {rows // n_data} per-shard "
-            f"rows within the {PREDICT_MAX_ROWS}-row VMEM envelope"
+            f"rows within the {PREDICT_MAX_ROWS}-row VMEM envelope",
         )
         return head_predict_reference(feats, w, b, labels)
     labels = labels.astype(jnp.int32)

@@ -101,7 +101,10 @@ env gates, each microbenched by ``tools/bench_stem.py --levers`` and
 recorded as a ship-or-rejection row in §4d —
 
 - ``MPT_STEM_BF16_POOL=1``  — pooling compares/phases in bf16 (halves the
-  in-VMEM f32 working set; the affine stays f32);
+  in-VMEM f32 working set; the affine stays f32). REFUSED by Mosaic on
+  v5e — "Target does not support this comparison": the chip has no bf16
+  vector compare — so on this chip the lever is a compile error, never a
+  silent f32 fallback (PR 21);
 - ``MPT_STEM_LANES=256``    — 256-image batch block (two full vregs per op);
 - ``MPT_STEM_IDX_INT8=1``   — int8 window-argmax storage (k ∈ [0, 8] needs
   4 bits; halves the idx tensor's HBM traffic vs bf16);
@@ -149,8 +152,11 @@ def _levers() -> dict:
 
 # Mosaic's stack allocation for the fold's temporaries exceeds the 16 MB
 # default scoped-vmem budget at useful block sizes; v5e has 128 MB
-# physical VMEM, so grant headroom instead of shrinking blocks.
-_VMEM_LIMIT = 100 * 1024 * 1024
+# physical VMEM, so grant headroom instead of shrinking blocks. 120 MiB:
+# the two levers that double the tile (MPT_STEM_LANES=256,
+# MPT_STEM_C_BLOCK=16) need 111.8 MiB and were refused under a 100 MiB
+# limit (Mosaic, libtpu 0.0.34, v5e — PR 21).
+_VMEM_LIMIT = 120 * 1024 * 1024
 
 
 def _tpu_params():
@@ -472,8 +478,9 @@ def stem_affine_relu_pool(y, a, b, *, interpret: bool | None = None, dp_mesh=Non
     >1 device, the kernel call is ``shard_map``-partitioned over that axis
     — each device runs the Mosaic call on its batch shard (see module
     docstring, Multi-chip). The batch must divide the axis (the trainer
-    validates this; indivisible batches fall back to the XLA composition
-    rather than silently replicating the call). If the axis is ALREADY
+    validates this; on a TPU an indivisible batch raises rather than
+    silently replicating the call or becoming the XLA composition). If the
+    axis is ALREADY
     bound (calling from inside the spmd-mode step's shard_map), the
     per-shard call runs directly — no nesting."""
     from mpi_pytorch_tpu.utils.hardware import tpu_backend
@@ -491,8 +498,16 @@ def stem_affine_relu_pool(y, a, b, *, interpret: bool | None = None, dp_mesh=Non
             n_data = dp_mesh.shape[axis]
     if y.shape[-1] % _levers()["c_block"] or (n_data > 1 and y.shape[0] % n_data):
         # Channel count must tile the sublane block (every 7×7 stem in the
-        # zoo has C=64) and the batch must tile the data axis. Anything
-        # else takes the XLA path.
+        # zoo has C=64) and the batch must tile the data axis. On a TPU the
+        # caller asked for the kernel and gets it or an error; elsewhere
+        # the XLA composition is the path anyway.
+        if tpu_backend():
+            raise ValueError(
+                f"fused stem: input {y.shape} does not tile the kernel "
+                f"(channels must divide by {_levers()['c_block']}, batch by "
+                f"the {n_data}-device data axis); pass --fused-stem false "
+                "for this shape"
+            )
         return _reference_impl(y, a, b)
     if interpret is None:
         from mpi_pytorch_tpu.utils.env import env_flag

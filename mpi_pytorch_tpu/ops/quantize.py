@@ -60,7 +60,9 @@ from jax.experimental import pallas as pl
 
 from mpi_pytorch_tpu.ops.fused_head_ce import (
     _BLOCK_V,
+    _no_tiling,
     _predict_row_block,
+    predict_compiler_params,
     online_predict_update,
 )
 
@@ -318,21 +320,6 @@ def _pad_int8(w_q, b, scale, block: int):
     return w_q, b, scale, v
 
 
-_int8_fallback_warned: set[str] = set()
-
-
-def _warn_int8_fallback(reason: str) -> None:
-    if reason in _int8_fallback_warned:
-        return
-    _int8_fallback_warned.add(reason)
-    from mpi_pytorch_tpu.utils.logging import run_logger
-
-    run_logger().warning(
-        "head_predict_int8 falling back to the XLA int8 reference (logits "
-        "materialized): %s", reason,
-    )
-
-
 def head_predict_int8_reference(feats, w_q, b, labels, w_scale, act_scale):
     """Plain-XLA int8 reference/fallback: the exact integer matmul the
     kernel computes (int32 accumulate), explicit logits, CE + argmax.
@@ -374,6 +361,7 @@ def _predict_int8_call(labels, feats_q, wp, sp, bp, *, block_r: int, interpret: 
         out_specs=[row_spec] * 6,
         out_shape=[jax.ShapeDtypeStruct((bsz, 1), jnp.float32)] * 6,
         interpret=interpret,
+        compiler_params=predict_compiler_params(interpret),
     )(labels.reshape(bsz, 1), feats_q, wp, sp.reshape(1, -1), bp.reshape(1, -1))
     return loss[:, 0], pred[:, 0].astype(jnp.int32)
 
@@ -422,17 +410,19 @@ def head_predict_int8(
             n_data = dp_mesh.shape[dp_mesh.axis_names[0]]
     rows = feats.shape[0]
     if rows % n_data:
-        _warn_int8_fallback(
-            f"batch rows {rows} not divisible by the data axis ({n_data})"
+        _no_tiling(
+            "head_predict_int8",
+            f"batch rows {rows} not divisible by the data axis ({n_data})",
         )
         return head_predict_int8_reference(
             feats, w_q, b, labels, w_scale, act_scale
         )
     block_r = _predict_row_block(rows // n_data)
     if block_r is None:
-        _warn_int8_fallback(
+        _no_tiling(
+            "head_predict_int8",
             f"no power-of-two row tiling divides {rows // n_data} per-shard "
-            "rows within the VMEM envelope"
+            "rows within the VMEM envelope",
         )
         return head_predict_int8_reference(
             feats, w_q, b, labels, w_scale, act_scale

@@ -1,71 +1,32 @@
-"""JAX version-skew shims — the ONE place the codebase touches moving APIs.
-
-``shard_map`` has lived in three places across supported JAX versions:
-
-- ``jax.experimental.shard_map.shard_map`` (≤ 0.4.x / 0.5.x), keyword
-  ``check_rep``;
-- ``jax.shard_map`` (0.6+), where ``check_rep`` was renamed ``check_vma``
-  (the varying-manual-axes generalization of the replication check).
-
-Every in-repo consumer (train/step.py, parallel/pipeline.py,
-ops/ring_attention.py, ops/moe.py, ops/fused_stem.py, evaluate.py, tests)
-imports from HERE and writes the modern spelling (``check_vma=``); this
-wrapper translates to whatever the installed JAX accepts. A version skew
-therefore surfaces as one failed import of this module
-(tests/test_imports.py names it), not as eight opaque test-collection
-errors.
+"""The ONE place the codebase touches JAX APIs that are not public and
+stable: ``shard_map`` is re-exported from here (callers write the public
+``jax.shard_map`` signature, ``check_vma=`` included), ``axis_is_manual``
+wraps the private axis-env lookup, and ``process_index`` the distributed
+client's state. A JAX upgrade that moves any of them surfaces as one failed
+import of this module
+(tests/test_imports.py names it), not as a scatter of collection errors.
 """
 
 from __future__ import annotations
 
-import inspect
-
-try:  # jax >= 0.6: public top-level API
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax <= 0.5: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg this JAX spells: 'check_vma' (new) or
-# 'check_rep' (old). Probed once at import.
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, **kwargs):
-    """``shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)``
-    with the replication-check kwarg translated for the installed JAX.
-    ``check_rep`` is accepted as a synonym so older call sites keep working."""
-    flag = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-    if flag is not None:
-        kwargs[_CHECK_KW] = flag
-    return _shard_map(f, **kwargs)
+from jax import shard_map  # noqa: F401  (re-export)
+from jax._src import core as _core
+from jax._src import distributed as _distributed
 
 
 def axis_is_manual(name: str) -> bool:
     """True when ``name`` is already a BOUND mesh axis in the current trace
-    context — i.e. this code is executing inside a shard_map/pmap over that
-    axis (e.g. the spmd-mode train step). Self-partitioning ops
+    context — i.e. this code is executing inside a shard_map over that axis
+    (e.g. the spmd-mode train step). Self-partitioning ops
     (ops/fused_stem.py, ops/fused_head_ce.py) use this to skip their own
     shard_map wrap: nesting over the same axis is an error, and inside the
-    outer map they already see per-shard operands. Axis-env introspection
-    is a moving private API, hence it lives HERE with the version shims."""
-    try:  # jax 0.4/0.5 spelling
-        from jax._src import core as _core
+    outer map they already see per-shard operands."""
+    return name in _core.get_axis_env().axis_sizes
 
-        # Only a positive hit is trusted — an axis env that exists but
-        # doesn't track shard_map manual axes must fall through to the
-        # axis_index probe, not report "unbound".
-        if name in _core.get_axis_env().axis_sizes:
-            return True
-    except Exception:
-        pass
-    try:  # fallback: axis_index resolves only under a bound axis
-        from jax import lax
 
-        lax.axis_index(name)
-        return True
-    except Exception:
-        return False
+def process_index() -> int:
+    """``jax.process_index()`` without its side effect. The public call
+    starts the default backend — on a TPU host that takes every chip — just
+    to read a number ``jax.distributed.initialize`` already stored (0 when
+    it was never called, i.e. single-process)."""
+    return _distributed.global_state.process_id

@@ -39,9 +39,9 @@ def create_mesh(cfg: MeshConfig, devices: list | None = None) -> Mesh:
     from mpi_pytorch_tpu.utils.env import fault_countdown
 
     if fault_countdown("MPT_FAULT_BACKEND_WEDGE_N"):
-        # The wedged-backend-init scenario (bench history: rounds r02/r05,
-        # rc=3): deterministic, in-process, absorbed by the resume-side
-        # retry loop (train/elastic.with_retries).
+        # The wedged-backend-init scenario: deterministic, in-process,
+        # absorbed by the resume-side retry loop
+        # (train/elastic.with_retries).
         raise RuntimeError(
             "injected fault: backend init wedged (MPT_FAULT_BACKEND_WEDGE_N)"
         )

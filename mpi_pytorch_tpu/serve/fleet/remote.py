@@ -167,6 +167,9 @@ class RemoteHost:
         self._facts_ttl_s = float(facts_ttl_s)
         self._rng = random.Random(seed)
         self._closed = False
+        # Local chip count the host process reported at readiness
+        # (RemoteFleet._spawn fills it; None when unknown).
+        self.chips: int | None = None
         # Keep-alive connection pool (ISSUE 16 satellite): the server
         # side has always spoken HTTP/1.1 with Content-Length, so the
         # only reason every call paid a TCP handshake was the client's
@@ -971,6 +974,68 @@ def child_host_args(cfg, index: int, port_file: str,
     return args
 
 
+class NoFreeChipError(ServeError):
+    """A serving-host process was asked for on a TPU host whose chips are
+    all taken by its siblings. A chip belongs to one process at a time; a
+    child started without one of its own would fail or hang at backend
+    init."""
+
+
+class _ChipSlots:
+    """One TPU chip per serving-host child, handed out through the child's
+    environment (libtpu's ``TPU_VISIBLE_CHIPS`` and process-bounds
+    variables — shown on the four-chip v5e host in PR 21: four concurrent
+    children each came up on their own single device). Without this every
+    child inherits the parent's environment, asks for every chip, and the
+    first wins.
+
+    A child keeps its slot across supervisor restarts (same index, same
+    chip); ``release`` returns it when the autoscaler retires the host.
+    On a machine with no TPU, or with the children pinned to the CPU
+    (``JAX_PLATFORMS=cpu``), there is nothing to hand out and ``env`` is
+    empty."""
+
+    def __init__(self, child_env: dict):
+        from mpi_pytorch_tpu.utils.hardware import local_tpu_chips
+
+        on_cpu = (
+            child_env.get("JAX_PLATFORMS", "").split(",")[0].strip().lower()
+            == "cpu"
+        )
+        self.chips = 0 if on_cpu else local_tpu_chips()
+        self._slot_of: dict[int, int] = {}
+        self._lock = threading.Lock()  # _spawn runs on a thread pool
+
+    def env(self, index: int) -> dict:
+        if not self.chips:
+            return {}
+        with self._lock:
+            if index not in self._slot_of:
+                free = sorted(
+                    set(range(self.chips)) - set(self._slot_of.values())
+                )
+                if not free:
+                    raise NoFreeChipError(
+                        f"remote fleet: no free TPU chip for host {index} — "
+                        f"this machine's {self.chips} chip(s) each belong "
+                        f"to one of hosts {sorted(self._slot_of)}"
+                    )
+                self._slot_of[index] = free[0]
+            slot = self._slot_of[index]
+        port = 8476 + slot  # libtpu's default controller port, one per chip
+        return {
+            "TPU_VISIBLE_CHIPS": str(slot),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+            "TPU_MESH_CONTROLLER_PORT": str(port),
+        }
+
+    def release(self, index: int) -> None:
+        with self._lock:
+            self._slot_of.pop(index, None)
+
+
 class RemoteFleet:
     """N ``serve.host`` subprocesses (+ optional warm spare) behind the
     transport-agnostic ``FleetRouter`` — one handle, same surface as the
@@ -1034,17 +1099,16 @@ class RemoteFleet:
         total = n + (1 if want_spare else 0)
         indices = list(range(total))
         self._next_index = total
+        self._chip_slots = _ChipSlots(self._env)
         spawned: dict[int, tuple] = {}
         try:
-            # Warm-start ordering: with a persistent compilation cache the
-            # FIRST host pays the cold compiles and populates the cache;
-            # every later spawn (including failover restarts and scale-ups)
-            # warms from it in parallel.
-            if cfg.compilation_cache_dir and total > 1:
-                spawned[indices[0]] = self._spawn(indices[0])
-                rest = indices[1:]
-            else:
-                rest = indices
+            # Warm-start ordering: the FIRST host pays the cold compiles
+            # and populates the persistent compilation cache the children
+            # share (config.enable_compilation_cache); every later spawn
+            # (including failover restarts and scale-ups) warms from it in
+            # parallel.
+            spawned[indices[0]] = self._spawn(indices[0])
+            rest = indices[1:]
             if rest:
                 with ThreadPoolExecutor(max_workers=len(rest)) as pool:
                     futs = {i: pool.submit(self._spawn, i) for i in rest}
@@ -1158,8 +1222,8 @@ class RemoteFleet:
         log_fh = open(log_path, "ab")
         try:
             proc = subprocess.Popen(
-                argv, env=self._env, cwd=self._repo,
-                stdout=log_fh, stderr=subprocess.STDOUT,
+                argv, env={**self._env, **self._chip_slots.env(index)},
+                cwd=self._repo, stdout=log_fh, stderr=subprocess.STDOUT,
             )
         finally:
             log_fh.close()
@@ -1187,6 +1251,7 @@ class RemoteFleet:
                 host = RemoteHost(
                     f"http://127.0.0.1:{ready['port']}", **kwargs,
                 )
+            host.chips = ready.get("chips")
         except BaseException:
             _terminate(proc)
             tail = ""
@@ -1223,6 +1288,8 @@ class RemoteFleet:
                 entry.proc.wait(timeout=60)
             except subprocess.TimeoutExpired:
                 _terminate(entry.proc)
+            # Only a dead process has let go of its chip.
+            self._chip_slots.release(host.index)
 
         threading.Thread(
             target=_reap, name="fleet-scale-reap", daemon=True

@@ -460,9 +460,15 @@ def main(argv=None) -> int:
         wire=cfg.serve_transport == "framed",
         logger=logger,
     )
+    import jax
+
     payload = {
         "port": host.port, "pid": os.getpid(),
         "host_index": -1 if host_index is None else host_index,
+        # Where this host actually runs: the parent of a remote fleet
+        # stays off the device and learns the chip count from here.
+        "platform": jax.default_backend(),
+        "chips": jax.local_device_count(),
     }
     if host.wire_port is not None:
         # ISSUE 16: the framed data-plane port, for WireHost's dial

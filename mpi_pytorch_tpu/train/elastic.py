@@ -1,8 +1,7 @@
 """Elastic training: mesh-shape-change resume, preemption watchdog, and the
 in-process halves of the fault-injection harness (ISSUE 7 / ROADMAP item 4).
 
-Production fleets lose and gain chips — this repo's own bench history shows
-it (rounds r02 and r05 died on a wedged TPU backend). The reference handles
+Production fleets lose and gain chips, and backends wedge. The reference handles
 every failure the same way: a human restarts ``main.py`` with
 ``FROM_CHECKPOINT=True`` onto the SAME MPI world (``main.py:127-130``).
 This module generalizes that into a self-healing loop:

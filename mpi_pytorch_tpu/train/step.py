@@ -416,8 +416,8 @@ def make_scanned_epoch(
     HBM-resident dataset exactly like ``make_cached_train_step``.
 
     Why: with the dataset cached on device, the remaining end-to-end cost is
-    per-step Python dispatch (one host→device round-trip per step — expensive
-    through a device relay). Scanning moves the epoch loop into XLA: one
+    per-step Python dispatch (one host→device round-trip per step).
+    Scanning moves the epoch loop into XLA: one
     dispatch per EPOCH, zero host involvement between steps. This is the
     idiomatic-TPU endpoint of the reference's data-feeding problem — where
     its MPI pipeline overlapped host stages (``evaluation_pipeline.py:

@@ -18,13 +18,14 @@ from typing import Any, Mapping
 
 
 def process_index() -> int:
-    # Resolved lazily so importing this module never forces jax initialization.
-    try:
-        import jax
+    """This process's index in the multi-process world (0 single-process).
+    Imported lazily so this module stays importable without jax, and read
+    WITHOUT starting a backend: a logger or metrics writer must never be
+    what takes the chips (the parent of a remote serving fleet logs, and
+    its children need them)."""
+    from mpi_pytorch_tpu.parallel.compat import process_index as _index
 
-        return jax.process_index()
-    except Exception:
-        return 0
+    return _index()
 
 
 def init_logger(name: str = "MPT", log_file: str | None = "training.log",

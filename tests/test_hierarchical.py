@@ -501,7 +501,7 @@ def test_pod_count_change_resume_2x4_to_1x4(tmp_path):
         if "xla_force_host_platform_device_count" not in f
     ]
     env["XLA_FLAGS"] = " ".join(flags + ["--xla_force_host_platform_device_count=4"])
-    env["MPT_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.join(os.path.dirname(__file__), "..")
     subprocess.run(
         [

@@ -19,10 +19,10 @@ _MODULES = sorted(
     for info in pkgutil.walk_packages(
         mpi_pytorch_tpu.__path__, prefix="mpi_pytorch_tpu."
     )
-    # native/_mptnative.so is a plain ctypes shared library (built on
-    # demand by native/__init__.py), not a Python extension module —
+    # native/_mptnative_<hash>.so is a plain ctypes shared library (built
+    # on demand by native/__init__.py), not a Python extension module —
     # importlib would look for a PyInit symbol it deliberately lacks.
-    if not info.name.endswith("._mptnative")
+    if "._mptnative" not in info.name
 )
 
 

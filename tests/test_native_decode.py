@@ -200,3 +200,20 @@ def test_env_kill_switch():
     )
     assert out.returncode == 0, out.stderr
     assert "disabled-ok" in out.stdout
+
+
+def test_library_name_is_keyed_on_the_source_hash(tmp_path, monkeypatch):
+    """A binary built from any other decode.cpp must be unloadable BY NAME:
+    a copied tree (the chip machine's, a checkout's) does not preserve the
+    mtimes an older freshness check compared, so a stale ``.so`` that
+    travelled with it used to load."""
+    current = native._lib_name()
+    assert current.startswith("_mptnative_") and current.endswith(".so")
+    assert all(
+        os.path.basename(p) == current for p in native._candidate_paths(current)
+    )
+    edited = tmp_path / "decode.cpp"
+    with open(native._SRC, "rb") as f:
+        edited.write_bytes(f.read() + b"\n// one more line\n")
+    monkeypatch.setattr(native, "_SRC", str(edited))
+    assert native._lib_name() != current

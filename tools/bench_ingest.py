@@ -125,7 +125,7 @@ def main() -> None:
                  "--width", str(args.image_size), "--height", str(args.image_size)],
                 cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                 capture_output=True, text=True, timeout=3600,
-                env=dict(os.environ, MPT_PLATFORM="cpu"),
+                env=dict(os.environ, JAX_PLATFORMS="cpu"),
             )
             pack_ok = proc.returncode == 0
             if not pack_ok:

@@ -71,7 +71,7 @@ def make(fn):
 def timeit(f, *args, n=30):
     r = f(*args)
     jax.block_until_ready(r)
-    # value-fetch barrier (docs/RESULTS.md 4c: block_until_ready can lie here)
+    # value-fetch barrier: the fetched value is the end of the dependency chain
     t0 = time.perf_counter()
     for _ in range(n):
         r = f(*args)

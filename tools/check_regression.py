@@ -26,7 +26,7 @@ than ``--tolerance-pct`` against its predecessor:
 
 Tolerances for history that CANNOT be compared, by design:
 
-- rounds with ``rc != 0`` (the r02/r05 wedged-backend losses) are skipped;
+- rounds with ``rc != 0`` (a round whose backend never came up) are skipped;
 - ``parsed``/``value`` null (staged or failed cells) are skipped;
 - no prior round with the same metric string → no pair → pass;
 - a missing serve baseline file → empty history → pass, announced loudly
@@ -244,7 +244,7 @@ def main(argv=None) -> int:
     ap.add_argument("--root", default=REPO, help="repo root (BENCH_r*.json)")
     ap.add_argument(
         "--tolerance-pct", type=float, default=10.0,
-        help="allowed regression before failing (CPU-relay noise floor)",
+        help="allowed regression before failing (run-to-run noise floor)",
     )
     ap.add_argument(
         "--serve", default=os.path.join(REPO, "docs", "serve_bench.json")

@@ -1,10 +1,11 @@
-"""Render the chip battery's JSON artifacts as RESULTS-ready markdown.
+"""Render the bench tools' JSON artifacts as RESULTS-ready markdown.
 
-``tools/run_chip_benches.sh`` leaves docs/{bench_latest,zoo_bench,
-zoo_flash,modes_bench,attention_bench,eval_bench}.json plus the flag-sweep
-and roofline text files. This prints the markdown tables those artifacts
-support, so folding a battery into docs/RESULTS.md is one command whenever
-the relay comes back (possibly in a later session):
+The bench tools (``bench.py``, ``tools/bench_{zoo,modes,attention,eval}.py``,
+``tools/bench_flags.py``, ``tools/roofline.py``) write
+docs/{bench_latest,zoo_bench,zoo_flash,modes_bench,attention_bench,
+eval_bench}.json plus the flag-sweep and roofline text files when given
+``--out``. This prints the markdown tables those artifacts support, so
+folding a set of chip runs into docs/RESULTS.md is one command:
 
     python tools/summarize_benches.py [docs]
 """
@@ -27,10 +28,10 @@ def _load(path):
         try:
             return json.load(f)
         except json.JSONDecodeError:
-            # corrupt != absent: a relay wedge can truncate an artifact
+            # corrupt != absent: a killed run can truncate an artifact
             # mid-write, and that stage must not silently vanish.
             print(f"WARNING: {path} exists but is not valid JSON "
-                  "(truncated battery stage?)", file=sys.stderr)
+                  "(truncated by a killed run?)", file=sys.stderr)
             return None
 
 
