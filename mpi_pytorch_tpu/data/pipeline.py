@@ -14,6 +14,18 @@ jitted step never recompiles. Transform math matches the reference
 (``main.py:62-65``): ToTensor (scale to [0,1]) → Resize(H,W) → Normalize
 (ImageNet mean/std), with the grayscale fix (`.convert('RGB')`) the reference
 is missing (SURVEY §3 quirks).
+
+Where a streaming epoch's time goes is written on the run's tracer
+(``obs.trace.current()``, inert unless a driver installed one): on the
+producer thread ``loader/epoch`` (its whole life; wall time outside it is
+time with no producer alive), ``loader/decode`` per batch (args ``images``,
+``source``, ``threads``, ``fallbacks``, ``quarantined``, ``thread_busy_s``
+— the seconds the decode workers were inside a decode, from counters kept
+where the decode happens and read only while a tracer records),
+``loader/cast`` (the batch's dtype conversion, on
+that one thread) and ``loader/put`` (blocked on a full queue: the consumer is
+the slower side); on the consumer's thread ``loader/get`` (blocked on an
+empty queue: the producer is). docs/OBSERVABILITY.md "Trace spans".
 """
 
 from __future__ import annotations
@@ -29,6 +41,7 @@ import numpy as np
 
 from mpi_pytorch_tpu.config import IMAGENET_MEAN, IMAGENET_STD
 from mpi_pytorch_tpu.data.manifest import Manifest
+from mpi_pytorch_tpu.obs import trace as obs_trace
 from mpi_pytorch_tpu.utils.env import fault_countdown
 
 
@@ -164,6 +177,10 @@ class DataLoader:
         self._quarantined: set[int] = set()  # manifest row indices
         self._poisoned_decode: set[int] = set()  # MPT_FAULT_DECODE_N victims
         self._bad_lock = threading.Lock()
+        # Nanoseconds the Python decode paths (PIL pool, synthetic, the
+        # native path's per-item fallback) spent inside a decode while a
+        # tracer was recording — the counterpart of decode.cpp's busy time.
+        self._py_busy_ns = 0
         self._cur_epoch = 0
         # Decode the whole shard ONCE into host RAM (first epoch), then serve
         # every later epoch by slicing — zero decode cost after epoch 0, at
@@ -257,7 +274,19 @@ class DataLoader:
 
     def _decode_with_retries(self, i: int) -> np.ndarray | None:
         """``_load_one`` behind bounded-backoff retries; None = quarantined
-        (the caller substitutes a good row and masks the label)."""
+        (the caller substitutes a good row and masks the label). Timed on
+        the thread that runs it, while a tracer records (``_py_busy_ns``)."""
+        if not obs_trace.current().enabled:
+            return self._retrying_load(i)
+        t0 = time.perf_counter_ns()
+        try:
+            return self._retrying_load(i)
+        finally:
+            busy = time.perf_counter_ns() - t0
+            with self._bad_lock:
+                self._py_busy_ns += busy
+
+    def _retrying_load(self, i: int) -> np.ndarray | None:
         delay = self.decode_retry_backoff_s
         err: BaseException | None = None
         for attempt in range(self.decode_retries + 1):
@@ -272,6 +301,30 @@ class DataLoader:
                     delay *= 2
         self._quarantine(i, err)
         return None
+
+    def _decode_counters(self) -> tuple[int, int]:
+        """``(images the C decoder refused, nanoseconds inside a decode)`` so
+        far: the native library's process-wide counters (another loader
+        decoding at the same time shows in them) plus this loader's Python
+        decodes. A batch's share is the difference around its
+        ``_load_batch``."""
+        refused = busy_ns = 0
+        if self.native_decode:
+            from mpi_pytorch_tpu import native
+
+            refused, busy_ns = native.counters()
+        with self._bad_lock:
+            return refused, busy_ns + self._py_busy_ns
+
+    @property
+    def decode_source(self) -> str:
+        """Where ``_load_batch`` gets its pixels: ``pack`` | ``native`` |
+        ``synthetic`` | ``pil`` (its order of preference)."""
+        if self._pack is not None:
+            return "pack"
+        if self.native_decode:
+            return "native"
+        return "synthetic" if self.synthetic else "pil"
 
     def _masked_labels(self, idx: np.ndarray) -> np.ndarray:
         """Batch labels with quarantined rows masked to -1 (the padding
@@ -495,27 +548,66 @@ class DataLoader:
         q: queue.Queue = queue.Queue(maxsize=self.prefetch)
         stop = threading.Event()
 
+        # The run's tracer, read on the consumer's thread (the driver's) and
+        # used by both threads; inert outside a traced run.
+        tracer = obs_trace.current()
+
         def put_or_abandon(item) -> bool:
             # Bounded put that gives up once the consumer is gone — never
             # blocks forever on a full queue. Returns whether it enqueued.
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.5)
-                    return True
-                except queue.Full:
-                    continue
-            return False
+            with tracer.span("loader/put"):
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.5)
+                        return True
+                    except queue.Full:
+                        continue
+                return False
+
+        def load_batch(idx, pool):
+            # ``_load_batch`` under a ``loader/decode`` span that says what
+            # the decode workers did; nothing is counted outside a traced run.
+            if not tracer.enabled:
+                return self._load_batch(idx, pool)
+            args = {
+                "images": len(idx), "source": self.decode_source,
+                "threads": self.num_workers,
+            }
+            with tracer.span("loader/decode", args=args):
+                refused, busy_ns = self._decode_counters()
+                quarantined = self.bad_samples
+                t0 = time.perf_counter()
+                stacked = self._load_batch(idx, pool)
+                call_s = time.perf_counter() - t0
+                refused_now, busy_ns_now = self._decode_counters()
+                args["fallbacks"] = refused_now - refused
+                args["quarantined"] = self.bad_samples - quarantined
+                # A pack is read by this one thread: its busy time is the
+                # call's; every other source counts where it decodes.
+                args["thread_busy_s"] = (
+                    call_s if self._pack is not None else (busy_ns_now - busy_ns) / 1e9
+                )
+            return stacked
 
         def decode_one_batch(idx, pool):
-            stacked = self._load_batch(idx, pool)
+            stacked = load_batch(idx, pool)
             if stacked.dtype != self.image_dtype:
-                stacked = stacked.astype(self.image_dtype)
+                with tracer.span("loader/cast"):
+                    stacked = stacked.astype(self.image_dtype)
             if fill_cache:
                 self._cache_images[idx] = stacked
                 self._cache_filled[idx] = True
             return stacked
 
         def producer() -> None:
+            # The producer's whole life, thread start to sentinel: the wall
+            # time of a run not under a ``loader/epoch`` had no producer alive.
+            with tracer.span(
+                "loader/epoch", args={"epoch": epoch, "batches": nb - start_batch}
+            ):
+                produce()
+
+        def produce() -> None:
             error = None
             try:
                 with ThreadPoolExecutor(max_workers=self.num_workers) as pool:
@@ -554,7 +646,8 @@ class DataLoader:
         def gen() -> Iterator[tuple[np.ndarray, np.ndarray]]:
             try:
                 while True:
-                    item = q.get()
+                    with tracer.span("loader/get"):
+                        item = q.get()
                     if item is None:
                         break
                     if isinstance(item, BaseException):

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import nn as jnn
@@ -95,8 +96,7 @@ class MultiHeadAttention(nn.Module):
     # each output column independently.
     qkv_fused: bool = False
 
-    @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    def _attend(self, q, k, v) -> jnp.ndarray:
         from mpi_pytorch_tpu.ops.flash_attention import flash_attention
         from mpi_pytorch_tpu.ops.fused_attention_small import (
             fused_attention_small,
@@ -107,6 +107,29 @@ class MultiHeadAttention(nn.Module):
         )
         from mpi_pytorch_tpu.ops.ulysses import ulysses_self_attention
 
+        if self.sp_strategy == "none":
+            if self.attn_impl == "flash":
+                return flash_attention(q, k, v)
+            elif self.attn_impl == "fused-small":
+                # init traces one dummy image: nothing to split over the
+                # data axis (see models/common.FusedStemBNReluPool).
+                return fused_attention_small(
+                    q, k, v,
+                    dp_mesh=None if self.is_initializing() else self.dp_mesh,
+                )
+            elif self.attn_impl == "full":
+                return full_attention(q, k, v)
+            else:
+                raise ValueError(f"unknown attn_impl {self.attn_impl!r}")
+        elif self.sp_strategy == "ring":
+            return ring_self_attention(q, k, v, self.sp_mesh)
+        elif self.sp_strategy == "ulysses":
+            return ulysses_self_attention(q, k, v, self.sp_mesh)
+        else:
+            raise ValueError(f"unknown sp_strategy {self.sp_strategy!r}")
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         hidden = x.shape[-1]
         if hidden % self.num_heads:
             raise ValueError(f"hidden {hidden} not divisible by {self.num_heads} heads")
@@ -133,26 +156,10 @@ class MultiHeadAttention(nn.Module):
                 param_dtype=self.param_dtype, name=name,
             )
             q, k, v = proj("q")(x), proj("k")(x), proj("v")(x)
-        if self.sp_strategy == "none":
-            if self.attn_impl == "flash":
-                out = flash_attention(q, k, v)
-            elif self.attn_impl == "fused-small":
-                # init traces one dummy image: nothing to split over the
-                # data axis (see models/common.FusedStemBNReluPool).
-                out = fused_attention_small(
-                    q, k, v,
-                    dp_mesh=None if self.is_initializing() else self.dp_mesh,
-                )
-            elif self.attn_impl == "full":
-                out = full_attention(q, k, v)
-            else:
-                raise ValueError(f"unknown attn_impl {self.attn_impl!r}")
-        elif self.sp_strategy == "ring":
-            out = ring_self_attention(q, k, v, self.sp_mesh)
-        elif self.sp_strategy == "ulysses":
-            out = ulysses_self_attention(q, k, v, self.sp_mesh)
-        else:
-            raise ValueError(f"unknown sp_strategy {self.sp_strategy!r}")
+        # The dispatch alone is the ``attention`` scope (its backward shows as
+        # ``transpose(jvp(…attention))``); the projections stay outside it.
+        with jax.named_scope("attention"):
+            out = self._attend(q, k, v)
         return nn.DenseGeneral(
             hidden, axis=(-2, -1), dtype=self.dtype,
             param_dtype=self.param_dtype, name="out",

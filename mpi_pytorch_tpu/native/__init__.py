@@ -113,7 +113,7 @@ def _try_load() -> ctypes.CDLL | None:
                 last_err = f"{type(e).__name__}: {e} {out}"
                 lib = None
                 break  # build/load failure: move to the next candidate dir
-            if _abi_version(lib) == 2:
+            if _abi_version(lib) == 3:
                 break
             last_err = f"stale native library (wrong ABI) at {path}"
             lib = None
@@ -137,6 +137,8 @@ def _try_load() -> ctypes.CDLL | None:
             ctypes.c_int,
             ctypes.POINTER(ctypes.c_int),
         ]
+        lib.mpt_decode_counters.restype = None
+        lib.mpt_decode_counters.argtypes = [ctypes.POINTER(ctypes.c_longlong)]
         # (decode.cpp also exports mpt_decode_one for ad-hoc C consumers and
         # microbenchmarks; the framework only uses the batch entry point.)
         return lib
@@ -172,6 +174,20 @@ def build_error() -> str | None:
     """Why the native library failed to load (for log lines), if it did."""
     load()
     return _build_error
+
+
+def counters() -> tuple[int, int]:
+    """``(images refused, nanoseconds the worker threads spent inside a
+    decode)`` over every ``decode_batch`` call of this process so far — kept
+    where the decode happens (two atomics in decode.cpp). A caller reads it
+    before and after a call and takes the difference; zeros when the library
+    is unavailable."""
+    lib = load()
+    if lib is None:
+        return 0, 0
+    out = (ctypes.c_longlong * 2)()
+    lib.mpt_decode_counters(out)
+    return int(out[0]), int(out[1])
 
 
 def decode_batch(

@@ -26,6 +26,7 @@
 #include <jpeglib.h>
 
 #include <atomic>
+#include <chrono>
 #include <cmath>
 #include <csetjmp>
 #include <cstdint>
@@ -252,6 +253,15 @@ int decode_file(const char* path, int out_h, int out_w, const float* mean,
                        out, prescale_margin, pixels, rscratch);
 }
 
+// Process-wide counters of what the batch entry point's worker threads did,
+// read by the loader before and after a call (the difference is that call's
+// share): images refused (left to the caller's fallback) and nanoseconds the
+// workers spent inside a decode. busy / (threads x wall) says whether N
+// workers were N cores' worth of work. Two clock reads an image (~50 ns)
+// against milliseconds of decode.
+std::atomic<long long> g_refused(0);
+std::atomic<long long> g_busy_ns(0);
+
 }  // namespace
 
 extern "C" {
@@ -289,6 +299,7 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
       const int i = next.fetch_add(1);
       if (i >= n) return;
       int st;
+      const auto t0 = std::chrono::steady_clock::now();
       try {
         st = decode_file(paths[i], out_h, out_w, mean, stdv, out + stride * i,
                          prescale_margin, filebuf, pixels, rs);
@@ -298,6 +309,10 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
         // failure, never thread/process death.
         st = ERR_DECODE;
       }
+      g_busy_ns.fetch_add(std::chrono::duration_cast<std::chrono::nanoseconds>(
+                              std::chrono::steady_clock::now() - t0)
+                              .count(),
+                          std::memory_order_relaxed);
       statuses[i] = st;
       if (st != OK) {
         // A failed decode may have partially written its slot; zero it so
@@ -305,6 +320,7 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
         // callers that skip the per-item fallback.
         std::memset(out + stride * i, 0, stride * sizeof(float));
         failures.fetch_add(1);
+        g_refused.fetch_add(1, std::memory_order_relaxed);
       }
     }
   };
@@ -319,6 +335,13 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
   return failures.load();
 }
 
-int mpt_abi_version() { return 2; }
+// out2 = {images refused, nanoseconds inside a decode}, both since the
+// library was loaded, over every mpt_decode_batch call.
+void mpt_decode_counters(long long* out2) {
+  out2[0] = g_refused.load(std::memory_order_relaxed);
+  out2[1] = g_busy_ns.load(std::memory_order_relaxed);
+}
+
+int mpt_abi_version() { return 3; }
 
 }  // extern "C"

@@ -13,8 +13,25 @@ XLA trace is being captured at the same time (``--profile-dir``) the host
 spans appear on the profiler's host timeline with the SAME names — the
 overlay recipe in ``docs/OBSERVABILITY.md``.
 
-Disabled (empty path) the tracer is inert: ``span`` yields immediately and
-``close`` writes nothing, so the hot loop pays nothing for the capability.
+One clock under both. Span times are ``time.perf_counter()`` minus an origin
+read at construction, and ``close()`` writes that origin down as
+``"otherData": {"t0_perf_counter_s", "t0_unix_ns"}`` (both read back to
+back): ``ts / 1e6 + t0_perf_counter_s`` puts a span on the clock of anything
+else in the process that reads ``perf_counter`` (a benchmark harness's
+window), and ``ts * 1e3 + t0_unix_ns`` on the wall clock, which is the
+profiler's — so the file lies on an XLA trace without an anchor event.
+
+Layers below the drivers reach the run's tracer through ``current()``: the
+trainer installs its tracer with ``use(tracer)`` for the length of the run,
+and the input pipeline (``data/pipeline.py``, ``trainer.device_prefetch``)
+opens its spans on whatever is installed. Nothing installed, or a tracer
+without a path, is the same inert object: it records nothing, opens no
+profiler annotation and ``close`` writes nothing. What it still does is read
+the clock at both ends of a span, because a span is also how a caller times
+a region: ``end`` returns the seconds it measured and ``span`` yields an
+object whose ``seconds`` holds them once the block has closed, on either
+path — the trainer's ``data_wait_ms`` / ``step_ms`` are the ``ingest`` and
+``step`` spans' own durations, not a second clock pair around them.
 """
 
 from __future__ import annotations
@@ -48,6 +65,16 @@ def trace_path(path: str, process: int, process_count: int) -> str:
     return f"{root}.p{process}{ext or '.json'}"
 
 
+class Timed:
+    """What ``Tracer.span`` yields: ``seconds`` is how long the block took,
+    set when it closes."""
+
+    __slots__ = ("seconds",)
+
+    def __init__(self):
+        self.seconds = 0.0
+
+
 class Tracer:
     """Chrome-trace-event span recorder. Thread-safe (the async checkpointer
     and loader threads may span concurrently); events buffer in memory and
@@ -58,7 +85,10 @@ class Tracer:
     def __init__(self, path: str | None, clock=time.perf_counter):
         self.path = path or None
         self._clock = clock
+        # The origin on both clocks, read back to back: ``close`` writes them
+        # down so the spans can be laid on any other timeline of the run.
         self._t0 = clock()
+        self._t0_unix_ns = time.time_ns()
         self._events: list[dict] = []
         self._lock = threading.Lock()
         self._pid: int | None = None
@@ -81,47 +111,53 @@ class Tracer:
     def begin(self, name: str, cat: str = "host"):
         """Open a span manually — for regions that span control-flow a
         ``with`` block can't wrap cleanly (the trainer's compile branches).
-        Returns a token for ``end``; None when disabled."""
-        if not self.enabled:
-            return None
-        ann = _trace_annotation(name)
+        Returns a token for ``end``. Disabled, the token carries the clock
+        reading alone: nothing is recorded and no annotation opens."""
+        ann = _trace_annotation(name) if self.enabled else None
         if ann is not None:
             ann.__enter__()
         return (name, cat, self._now_us(), ann)
 
-    def end(self, token, args: Mapping[str, Any] | None = None) -> None:
-        if token is None:
-            return
+    def end(self, token, args: Mapping[str, Any] | None = None) -> float:
+        """Close the span ``begin`` opened and return the seconds it lasted
+        (enabled or not), so a caller that wants the number does not time
+        the region a second time."""
         name, cat, ts, ann = token
+        dur = self._now_us() - ts
         # Balance the TraceAnnotation even when the tracer was closed
         # mid-span (failure-path flush) — the event is dropped, the
         # profiler's host annotation stack must not be.
         if ann is not None:
             ann.__exit__(None, None, None)
-        if not self.enabled:
-            return
-        event = {
-            "name": name,
-            "cat": cat,
-            "ph": "X",  # complete event: ts+dur; nesting renders from overlap
-            "ts": round(ts, 3),  # Chrome trace timestamps are microseconds
-            "dur": round(self._now_us() - ts, 3),
-            "pid": self._process_index(),
-            "tid": threading.get_ident() % 2**31,
-        }
-        if args:
-            event["args"] = dict(args)
-        with self._lock:
-            self._events.append(event)
+        if self.enabled:
+            event = {
+                "name": name,
+                "cat": cat,
+                "ph": "X",  # complete event: ts+dur; nesting renders from overlap
+                "ts": round(ts, 3),  # Chrome trace timestamps are microseconds
+                "dur": round(dur, 3),
+                "pid": self._process_index(),
+                "tid": threading.get_ident() % 2**31,
+            }
+            if args:
+                event["args"] = dict(args)
+            with self._lock:
+                self._events.append(event)
+        return dur / 1e6
 
     @contextmanager
     def span(self, name: str, cat: str = "host", args: Mapping[str, Any] | None = None):
-        """``with tracer.span("ingest"): ...`` — the primary API."""
+        """``with tracer.span("ingest") as timed: ...`` — the primary API.
+        ``timed.seconds`` is the span's length once the block has closed.
+        ``args`` is read when the span closes, so the block may fill a dict
+        it passed in with what it learned (a batch's byte count, a decoder's
+        counters)."""
+        timed = Timed()
         token = self.begin(name, cat)
         try:
-            yield
+            yield timed
         finally:
-            self.end(token, args)
+            timed.seconds = self.end(token, args)
 
     def instant(self, name: str, args: Mapping[str, Any] | None = None) -> None:
         """A zero-duration marker (anomalies, heartbeats) on the timeline."""
@@ -156,8 +192,39 @@ class Tracer:
         os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
         with self._lock, open(out, "w") as f:
             json.dump(
-                {"traceEvents": self._events, "displayTimeUnit": "ms"},
+                {
+                    "traceEvents": self._events,
+                    "displayTimeUnit": "ms",
+                    "otherData": {
+                        "t0_perf_counter_s": self._t0,
+                        "t0_unix_ns": self._t0_unix_ns,
+                    },
+                },
                 f,
                 separators=(",", ":"),
             )
         return out
+
+
+# The process-wide current tracer: inert until a driver installs its own.
+_INERT = Tracer(None)
+_current: Tracer = _INERT
+
+
+def current() -> Tracer:
+    """The tracer of the run in progress — the inert one when no driver
+    installed any, so a layer below the drivers opens its spans
+    unconditionally and pays nothing outside a traced run."""
+    return _current
+
+
+@contextmanager
+def use(tracer: Tracer):
+    """Install ``tracer`` as ``current()`` for the length of the block (one
+    run of a driver); the previous one is restored on the way out."""
+    global _current
+    previous, _current = _current, tracer
+    try:
+        yield tracer
+    finally:
+        _current = previous

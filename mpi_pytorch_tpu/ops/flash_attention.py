@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from mpi_pytorch_tpu.ops.kernel_call import kernel_call
+
 _NEG = -1e30  # finite mask value: keeps the online-softmax recurrence NaN-free
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -129,7 +131,8 @@ def _fwd_impl(q3, k3, v3, *, causal, block_q, block_k, interpret):
     )
     from jax.experimental.pallas import tpu as pltpu
 
-    out, lse = pl.pallas_call(
+    out, lse = kernel_call(
+        "flash_attn_fwd",
         kernel,
         grid=(bh, n_q, n_k),
         in_specs=[

@@ -87,6 +87,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from mpi_pytorch_tpu.ops.kernel_call import kernel_call
+
 _NEG = -1e30  # finite mask value — exp(_NEG - m) underflows to exactly 0
 
 # The tiny-S envelope: one (G·S_pad)² f32 score tile must fit comfortably
@@ -191,7 +193,8 @@ def _fwd_impl(qg, kg, vg, *, seq_len, s_pad, g, causal, interpret):
     n, r, d = qg.shape
     bias = _mask_bias(g, s_pad, seq_len, causal)
     tile, bspec, grid = _tile_specs(n, r, d)
-    return pl.pallas_call(
+    return kernel_call(
+        "attn_small_fwd",
         functools.partial(_fwd_kernel, scale=d**-0.5),
         grid=grid,
         in_specs=[tile, tile, tile, bspec],
@@ -205,7 +208,8 @@ def _bwd_impl(qg, kg, vg, dog, *, seq_len, s_pad, g, causal, interpret):
     n, r, d = qg.shape
     bias = _mask_bias(g, s_pad, seq_len, causal)
     tile, bspec, grid = _tile_specs(n, r, d)
-    return pl.pallas_call(
+    return kernel_call(
+        "attn_small_bwd",
         functools.partial(_bwd_kernel, scale=d**-0.5),
         grid=grid,
         in_specs=[tile, tile, tile, tile, bspec],

@@ -54,6 +54,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from mpi_pytorch_tpu.ops.kernel_call import kernel_call
+
 _BLOCK_V = 2048  # fwd vocab tile; [B, BV] f32 = 4 MB at B=512 (4096 OOMs scoped VMEM)
 # The backward pass holds ~5 live [B, BV] f32 temporaries (logits, softmax,
 # onehot, dlog, dW) plus feats/dfeats — 2048 blows the 16 MB scoped-VMEM
@@ -194,7 +196,8 @@ def _fwd_impl(feats, w, b, labels, interpret):
     wp, bp, v = _pad_wb(w, b, _BLOCK_V)
     bsz, d = feats.shape
     grid = wp.shape[1] // _BLOCK_V
-    out = pl.pallas_call(
+    out = kernel_call(
+        "head_ce_fwd",
         _fwd_kernel,
         grid=(grid,),
         in_specs=[
@@ -234,7 +237,8 @@ def _bwd_rule(interpret, residuals, g):
     feats, wp, bp, labels, m, l, v = residuals
     bsz, d = feats.shape
     grid = wp.shape[1] // _BLOCK_V_BWD
-    dfeats, dw, db = pl.pallas_call(
+    dfeats, dw, db = kernel_call(
+        "head_ce_bwd",
         _bwd_kernel,
         grid=(grid,),
         in_specs=[
@@ -385,7 +389,8 @@ def _predict_call(labels, feats, wp, bp, *, block_r: int, interpret: bool):
     """One (per-shard) row-tiled kernel invocation over pre-padded W/bias."""
     bsz, d = feats.shape
     row_spec = pl.BlockSpec((block_r, 1), lambda i, j: (i, 0))
-    loss, pred, *_ = pl.pallas_call(
+    loss, pred, *_ = kernel_call(
+        "head_predict",
         _predict_kernel,
         grid=(bsz // block_r, wp.shape[1] // _BLOCK_V),
         in_specs=[

@@ -128,6 +128,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from mpi_pytorch_tpu.ops.kernel_call import kernel_call
+
 _NEG = float("-inf")
 
 # Pool geometry is fixed: the torchvision stem (3×3, stride 2, pad 1).
@@ -364,7 +366,8 @@ def _fwd_impl(yt, a, b, *, want_idx, interpret):
     grid = (c // nc, bsz // nb)
     idx_dtype = jnp.int8 if lev["idx_int8"] else jnp.bfloat16
     if want_idx:
-        return pl.pallas_call(
+        return kernel_call(
+            "stem_fwd",
             functools.partial(_fwd_kernel, bf16_pool=lev["bf16_pool"]),
             grid=grid,
             in_specs=in_specs,
@@ -376,8 +379,10 @@ def _fwd_impl(yt, a, b, *, want_idx, interpret):
             interpret=interpret,
             compiler_params=_tpu_params() if not interpret else None,
         )(yt, a2, b2)
-    return pl.pallas_call(
+    return kernel_call(
+        "stem_fwd",  # one kernel to a trace reader, with or without idx
         functools.partial(_primal_kernel, bf16_pool=lev["bf16_pool"]),
+        name="stem_fwd_primal",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec,
@@ -397,7 +402,8 @@ def _bwd_impl(gt, idxt, pooledt, yt, a, *, interpret):
     a2 = a.astype(jnp.float32).reshape(c, 1)
     small = pl.BlockSpec((h2, w2, nc, nb), lambda j, i: (0, 0, j, i))
     big = pl.BlockSpec((h, w, nc, nb), lambda j, i: (0, 0, j, i))
-    dyt, da8, db8 = pl.pallas_call(
+    dyt, da8, db8 = kernel_call(
+        "stem_bwd",
         functools.partial(_bwd_kernel, n_c=c // nc, n_b=bsz // nb, nc=nc),
         grid=(c // nc, bsz // nb),
         in_specs=[

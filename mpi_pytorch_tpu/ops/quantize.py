@@ -65,6 +65,7 @@ from mpi_pytorch_tpu.ops.fused_head_ce import (
     predict_compiler_params,
     online_predict_update,
 )
+from mpi_pytorch_tpu.ops.kernel_call import kernel_call
 
 # ---------------------------------------------------------------------------
 # per-channel weight quantization
@@ -348,7 +349,8 @@ def _predict_int8_call(labels, feats_q, wp, sp, bp, *, block_r: int, interpret: 
     W/scale/bias (the ``_predict_call`` shape with one extra operand)."""
     bsz, d = feats_q.shape
     row_spec = pl.BlockSpec((block_r, 1), lambda i, j: (i, 0))
-    loss, pred, *_ = pl.pallas_call(
+    loss, pred, *_ = kernel_call(
+        "head_predict_int8",
         _predict_int8_kernel,
         grid=(bsz // block_r, wp.shape[1] // _BLOCK_V),
         in_specs=[

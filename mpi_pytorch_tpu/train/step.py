@@ -65,13 +65,14 @@ def ingest_images(images, compute_dtype):
       the exact op order of ``pipeline.normalize_image``, where XLA fuses it
       into the first convolution for free;
     - float batches were normalized on the host and just cast."""
-    if images.dtype == jnp.uint8:
-        x = images.astype(jnp.float32) / 255.0
-        x = (x - jnp.asarray(IMAGENET_MEAN, jnp.float32)) / jnp.asarray(
-            IMAGENET_STD, jnp.float32
-        )
-        return x.astype(compute_dtype)
-    return images.astype(compute_dtype)
+    with jax.named_scope("input"):
+        if images.dtype == jnp.uint8:
+            x = images.astype(jnp.float32) / 255.0
+            x = (x - jnp.asarray(IMAGENET_MEAN, jnp.float32)) / jnp.asarray(
+                IMAGENET_STD, jnp.float32
+            )
+            return x.astype(compute_dtype)
+        return images.astype(compute_dtype)
 
 
 def _loss_and_updates(state: TrainState, images, labels, rng, remat: bool = False):
@@ -90,12 +91,18 @@ def _loss_and_updates(state: TrainState, images, labels, rng, remat: bool = Fals
         if state.batch_stats is not None:
             variables["batch_stats"] = state.batch_stats
             mutable.append("batch_stats")
-        out, updated = state.apply_fn(
-            variables, images, train=True, rngs={"dropout": rng}, mutable=mutable
-        )
+        # The backward pass needs no scope of its own: JAX names what it
+        # derives from these two ``transpose(jvp(forward))`` / ``…(loss)``.
+        with jax.named_scope("forward"):
+            out, updated = state.apply_fn(
+                variables, images, train=True, rngs={"dropout": rng}, mutable=mutable
+            )
         new_bs = updated["batch_stats"] if state.batch_stats is not None else None
-        aux = sum(jnp.sum(v) for v in jax.tree_util.tree_leaves(updated.get("losses", {})))
-        loss = classification_loss(out, labels) + aux
+        with jax.named_scope("loss"):
+            aux = sum(
+                jnp.sum(v) for v in jax.tree_util.tree_leaves(updated.get("losses", {}))
+            )
+            loss = classification_loss(out, labels) + aux
         logits = out[0] if isinstance(out, tuple) else out
         return loss, (new_bs, logits)
 
@@ -108,15 +115,32 @@ def _loss_and_updates(state: TrainState, images, labels, rng, remat: bool = Fals
 
 
 def _apply_updates(state: TrainState, grads, new_bs) -> TrainState:
-    updates, new_opt = state.tx.update(grads, state.opt_state, state.params)
-    new_params = optax.apply_updates(state.params, updates)
-    return state.replace(
-        step=state.step + 1,
-        params=new_params,
-        batch_stats=new_bs if state.batch_stats is not None else None,
-        opt_state=new_opt,
-        rng=jax.random.fold_in(state.rng, 1),
-    )
+    with jax.named_scope("optimizer"):
+        updates, new_opt = state.tx.update(grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
+        return state.replace(
+            step=state.step + 1,
+            params=new_params,
+            batch_stats=new_bs if state.batch_stats is not None else None,
+            opt_state=new_opt,
+            rng=jax.random.fold_in(state.rng, 1),
+        )
+
+
+def _step_metrics(loss, logits, labels, grads) -> dict:
+    """The step's own numbers, in every compiler-partitioned step flavor.
+    grad_norm: the global (all-parameter) L2 norm — the training-health
+    signal the obs layer records per step (obs/health.py). A scalar
+    reduction XLA fuses into the backward; negligible next to the matmuls,
+    and present in every step flavor so telemetry can't depend on which
+    mode a run uses."""
+    with jax.named_scope("metrics"):
+        return {
+            "loss": loss,
+            "correct": accuracy_count(logits, labels),
+            "count": valid_count(labels),
+            "grad_norm": optax.global_norm(grads).astype(jnp.float32),
+        }
 
 
 def _step_ok(metrics) -> jax.Array:
@@ -125,7 +149,8 @@ def _step_ok(metrics) -> jax.Array:
     count-weighted global mean, the norm spans every parameter), so under
     SPMD every shard/host computes the identical verdict — the property
     that lets the skip policy branch without a collective."""
-    return jnp.isfinite(metrics["loss"]) & jnp.isfinite(metrics["grad_norm"])
+    with jax.named_scope("metrics"):
+        return jnp.isfinite(metrics["loss"]) & jnp.isfinite(metrics["grad_norm"])
 
 
 def _guard_bad_step(ok, new_tree, old_tree):
@@ -136,15 +161,17 @@ def _guard_bad_step(ok, new_tree, old_tree):
     batch. A whole-tree select instead of ``lax.cond`` because it stays
     trivially correct inside shard_map/scan and costs one fused elementwise
     pass only on runs that opted into the policy."""
-    return jax.tree_util.tree_map(
-        lambda n, o: jnp.where(ok, n, o), new_tree, old_tree
-    )
+    with jax.named_scope("optimizer"):
+        return jax.tree_util.tree_map(
+            lambda n, o: jnp.where(ok, n, o), new_tree, old_tree
+        )
 
 
 def _with_skip_flag(metrics, ok):
     """Stamp the step's verdict into the metrics (``skipped`` ∈ {0, 1}) —
     the host side of the policy (streak counting, telemetry) reads this."""
-    return dict(metrics, skipped=(~ok).astype(jnp.int32))
+    with jax.named_scope("metrics"):
+        return dict(metrics, skipped=(~ok).astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -172,19 +199,6 @@ def make_train_step(
     Memoized so repeated ``train()`` calls in one process (resume, tests)
     reuse the same jitted function and its XLA compilation cache."""
 
-    def compute_metrics(loss, logits, labels, grads):
-        # grad_norm: the global (all-parameter) L2 norm — the training-health
-        # signal the obs layer records per step (obs/health.py). A scalar
-        # reduction XLA fuses into the backward; negligible next to the
-        # matmuls, and present in every step flavor so telemetry can't
-        # depend on which mode a run uses.
-        return {
-            "loss": loss,
-            "correct": accuracy_count(logits, labels),
-            "count": valid_count(labels),
-            "grad_norm": optax.global_norm(grads).astype(jnp.float32),
-        }
-
     if accum_steps <= 1:
 
         @functools.partial(jax.jit, donate_argnums=(0,))
@@ -196,7 +210,7 @@ def make_train_step(
                 state, images, labels, rng, remat=remat
             )
             new_state = _apply_updates(state, grads, new_bs)
-            metrics = compute_metrics(loss, logits, labels, grads)
+            metrics = _step_metrics(loss, logits, labels, grads)
             if bad_step_skip:
                 ok = _step_ok(metrics)
                 new_state = _guard_bad_step(ok, new_state, state)
@@ -287,14 +301,15 @@ def make_train_step(
             lambda g: g / denom.astype(g.dtype), grad_sum
         )
         new_state = _apply_updates(state, grads, new_bs)
-        metrics = {
-            "loss": loss_sum / denom,
-            "correct": correct,
-            "count": count,
-            # Norm of the ACCUMULATED (count-weighted mean) gradient — the
-            # same quantity the unsplit step reports.
-            "grad_norm": optax.global_norm(grads).astype(jnp.float32),
-        }
+        with jax.named_scope("metrics"):
+            metrics = {
+                "loss": loss_sum / denom,
+                "correct": correct,
+                "count": count,
+                # Norm of the ACCUMULATED (count-weighted mean) gradient — the
+                # same quantity the unsplit step reports.
+                "grad_norm": optax.global_norm(grads).astype(jnp.float32),
+            }
         if bad_step_skip:
             ok = _step_ok(metrics)
             new_state = _guard_bad_step(ok, new_state, state)
@@ -366,15 +381,17 @@ def _gather_batch(mesh, compute_dtype, dataset, labels_all, idx, valid):
     rows are sharded over ``data`` whenever that axis has >1 device
     (``build_device_cache``), so the gather goes through the cross-shard
     path; a 1-device data axis holds the whole dataset locally."""
-    if mesh.shape[mesh.axis_names[0]] > 1:
-        raw = _sharded_cache_take(mesh, dataset, idx)
-    else:
-        raw = jnp.take(dataset, idx, axis=0)
-    images = ingest_images(raw, compute_dtype)
-    images = lax.with_sharding_constraint(
-        images, NamedSharding(mesh, P(mesh.axis_names[0]))
-    )
-    labels = jnp.where(valid, jnp.take(labels_all, idx), -1)
+    with jax.named_scope("input"):
+        if mesh.shape[mesh.axis_names[0]] > 1:
+            raw = _sharded_cache_take(mesh, dataset, idx)
+        else:
+            raw = jnp.take(dataset, idx, axis=0)
+    images = ingest_images(raw, compute_dtype)  # opens ``input`` itself
+    with jax.named_scope("input"):
+        images = lax.with_sharding_constraint(
+            images, NamedSharding(mesh, P(mesh.axis_names[0]))
+        )
+        labels = jnp.where(valid, jnp.take(labels_all, idx), -1)
     return images, labels
 
 
@@ -390,12 +407,7 @@ def _cached_batch_step(
     rng = jax.random.fold_in(state.rng, state.step)
     loss, logits, new_bs, grads = _loss_and_updates(state, images, labels, rng, remat=remat)
     new_state = _apply_updates(state, grads, new_bs)
-    metrics = {
-        "loss": loss,
-        "correct": accuracy_count(logits, labels),
-        "count": valid_count(labels),
-        "grad_norm": optax.global_norm(grads).astype(jnp.float32),
-    }
+    metrics = _step_metrics(loss, logits, labels, grads)
     if bad_step_skip:
         # Inside the scanned epoch this guards EVERY scan iteration: a
         # non-finite step mid-scan is discarded on device and the scan
@@ -876,11 +888,12 @@ def make_spmd_train_step(
         # pmean'd so the replicated state stays consistent across shards
         # (the reference instead checkpoints rank 0's stats, main.py:162-171).
         if new_bs is not None:
-            new_bs = (
-                collectives.hier_pmean(new_bs, ici_axis, pod_axis)
-                if hier
-                else collectives.all_reduce(new_bs, "mean", axis=ici_axis)
-            )
+            with jax.named_scope("grad_sync"):
+                new_bs = (
+                    collectives.hier_pmean(new_bs, ici_axis, pod_axis)
+                    if hier
+                    else collectives.all_reduce(new_bs, "mean", axis=ici_axis)
+                )
         return loss, logits, new_bs, grads, labels
 
     def _metrics(loss, logits, labels, grad_norm):
@@ -891,38 +904,42 @@ def make_spmd_train_step(
         # regardless of local batch size, mpi_tools.py:36). These are scalar
         # psums (a few bytes), spanning both nested axes in one collective —
         # not worth a two-phase decomposition or a ledger entry.
-        local_count = valid_count(labels)
-        global_count = lax.psum(local_count, red_axes)
-        return {
-            "loss": lax.psum(loss * local_count.astype(loss.dtype), red_axes)
-            / jnp.maximum(global_count.astype(loss.dtype), 1),
-            "correct": lax.psum(accuracy_count(logits, labels), red_axes),
-            "count": global_count,
-            "grad_norm": grad_norm.astype(jnp.float32),
-        }
+        with jax.named_scope("metrics"):
+            local_count = valid_count(labels)
+            global_count = lax.psum(local_count, red_axes)
+            return {
+                "loss": lax.psum(loss * local_count.astype(loss.dtype), red_axes)
+                / jnp.maximum(global_count.astype(loss.dtype), 1),
+                "correct": lax.psum(accuracy_count(logits, labels), red_axes),
+                "count": global_count,
+                "grad_norm": grad_norm.astype(jnp.float32),
+            }
 
     if not zero_opt_state:
 
         def per_shard(state: TrainState, batch):
             loss, logits, new_bs, grads, labels = _forward_backward(state, batch)
-            if grad_bucket_mb > 0:
-                plan = grad_bucket_plan(grads, grad_bucket_mb)
-                grads = (
-                    _hier_bucketed_mean(grads, plan, ici_axis, pod_axis)
-                    if hier
-                    else _bucketed_pmean(grads, plan, ici_axis)
-                )
-            elif hier:
-                # Three-phase hierarchical allreduce: the DCN sees 1/ici of
-                # the gradient bytes a flat pmean would push across it.
-                grads = collectives.hier_pmean(grads, ici_axis, pod_axis)
-            else:
-                # THE line (≙ the entire mpi_avg_grads stack, mpi_tools.py:30-37):
-                grads = collectives.avg_grads(grads, axis=ici_axis)
+            with jax.named_scope("grad_sync"):
+                if grad_bucket_mb > 0:
+                    plan = grad_bucket_plan(grads, grad_bucket_mb)
+                    grads = (
+                        _hier_bucketed_mean(grads, plan, ici_axis, pod_axis)
+                        if hier
+                        else _bucketed_pmean(grads, plan, ici_axis)
+                    )
+                elif hier:
+                    # Three-phase hierarchical allreduce: the DCN sees 1/ici of
+                    # the gradient bytes a flat pmean would push across it.
+                    grads = collectives.hier_pmean(grads, ici_axis, pod_axis)
+                else:
+                    # THE line (≙ the entire mpi_avg_grads stack, mpi_tools.py:30-37):
+                    grads = collectives.avg_grads(grads, axis=ici_axis)
             new_state = _apply_updates(state, grads, new_bs)
             # grads were just averaged: every shard computes the identical
             # global-gradient norm, so no further collective is needed.
-            metrics = _metrics(loss, logits, labels, optax.global_norm(grads))
+            with jax.named_scope("metrics"):
+                grad_norm = optax.global_norm(grads)
+            metrics = _metrics(loss, logits, labels, grad_norm)
             if bad_step_skip:
                 # The verdict reads the ALREADY-psum'd loss and the
                 # averaged-grads norm, so every shard takes the same branch
@@ -952,75 +969,78 @@ def make_spmd_train_step(
     def per_shard_zero(opt_treedef, state: TrainState, flat_opt, batch):
         loss, logits, new_bs, grads, labels = _forward_backward(state, batch)
 
-        if grad_bucket_mb > 0:
-            plan = grad_bucket_plan(grads, grad_bucket_mb)
-            grad_slices = (
-                _hier_bucketed_reduce_scatter(
-                    grads, plan, ici_axis, pod_axis, n_shards, n_pods
+        with jax.named_scope("grad_sync"):
+            if grad_bucket_mb > 0:
+                plan = grad_bucket_plan(grads, grad_bucket_mb)
+                grad_slices = (
+                    _hier_bucketed_reduce_scatter(
+                        grads, plan, ici_axis, pod_axis, n_shards, n_pods
+                    )
+                    if hier
+                    else _bucketed_reduce_scatter(grads, plan, ici_axis, n_shards)
                 )
-                if hier
-                else _bucketed_reduce_scatter(grads, plan, ici_axis, n_shards)
-            )
-        elif hier:
-            # Phases 1+2 only: each ici shard keeps its global-mean slice
-            # (pod-replicated) — the slice IS what the sharded optimizer
-            # update consumes, so no gather of gradients ever happens.
-            grad_slices = collectives.hier_reduce_scatter_mean(
-                grads, ici_axis, pod_axis
-            )
-        else:
-            grads = collectives.avg_grads(grads, axis=ici_axis)
-            grad_slices = _slice_tree(grads, ici_axis, n_shards)
+            elif hier:
+                # Phases 1+2 only: each ici shard keeps its global-mean slice
+                # (pod-replicated) — the slice IS what the sharded optimizer
+                # update consumes, so no gather of gradients ever happens.
+                grad_slices = collectives.hier_reduce_scatter_mean(
+                    grads, ici_axis, pod_axis
+                )
+            else:
+                grads = collectives.avg_grads(grads, axis=ici_axis)
+                grad_slices = _slice_tree(grads, ici_axis, n_shards)
         # Global grad norm from the owned slices: the slices tile the mean
         # gradient exactly (padding contributes zeros), so psum of per-slice
         # squared sums is the global squared norm — same number every other
         # step flavor reports, one scalar collective. Over the ZeRO axis
         # only: on a nested mesh the slices are pod-replicated, so an
         # all-axis psum would count each slice pods times.
-        sq = sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)))
-            for g in jax.tree_util.tree_leaves(grad_slices)
-        )
-        grad_norm = jnp.sqrt(lax.psum(sq, zero_axis))
+        with jax.named_scope("metrics"):
+            sq = sum(
+                jnp.sum(jnp.square(g.astype(jnp.float32)))
+                for g in jax.tree_util.tree_leaves(grad_slices)
+            )
+            grad_norm = jnp.sqrt(lax.psum(sq, zero_axis))
 
-        param_slices = _slice_tree(state.params, zero_axis, n_shards)
-        opt_local = jax.tree_util.tree_unflatten(
-            opt_treedef,
-            [
-                leaf.reshape(leaf.shape[1:]) if getattr(leaf, "ndim", 0) else leaf
-                for leaf in flat_opt
-            ],
-        )
-        # The sliced trees preserve the params' TREE structure, so the optax
-        # chain (schedules off the replicated count scalar, multi_transform
-        # labels, adamw decay against the sliced params) applies unchanged.
-        updates, new_opt = state.tx.update(grad_slices, opt_local, param_slices)
-        new_param_slices = optax.apply_updates(param_slices, updates)
-        # Reassemble full params for the next forward: ONE tiled allgather
-        # per leaf, then strip the zero_shard_spec padding. On a nested
-        # mesh this gathers over ``ici`` ONLY — every pod holds the full
-        # slice set, so reassembling params costs zero DCN bytes (the
-        # within-pod ZeRO placement rule).
-        gathered = (
-            collectives.hier_all_gather(new_param_slices, ici_axis)
-            if hier
-            else collectives.all_gather(new_param_slices, axis=ici_axis)
-        )
-        new_params = jax.tree_util.tree_map(
-            lambda full, orig: full[: orig.size].reshape(orig.shape),
-            gathered,
-            state.params,
-        )
-        new_state = state.replace(
-            step=state.step + 1,
-            params=new_params,
-            batch_stats=new_bs if state.batch_stats is not None else None,
-            rng=jax.random.fold_in(state.rng, 1),
-        )
-        new_flat = tuple(
-            leaf[None] if getattr(leaf, "ndim", 0) else leaf
-            for leaf in jax.tree_util.tree_leaves(new_opt)
-        )
+        with jax.named_scope("optimizer"):
+            param_slices = _slice_tree(state.params, zero_axis, n_shards)
+            opt_local = jax.tree_util.tree_unflatten(
+                opt_treedef,
+                [
+                    leaf.reshape(leaf.shape[1:]) if getattr(leaf, "ndim", 0) else leaf
+                    for leaf in flat_opt
+                ],
+            )
+            # The sliced trees preserve the params' TREE structure, so the optax
+            # chain (schedules off the replicated count scalar, multi_transform
+            # labels, adamw decay against the sliced params) applies unchanged.
+            updates, new_opt = state.tx.update(grad_slices, opt_local, param_slices)
+            new_param_slices = optax.apply_updates(param_slices, updates)
+            # Reassemble full params for the next forward: ONE tiled allgather
+            # per leaf, then strip the zero_shard_spec padding. On a nested
+            # mesh this gathers over ``ici`` ONLY — every pod holds the full
+            # slice set, so reassembling params costs zero DCN bytes (the
+            # within-pod ZeRO placement rule).
+            gathered = (
+                collectives.hier_all_gather(new_param_slices, ici_axis)
+                if hier
+                else collectives.all_gather(new_param_slices, axis=ici_axis)
+            )
+            new_params = jax.tree_util.tree_map(
+                lambda full, orig: full[: orig.size].reshape(orig.shape),
+                gathered,
+                state.params,
+            )
+            new_state = state.replace(
+                step=state.step + 1,
+                params=new_params,
+                batch_stats=new_bs if state.batch_stats is not None else None,
+                rng=jax.random.fold_in(state.rng, 1),
+            )
+            new_flat = tuple(
+                leaf[None] if getattr(leaf, "ndim", 0) else leaf
+                for leaf in jax.tree_util.tree_leaves(new_opt)
+            )
         metrics = _metrics(loss, logits, labels, grad_norm)
         if bad_step_skip:
             # Same contract as the non-ZeRO shard: the psum'd loss/norm

@@ -42,6 +42,7 @@ from mpi_pytorch_tpu.obs import (
     Tracer,
     parse_rules,
 )
+from mpi_pytorch_tpu.obs import trace as obs_trace
 from mpi_pytorch_tpu.parallel.collectives import LEDGER
 from mpi_pytorch_tpu.parallel.mesh import (
     create_mesh,
@@ -485,19 +486,32 @@ def _state_shardings(state):
     return jax.tree_util.tree_map(lambda x: x.sharding, state)
 
 
-def device_prefetch(batches, mesh, host_batch: int, depth: int = 2):
+def device_prefetch(
+    batches, mesh, host_batch: int, depth: int = 2, *, epoch: int = 0,
+    start_step: int = 0,
+):
     """Double-buffered host→device transfer: pad + ``shard_batch`` each
     host batch ``depth`` steps ahead of the consumer. ``device_put`` is
     asynchronous, so the H2D copy for batch N+1 overlaps the compute of
     batch N — the overlap the reference's 4-stage MPI pipeline bought with
     dedicated ranks (``evaluation_pipeline.py:53-129``), at zero process
-    cost."""
+    cost.
+
+    Each batch's pad + ``shard_batch`` is one ``h2d`` span on the run's
+    tracer (args ``bytes``, ``epoch``, ``batch`` — the last two only name
+    the batch, which is what lets a device trace be joined to it): the
+    host's share of the transfer. A finished batch then waits in ``buf``
+    until ``depth`` more are behind it."""
     from collections import deque
 
+    tracer = obs_trace.current()
     buf = deque()
-    for images, labels in batches:
-        images, labels = pad_batch(images, labels, host_batch)
-        buf.append(shard_batch((images, labels), mesh))
+    for batch_i, (images, labels) in enumerate(batches, start=start_step):
+        args = {"epoch": epoch, "batch": batch_i}
+        with tracer.span("h2d", args=args):
+            images, labels = pad_batch(images, labels, host_batch)
+            args["bytes"] = int(images.nbytes + labels.nbytes)
+            buf.append(shard_batch((images, labels), mesh))
         if len(buf) > depth:
             yield buf.popleft()
     while buf:
@@ -753,10 +767,14 @@ def train(cfg: Config) -> TrainSummary:
         or cfg.bad_step_policy != "abort"
     )
     try:
-        return _train_impl(
-            cfg, logger, metrics, tracer, health, heartbeat, telemetry_sync,
-            registry, monitor, flight,
-        )
+        # The run's tracer is also the process-wide current one for its
+        # length: the layers below (data/pipeline.py, device_prefetch) open
+        # their spans on it without a flag of their own.
+        with obs_trace.use(tracer):
+            return _train_impl(
+                cfg, logger, metrics, tracer, health, heartbeat, telemetry_sync,
+                registry, monitor, flight,
+            )
     except BaseException:
         # A failure anywhere — including build/cache/compile, BEFORE the
         # epoch loop's own handler exists — must still flush the buffered
@@ -947,6 +965,37 @@ def _train_impl(
             n_cache, n_data, dataset.nbytes / n_data / 1e6, dataset.dtype,
         )
 
+    # Where the ``compile`` span's seconds go, as child spans: one ``lower``
+    # per ``.lower(...)`` (tracing the Python step into StableHLO), one
+    # ``load_or_compile`` per ``.compile(...)`` (``cache_hit``: the
+    # persistent cache served it), one ``cost_analysis``.
+    def _lower(program: str, jitted, *args):
+        with tracer.span("lower", args={"program": program}):
+            return jitted.lower(*args)
+
+    def _load_or_compile(lowered):
+        hits: list[str] = []
+
+        def on_event(event: str, **_kw) -> None:
+            if event == "/jax/compilation_cache/cache_hits":
+                hits.append(event)
+
+        args: dict = {}
+        jax.monitoring.register_event_listener(on_event)
+        try:
+            with tracer.span("load_or_compile", args=args):
+                compiled = lowered.compile(
+                    compiler_options=cfg.parsed_compiler_options()
+                )
+                args["cache_hit"] = bool(hits)
+        finally:
+            jax.monitoring.unregister_event_listener(on_event)
+        return compiled
+
+    def _with_flops(compiled):
+        with tracer.span("cost_analysis"):
+            return compiled, hw.step_flops(compiled)
+
     def build_compiled(st: TrainState):
         """AOT-compile the train step (scan-epoch mode: the whole-epoch
         scan) against ``st``'s placed layout → ``(compiled_step,
@@ -964,13 +1013,15 @@ def _train_impl(
                 # the scan mode reuses the Lowered (cost analysis needs no
                 # backend compile) because XLA counts a scan body once
                 # regardless of trip count.
-                lowered_step = jax.jit(
-                    make_cached_train_step(
-                        mesh, _dtype(cfg.compute_dtype), remat=(cfg.remat == "full"),
-                        bad_step_skip=bad_step_skip,
+                lowered_step = _lower(
+                    "step",
+                    jax.jit(
+                        make_cached_train_step(
+                            mesh, _dtype(cfg.compute_dtype), remat=(cfg.remat == "full"),
+                            bad_step_skip=bad_step_skip,
+                        ),
+                        donate_argnums=(0,), out_shardings=(_state_shardings(st), None),
                     ),
-                    donate_argnums=(0,), out_shardings=(_state_shardings(st), None),
-                ).lower(
                     st, dataset, labels_all,
                     np.zeros((cache_batch,), np.int32), np.ones((cache_batch,), bool),
                 )
@@ -979,14 +1030,18 @@ def _train_impl(
                         mesh, _dtype(cfg.compute_dtype), remat=(cfg.remat == "full"),
                         bad_step_skip=bad_step_skip,
                     )
-                    compiled = jax.jit(
-                        epoch_fn, donate_argnums=(0,),
-                        out_shardings=(_state_shardings(st), None),
-                    ).lower(
-                        st, dataset, labels_all,
-                        np.zeros((n_steps, cache_batch), np.int32),
-                        np.ones((n_steps, cache_batch), bool),
-                    ).compile(compiler_options=cfg.parsed_compiler_options())
+                    compiled = _load_or_compile(
+                        _lower(
+                            "epoch",
+                            jax.jit(
+                                epoch_fn, donate_argnums=(0,),
+                                out_shardings=(_state_shardings(st), None),
+                            ),
+                            st, dataset, labels_all,
+                            np.zeros((n_steps, cache_batch), np.int32),
+                            np.ones((n_steps, cache_batch), bool),
+                        )
+                    )
                     # Per-step FLOPs for the scan mode, without compiling a
                     # throwaway per-step executable. Two wrinkles: (a)
                     # Lowered.cost_analysis() runs BEFORE SPMD partitioning,
@@ -996,8 +1051,9 @@ def _train_impl(
                     # or trip-count times is an XLA implementation detail
                     # (observed: once). Use the compiled scan's number,
                     # disambiguated against the lowered estimate.
-                    est = hw.step_flops(lowered_step) / max(1, jax.device_count())
-                    cand = hw.step_flops(compiled)
+                    with tracer.span("cost_analysis"):
+                        est = hw.step_flops(lowered_step) / max(1, jax.device_count())
+                        cand = hw.step_flops(compiled)
                     if cand > 0 and est > 0 and n_steps > 1:
                         flops = (
                             cand if abs(cand - est) <= abs(cand / n_steps - est)
@@ -1006,10 +1062,7 @@ def _train_impl(
                     else:
                         flops = cand if cand > 0 else est
                     return compiled, flops
-                compiled = lowered_step.compile(
-                    compiler_options=cfg.parsed_compiler_options()
-                )
-                return compiled, hw.step_flops(compiled)
+                return _with_flops(_load_or_compile(lowered_step))
             step_fn = (
                 make_spmd_train_step(
                     mesh, _dtype(cfg.compute_dtype), remat=(cfg.remat == "full"),
@@ -1018,10 +1071,13 @@ def _train_impl(
                     bad_step_skip=bad_step_skip,
                 )
                 if cfg.spmd_mode
-                else make_train_step(
-                    _dtype(cfg.compute_dtype), remat=(cfg.remat == "full"),
-                    accum_steps=cfg.accum_steps, mesh=mesh,
-                    bad_step_skip=bad_step_skip,
+                else jax.jit(
+                    make_train_step(
+                        _dtype(cfg.compute_dtype), remat=(cfg.remat == "full"),
+                        accum_steps=cfg.accum_steps, mesh=mesh,
+                        bad_step_skip=bad_step_skip,
+                    ),
+                    donate_argnums=(0,), out_shardings=(_state_shardings(st), None),
                 )
             )
             # The sample must match the loader's batch dtype exactly — the
@@ -1031,18 +1087,7 @@ def _train_impl(
                  np.zeros((host_batch,), np.int32)),
                 mesh,
             )
-            if cfg.spmd_mode:
-                compiled = step_fn.lower(st, sample).compile(
-                    compiler_options=cfg.parsed_compiler_options()
-                )
-            else:
-                compiled = jax.jit(
-                    step_fn, donate_argnums=(0,),
-                    out_shardings=(_state_shardings(st), None),
-                ).lower(st, sample).compile(
-                    compiler_options=cfg.parsed_compiler_options()
-                )
-            return compiled, hw.step_flops(compiled)
+            return _with_flops(_load_or_compile(_lower("step", step_fn, st, sample)))
         finally:
             tracer.end(span)
 
@@ -1197,9 +1242,39 @@ def _train_impl(
 
     # SURVEY §5 observability: step-level XLA traces, viewable in TensorBoard
     # (the reference only has MPI.Wtime wall-clock pairs, main.py:145,158).
-    profiling = bool(cfg.profile_dir)
-    if profiling:
-        jax.profiler.start_trace(cfg.profile_dir)
+    # --profile-dir traces STEADY STATE, so that benchmark/trace/ can reduce
+    # what an operator records: it starts once the first execution of the
+    # step program has been awaited (never the compile), stops two epoch
+    # boundaries later or at the end of the run, and records the host at
+    # the level the benchmark harness uses (this program's spans, nothing
+    # of Python's, none of the runtime's transfer threads).
+    profile_from_epoch = None  # epoch in which the profiler was started
+    profile_done = not cfg.profile_dir
+
+    def _profile_start(at_epoch: int, first_out) -> None:
+        """After the run's first dispatch of the step program: await it,
+        then start the trace. A no-op on every later call."""
+        nonlocal profile_from_epoch
+        if profile_done or profile_from_epoch is not None:
+            return
+        jax.block_until_ready(first_out)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(cfg.profile_dir, profiler_options=options)
+        profile_from_epoch = at_epoch
+
+    def _profile_stop(at_epoch: int | None = None) -> None:
+        """Stop a running trace once ``at_epoch`` is two epochs past its
+        start (None: the run is over)."""
+        nonlocal profile_done
+        if profile_done or profile_from_epoch is None:
+            return
+        if at_epoch is not None and at_epoch < profile_from_epoch + 2:
+            return
+        jax.profiler.stop_trace()
+        profile_done = True
+        logger.info("profiler trace written to %s", cfg.profile_dir)
 
     # The guard stays installed through the preemption save and the final
     # checkpoint drain below: a FIRST signal arriving mid-drain is absorbed
@@ -1428,7 +1503,13 @@ def _train_impl(
     with guard:
       try:
         while epoch < cfg.num_epochs:
+            # The epoch boundary, named piece by piece (epoch/control →
+            # epoch/prepare → step → epoch/wait → epoch/account →
+            # epoch/record), so that a device trace's idle time between two
+            # epochs falls under the host work that caused it.
+            control = tracer.begin("epoch/control")
             if _stop_agreed(watchdog.should_stop(epoch=epoch), mesh):
+                tracer.end(control, args={"epoch": epoch, "stop": True})
                 summary.preempted = True
                 logger.info(
                     "preemption signal: stopping before epoch %d "
@@ -1443,93 +1524,90 @@ def _train_impl(
             losses, counts = [], []
             loss_v = count_v = None  # [steps] device arrays, set below
             rollback_trigger = None  # (reason, step) breaking the step loop
-            if cfg.device_cache and cfg.scan_epoch:
-                # One dispatch for the whole epoch: stack the per-step index
-                # batches and let the compiled lax.scan run every step
-                # back-to-back on device. metrics come back as [n_steps]
-                # arrays — used as-is, never split into per-step scalars.
-                # (start_step_this is always 0 here: validate_cursor replays
-                # rather than reshaping the compiled scan.)
-                idx_steps = list(
-                    cached_index_batches(cfg, n_cache, cache_batch, epoch, n_steps)
-                )
-                if idx_steps:  # zero-step epochs (tiny shard + drop_remainder) no-op
-                    idx_all = np.stack([i for i, _ in idx_steps])
-                    valid_all = np.stack([v for _, v in idx_steps])
-                    with tracer.span("step", args={"epoch": epoch, "mode": "scan"}):
-                        state, m = compiled_step(state, dataset, labels_all, idx_all, valid_all)
-                        if telemetry_sync:
-                            jax.block_until_ready(m["loss"])
-                    loss_v, count_v = m["loss"], m["count"]
-                    skipped_before_epoch = steps_skipped_total
-                    if bad_step_skip and "skipped" in m:
-                        # Mask skipped steps out of the epoch accounting (a
-                        # discarded update contributes no samples, and its
-                        # observed NaN loss must not poison the mean), and
-                        # enforce the consecutive-skip budget post-hoc.
-                        skip_v = np.asarray(m["skipped"], np.int64)
-                        steps_skipped_total += int(skip_v.sum())
-                        if registry is not None and skip_v.sum():
-                            registry.counter("train/steps_skipped").inc(
-                                int(skip_v.sum())
-                            )
-                        keep = jnp.asarray(1 - skip_v)
-                        loss_v = jnp.where(keep == 1, loss_v, 0.0)
-                        count_v = count_v * keep.astype(count_v.dtype)
-                        # Seed from the previous epoch's trailing streak so
-                        # a run of skips spanning the epoch boundary still
-                        # trips the limit (the scan has no per-step host
-                        # boundary to count at).
-                        longest, run = 0, skip_streak
-                        for flag in skip_v:
-                            run = run + 1 if flag else 0
-                            longest = max(longest, run)
-                        skip_streak = run  # carries into the next epoch
-                        if longest >= cfg.max_skipped_steps:
-                            _abort_skip_limit(
-                                metrics, epoch, int(longest), cfg.max_skipped_steps
-                            )
-                    # Per-step records post-hoc from the [n_steps] arrays
-                    # (host timing is null — the scan never returns to the
-                    # host between steps); sentinel checks every step.
-                    health.on_scan_epoch(
-                        epoch, m, steps_skipped_base=skipped_before_epoch
+            tracer.end(control, args={"epoch": epoch})
+            scan_inputs = None  # (idx [steps, B], valid [steps, B]) of a scanned epoch
+            with tracer.span("epoch/prepare", args={"epoch": epoch}):
+                if cfg.device_cache and cfg.scan_epoch:
+                    # One dispatch for the whole epoch: stack the per-step
+                    # index batches and let the compiled lax.scan run every
+                    # step back-to-back on device. (start_step_this is always
+                    # 0 here: validate_cursor replays rather than reshaping
+                    # the compiled scan.)
+                    idx_steps = list(
+                        cached_index_batches(cfg, n_cache, cache_batch, epoch, n_steps)
                     )
-                    if cfg.log_every_steps:
-                        for step_i in range(
-                            cfg.log_every_steps - 1, int(loss_v.shape[0]), cfg.log_every_steps
-                        ):
-                            logger.info(
-                                "epoch %d step %d loss %.4f",
-                                epoch, step_i + 1, float(loss_v[step_i]),
-                            )
-                step_args = ()
-            elif cfg.device_cache:
-                # Same (seed, epoch) shuffle discipline as DataLoader.epoch, so
-                # cached and streaming runs see identical batch compositions.
-                step_args = (
-                    (dataset, labels_all, idx, valid)
-                    for idx, valid in cached_index_batches(
-                        cfg, n_cache, cache_batch, epoch, n_steps,
-                        start_step=start_step_this,
+                    if idx_steps:  # zero-step epochs (tiny shard + drop_remainder) no-op
+                        scan_inputs = (
+                            np.stack([i for i, _ in idx_steps]),
+                            np.stack([v for _, v in idx_steps]),
+                        )
+                    step_args = ()
+                elif cfg.device_cache:
+                    # Same (seed, epoch) shuffle discipline as DataLoader.epoch, so
+                    # cached and streaming runs see identical batch compositions.
+                    step_args = (
+                        (dataset, labels_all, idx, valid)
+                        for idx, valid in cached_index_batches(
+                            cfg, n_cache, cache_batch, epoch, n_steps,
+                            start_step=start_step_this,
+                        )
                     )
-                )
-            else:
-                # Tail batches (drop_remainder=False) are padded to the static
-                # shape with masked rows, so training keeps every image without
-                # triggering an XLA recompile; device_prefetch keeps the H2D
-                # copies a couple of steps ahead of compute.
-                batches = synchronized_batches(
-                    loader, epoch, n_steps, start_step=start_step_this
-                )
-                if faults.nonfinite_at_step:
-                    batches = faults.poison_batches(batches, epoch)
-                step_args = (
-                    (dev_batch,)
-                    for dev_batch in device_prefetch(
-                        batches, mesh, host_batch, cfg.prefetch_device_batches,
+                else:
+                    # Tail batches (drop_remainder=False) are padded to the static
+                    # shape with masked rows, so training keeps every image without
+                    # triggering an XLA recompile; device_prefetch keeps the H2D
+                    # copies a couple of steps ahead of compute. (Generators:
+                    # the loader's producer starts at the first ``ingest``.)
+                    batches = synchronized_batches(
+                        loader, epoch, n_steps, start_step=start_step_this
                     )
-                )
+                    if faults.nonfinite_at_step:
+                        batches = faults.poison_batches(batches, epoch)
+                    step_args = (
+                        (dev_batch,)
+                        for dev_batch in device_prefetch(
+                            batches, mesh, host_batch, cfg.prefetch_device_batches,
+                            epoch=epoch, start_step=start_step_this,
+                        )
+                    )
+            if scan_inputs is not None:
+                # metrics come back as [n_steps] arrays — used as-is, never
+                # split into per-step scalars.
+                idx_all, valid_all = scan_inputs
+                with tracer.span("step", args={"epoch": epoch, "mode": "scan"}):
+                    state, m = compiled_step(state, dataset, labels_all, idx_all, valid_all)
+                    if telemetry_sync:
+                        jax.block_until_ready(m["loss"])
+                _profile_start(epoch, m["loss"])
+                loss_v, count_v = m["loss"], m["count"]
+                skipped_before_epoch = steps_skipped_total
+                if bad_step_skip and "skipped" in m:
+                    # Mask skipped steps out of the epoch accounting (a
+                    # discarded update contributes no samples, and its
+                    # observed NaN loss must not poison the mean), and
+                    # enforce the consecutive-skip budget post-hoc.
+                    skip_v = np.asarray(m["skipped"], np.int64)
+                    steps_skipped_total += int(skip_v.sum())
+                    if registry is not None and skip_v.sum():
+                        registry.counter("train/steps_skipped").inc(
+                            int(skip_v.sum())
+                        )
+                    keep = jnp.asarray(1 - skip_v)
+                    loss_v = jnp.where(keep == 1, loss_v, 0.0)
+                    count_v = count_v * keep.astype(count_v.dtype)
+                    # Seed from the previous epoch's trailing streak so
+                    # a run of skips spanning the epoch boundary still
+                    # trips the limit (the scan has no per-step host
+                    # boundary to count at).
+                    longest, run = 0, skip_streak
+                    for flag in skip_v:
+                        run = run + 1 if flag else 0
+                        longest = max(longest, run)
+                    skip_streak = run  # carries into the next epoch
+                    if longest >= cfg.max_skipped_steps:
+                        _abort_skip_limit(
+                            metrics, epoch, int(longest), cfg.max_skipped_steps
+                        )
             stopped_mid_epoch = False
             step_iter = iter(step_args)
             step_i = start_step_this - 1
@@ -1538,12 +1616,11 @@ def _train_impl(
                 # decode + H2D dispatch not yet hidden by prefetch — the
                 # host-side half of the data-wait vs device-compute split
                 # the per-step records carry.
-                t_ingest = time.perf_counter()
-                with tracer.span("ingest"):
+                with tracer.span("ingest") as ingest:
                     args = next(step_iter, None)
                 if args is None:
                     break
-                data_wait_s = time.perf_counter() - t_ingest
+                data_wait_s = ingest.seconds
                 step_i += 1
                 # Single-process: stop promptly at a step boundary, dropping
                 # the partial epoch (its updates stay in `state` but aren't
@@ -1553,8 +1630,7 @@ def _train_impl(
                 if watchdog.should_stop(epoch=epoch, step=step_i) and jax.process_count() == 1:
                     stopped_mid_epoch = True
                     break
-                t_step = time.perf_counter()
-                with tracer.span("step", args={"epoch": epoch, "step": step_i}):
+                with tracer.span("step", args={"epoch": epoch, "step": step_i}) as stepped:
                     state, m = compiled_step(state, *args)
                     if telemetry_sync:
                         jax.block_until_ready(m["loss"])
@@ -1565,7 +1641,8 @@ def _train_impl(
                     # hierarchical steps — a flat mesh has no cross-pod
                     # phase to slow down.
                     faults.maybe_dcn_delay(_hier)
-                step_s = time.perf_counter() - t_step
+                step_s = stepped.seconds
+                _profile_start(epoch, m["loss"])
                 was_skipped = None
                 if bad_step_skip:
                     # The device already discarded the bad update; count the
@@ -1647,58 +1724,85 @@ def _train_impl(
                 )
                 break
             # Device sync so the timer measures compute, not dispatch.
-            jax.block_until_ready(state.params)
+            with tracer.span("epoch/wait", args={"epoch": epoch}):
+                jax.block_until_ready(state.params)
+            _profile_stop(epoch)
             dt = time.perf_counter() - t0
-            if losses:  # per-step paths collected python lists
-                loss_v = jnp.stack(losses)
-                count_v = jnp.stack(counts)
-            steps_run = int(loss_v.shape[0]) if loss_v is not None else 0
-            if steps_run:
-                # Per-sample accounting: weight each step's mean loss by its
-                # global valid-row count, so padded tail steps aren't over-weighted
-                # (matches the reference's per-sample loss bookkeeping) and
-                # throughput never counts padding rows. One device sync per epoch.
-                count_f = count_v.astype(jnp.float32)
-                n_valid = float(jnp.sum(count_f))
-                epoch_loss = (
-                    float(jnp.sum(loss_v * count_f) / n_valid) if n_valid else float("nan")
+            account_args = {"epoch": epoch}
+            with tracer.span("epoch/account", args=account_args):
+                # From the step metrics on the device to host floats: three
+                # tiny device programs and their read-back.
+                if losses:  # per-step paths collected python lists
+                    loss_v = jnp.stack(losses)
+                    count_v = jnp.stack(counts)
+                steps_run = int(loss_v.shape[0]) if loss_v is not None else 0
+                account_args["steps"] = steps_run
+                if steps_run:
+                    # Per-sample accounting: weight each step's mean loss by its
+                    # global valid-row count, so padded tail steps aren't over-weighted
+                    # (matches the reference's per-sample loss bookkeeping) and
+                    # throughput never counts padding rows. One device sync per epoch.
+                    count_f = count_v.astype(jnp.float32)
+                    n_valid = float(jnp.sum(count_f))
+                    epoch_loss = (
+                        float(jnp.sum(loss_v * count_f) / n_valid) if n_valid else float("nan")
+                    )
+                else:
+                    n_valid = 0.0
+                    epoch_loss = float("nan")
+            with tracer.span("epoch/record", args={"epoch": epoch}):
+                if scan_inputs is not None:
+                    # Per-step records post-hoc from the [n_steps] arrays
+                    # (host timing is null — the scan never returns to the
+                    # host between steps); sentinel checks every step.
+                    # Writing them is part of what a scanned epoch costs:
+                    # their time joins ``dt`` (the record's ``time_s``).
+                    t_steps = time.perf_counter()
+                    health.on_scan_epoch(
+                        epoch, m, steps_skipped_base=skipped_before_epoch
+                    )
+                    if cfg.log_every_steps:
+                        for logged_i in range(
+                            cfg.log_every_steps - 1, steps_run, cfg.log_every_steps
+                        ):
+                            logger.info(
+                                "epoch %d step %d loss %.4f",
+                                epoch, logged_i + 1, float(loss_v[logged_i]),
+                            )
+                    dt += time.perf_counter() - t_steps
+                total_images += int(n_valid)
+                ips = n_valid / dt if dt > 0 else 0.0
+                # cost_analysis() FLOPs are PER-DEVICE under SPMD partitioning.
+                per_chip_tflops = flops_per_step * steps_run / dt / 1e12 if dt > 0 else 0.0
+                tflops = per_chip_tflops * jax.device_count()
+                # mfu None (omitted) when either peak or FLOPs are unknown — a
+                # confident "0.0%" would be indistinguishable from a stalled chip.
+                mfu = 100.0 * per_chip_tflops / peak if (peak and flops_per_step > 0) else None
+                # ≙ reference epoch log line (main.py:158-160), plus throughput/MFU
+                logger.info(
+                    "Epoch: %d, Loss: %.6f, Time: %.2f s, %.1f img/s%s",
+                    epoch, epoch_loss, dt, ips,
+                    f", MFU {mfu:.1f}%" if mfu is not None else "",
                 )
-            else:
-                n_valid = 0.0
-                epoch_loss = float("nan")
-            total_images += int(n_valid)
-            ips = n_valid / dt if dt > 0 else 0.0
-            # cost_analysis() FLOPs are PER-DEVICE under SPMD partitioning.
-            per_chip_tflops = flops_per_step * steps_run / dt / 1e12 if dt > 0 else 0.0
-            tflops = per_chip_tflops * jax.device_count()
-            # mfu None (omitted) when either peak or FLOPs are unknown — a
-            # confident "0.0%" would be indistinguishable from a stalled chip.
-            mfu = 100.0 * per_chip_tflops / peak if (peak and flops_per_step > 0) else None
-            # ≙ reference epoch log line (main.py:158-160), plus throughput/MFU
-            logger.info(
-                "Epoch: %d, Loss: %.6f, Time: %.2f s, %.1f img/s%s",
-                epoch, epoch_loss, dt, ips,
-                f", MFU {mfu:.1f}%" if mfu is not None else "",
-            )
-            metrics.write(
-                {"kind": "epoch", "epoch": epoch, "loss": epoch_loss, "time_s": dt,
-                 "images_per_sec": ips, "tflops": tflops, "mfu_pct": mfu}
-            )
-            if registry is not None:
-                # The MFU-estimate / throughput gauges a fleet controller
-                # (ROADMAP item 1) reads live instead of tailing the stream.
-                # No monitor.evaluate here: rules are defined in per-step
-                # evaluation units (for=/warmup/rate deltas), and a second
-                # pass over the same last-step state would double-count a
-                # single breach; the next epoch's first step evaluates
-                # these gauges instead.
-                registry.gauge("train/images_per_sec").set(ips)
-                if mfu is not None:
-                    registry.gauge("train/mfu_pct").set(mfu)
-            if steps_run and n_valid:
-                # Free epoch-granularity sentinel (the loss is already a
-                # host float); zero-valid-row epochs are legitimately NaN.
-                health.check_epoch(epoch, epoch_loss)
+                metrics.write(
+                    {"kind": "epoch", "epoch": epoch, "loss": epoch_loss, "time_s": dt,
+                     "images_per_sec": ips, "tflops": tflops, "mfu_pct": mfu}
+                )
+                if registry is not None:
+                    # The MFU-estimate / throughput gauges a fleet controller
+                    # (ROADMAP item 1) reads live instead of tailing the stream.
+                    # No monitor.evaluate here: rules are defined in per-step
+                    # evaluation units (for=/warmup/rate deltas), and a second
+                    # pass over the same last-step state would double-count a
+                    # single breach; the next epoch's first step evaluates
+                    # these gauges instead.
+                    registry.gauge("train/images_per_sec").set(ips)
+                    if mfu is not None:
+                        registry.gauge("train/mfu_pct").set(mfu)
+                if steps_run and n_valid:
+                    # Free epoch-granularity sentinel (the loss is already a
+                    # host float); zero-valid-row epochs are legitimately NaN.
+                    health.check_epoch(epoch, epoch_loss)
             summary.epoch_times.append(dt)
             summary.epoch_losses.append(epoch_loss)
             summary.epochs_run += 1
@@ -1906,9 +2010,7 @@ def _train_impl(
       # loudly. Still under the guard: see the note at `with guard:` above.
       checkpointer.wait()
 
-    if profiling:
-        jax.profiler.stop_trace()
-        logger.info("profiler trace written to %s", cfg.profile_dir)
+    _profile_stop()  # the run ended inside the traced window
 
     wall = time.perf_counter() - train_t0
     summary.final_loss = epoch_loss

@@ -54,3 +54,32 @@ def bundles():
         )
         out[name] = (bundle, variables)
     return out
+
+
+@pytest.fixture
+def spans_of(tmp_path):
+    """``spans_of(run, epochs=0)``: events by name (sorted by start) of what
+    ``run()`` did with a tracer installed as the run's current one, once
+    ``epochs`` loader producers have closed their ``loader/epoch`` span (a
+    producer closes just after its sentinel is taken; the wait has a
+    deadline of its own)."""
+    import json
+    import time
+
+    from mpi_pytorch_tpu.obs import trace as obs_trace
+
+    def spans_of(run, epochs: int = 0) -> dict:
+        tracer = obs_trace.Tracer(str(tmp_path / "spans.json"))
+        with obs_trace.use(tracer):
+            run()
+        deadline = time.monotonic() + 10
+        while sum(e["name"] == "loader/epoch" for e in list(tracer._events)) < epochs:
+            assert time.monotonic() < deadline, "a producer never closed loader/epoch"
+            time.sleep(0.01)
+        by_name: dict = {}
+        with open(tracer.close()) as f:
+            for e in sorted(json.load(f)["traceEvents"], key=lambda e: e["ts"]):
+                by_name.setdefault(e["name"], []).append(e)
+        return by_name
+
+    return spans_of

@@ -461,3 +461,76 @@ def test_uint8_ingest_matches_host_normalize(tmp_path):
         np.testing.assert_array_equal(fl, ul)
         on_device = np.asarray(ingest_images(jnp.asarray(ui), jnp.float32))
         np.testing.assert_allclose(on_device, fi, rtol=0, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# where a streaming epoch's time goes: the loader's spans (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("source", ["synthetic", "pack"])
+def test_loader_decode_span_names_its_source(tmp_path, spans_of, source):
+    if source == "pack":
+        from mpi_pytorch_tpu.data.packed import write_pack
+
+        _, (manifest, _) = _jpeg_dataset(tmp_path, n=40)
+        packed_dir = str(tmp_path / "packed")
+        write_pack(manifest, (16, 16), f"{packed_dir}/train_16x16", num_workers=2)
+        loader = DataLoader(
+            manifest, batch_size=8, image_size=(16, 16), packed_dir=packed_dir, num_workers=2
+        )
+    else:
+        loader = DataLoader(
+            _tiny_manifest(), batch_size=8, image_size=(16, 16), synthetic=True, num_workers=2
+        )
+
+    def run():
+        assert len(list(loader.epoch(0))) == len(loader)
+
+    spans = spans_of(run, epochs=1)
+    assert len(spans["loader/decode"]) == len(loader)
+    for e in spans["loader/decode"]:
+        assert e["args"]["source"] == source and e["args"]["images"] == 8
+        assert 0 < e["args"]["thread_busy_s"] <= 2 * e["dur"] / 1e6 * 1.05
+    assert "loader/cast" not in spans  # float32 batches: nothing to convert
+    assert spans["loader/epoch"][0]["args"]["batches"] == len(loader)
+
+
+def test_untraced_loader_opens_no_span(tmp_path):
+    """Outside a traced run the current tracer is the inert one: the loader
+    records nothing and counts nothing (no clock read or lock per image, no
+    counter read per batch)."""
+    from mpi_pytorch_tpu.obs import trace as obs_trace
+
+    dl = DataLoader(_tiny_manifest(), batch_size=8, image_size=(8, 8), synthetic=True)
+    dl._decode_counters = None  # a call would raise
+    assert len(list(dl.epoch(0))) == 2
+    assert not obs_trace.current().enabled and obs_trace.current()._events == []
+    assert dl._py_busy_ns == 0
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_device_prefetch_h2d_span_per_batch(spans_of, depth):
+    """``h2d`` around pad + shard_batch of every batch, named by the epoch
+    and batch it carries (what joins a device trace to it), with the padded
+    batch's bytes."""
+    from mpi_pytorch_tpu.config import MeshConfig
+    from mpi_pytorch_tpu.parallel.mesh import create_mesh
+    from mpi_pytorch_tpu.train.trainer import device_prefetch
+
+    mesh = create_mesh(MeshConfig())
+    images = np.zeros((6, 8, 8, 3), np.float32)  # padded to the host batch of 8
+    labels = np.zeros((6,), np.int32)
+
+    def run():
+        out = list(
+            device_prefetch(
+                iter([(images, labels)] * 3), mesh, 8, depth, epoch=5, start_step=1
+            )
+        )
+        assert len(out) == 3 and out[0][0].shape == (8, 8, 8, 3)
+
+    spans = spans_of(run)["h2d"]
+    assert [e["args"] for e in spans] == [
+        {"epoch": 5, "batch": b, "bytes": 8 * 8 * 8 * 3 * 4 + 8 * 4} for b in (1, 2, 3)
+    ]

@@ -217,3 +217,91 @@ def test_library_name_is_keyed_on_the_source_hash(tmp_path, monkeypatch):
         edited.write_bytes(f.read() + b"\n// one more line\n")
     monkeypatch.setattr(native, "_SRC", str(edited))
     assert native._lib_name() != current
+
+
+# ---------------------------------------------------------------------------
+# the counter where the decode happens, and the loader's spans (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+
+def test_native_counter_counts_refusals_and_busy_time(tmp_path):
+    """Two process-wide atomics in decode.cpp: one refusal per image the C
+    decoder left to the fallback, none for a clean image, and the workers'
+    nanoseconds inside a decode positive and at most threads x the call's
+    wall time."""
+    import time
+
+    m = _jpeg_manifest(tmp_path, n=8)
+    paths = [os.path.join(m.img_dir, f) for f in m.filenames]
+    bad = tmp_path / "bad.jpg"
+    bad.write_bytes(b"this is not a jpeg")
+    before = native.counters()
+    t0 = time.perf_counter()
+    native.decode_batch(
+        paths + [str(bad)], (64, 64), MEAN, STD, threads=4,
+        fallback=lambda p: np.zeros((64, 64, 3), np.float32),
+    )
+    wall_ns = (time.perf_counter() - t0) * 1e9
+    refused, busy_ns = (a - b for a, b in zip(native.counters(), before))
+    assert refused == 1
+    assert 0 < busy_ns <= 4 * wall_ns
+
+
+def _traced_epoch(spans_of, loader):
+    batches = []
+    spans = spans_of(lambda: batches.extend(loader.epoch(0)), epochs=1)
+    return batches, spans
+
+
+@pytest.mark.parametrize(
+    "source,kw",
+    [
+        ("native", dict(native_decode=True, image_dtype="bfloat16")),
+        ("pil", dict(native_decode=False, image_dtype="bfloat16")),
+        ("pil", dict(native_decode=False, image_dtype="float32")),
+    ],
+)
+def test_loader_spans_per_batch_with_their_args(tmp_path, spans_of, source, kw):
+    """``loader/decode|cast|put|get`` per batch, ``loader/epoch`` around the
+    producer's life; the decode span's args say what was decoded, by whom,
+    and how busy the workers were."""
+    m = _jpeg_manifest(tmp_path, n=12)
+    loader = DataLoader(
+        m, batch_size=4, image_size=(64, 64), shuffle=False, num_workers=3, **kw
+    )
+    batches, spans = _traced_epoch(spans_of, loader)
+    assert len(batches) == 3
+    (life,) = spans["loader/epoch"]
+    assert life["args"] == {"epoch": 0, "batches": 3}
+    decodes = spans["loader/decode"]
+    assert len(decodes) == 3
+    for e in decodes:
+        args = e["args"]
+        assert args["images"] == 4 and args["source"] == source and args["threads"] == 3
+        assert args["fallbacks"] == 0 and args["quarantined"] == 0
+        assert 0 < args["thread_busy_s"] <= 3 * e["dur"] / 1e6 * 1.05
+        assert life["ts"] <= e["ts"] and e["ts"] + e["dur"] <= life["ts"] + life["dur"]
+    # The Python paths time themselves as decode.cpp does; the C path does
+    # not touch the Python counter.
+    assert (loader._py_busy_ns > 0) == (source == "pil")
+    # The cast runs only where the decode's float32 is not the batch dtype.
+    assert len(spans.get("loader/cast", [])) == (3 if kw["image_dtype"] == "bfloat16" else 0)
+    assert len(spans["loader/put"]) == 4  # three batches and the sentinel
+    assert len(spans["loader/get"]) == 4
+    # Producer and consumer run on two threads.
+    assert {e["tid"] for e in spans["loader/put"]} != {e["tid"] for e in spans["loader/get"]}
+
+
+def test_loader_decode_span_counts_fallbacks_and_quarantines(tmp_path, spans_of):
+    m = _jpeg_manifest(tmp_path, n=4)
+    with open(os.path.join(m.img_dir, m.filenames[1]), "wb") as f:
+        f.write(b"this is not a jpeg")
+    loader = DataLoader(
+        m, batch_size=4, image_size=(64, 64), shuffle=False, native_decode=True,
+        decode_retries=0,
+    )
+    (batch,), spans = _traced_epoch(spans_of, loader)
+    (decode,) = spans["loader/decode"]
+    # The C decoder refused one file; PIL could not read it either.
+    assert decode["args"]["fallbacks"] == 1 and decode["args"]["quarantined"] == 1
+    assert list(batch[1]).count(-1) == 1

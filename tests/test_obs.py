@@ -87,6 +87,62 @@ def test_trace_path_per_process_suffix():
     assert trace_path("run", 1, 2) == "run.p1.json"
 
 
+def test_tracer_writes_its_origin_down(tmp_path):
+    """``otherData`` holds the span clock's origin on ``perf_counter`` and on
+    the wall clock, read back to back: what lays the file on any other
+    timeline of the run (ISSUE 24 part C)."""
+    import time
+
+    before = (time.perf_counter(), time.time_ns())
+    tracer = Tracer(str(tmp_path / "t.json"))
+    after = (time.perf_counter(), time.time_ns())
+    with tracer.span("s"):
+        pass
+    other = json.load(open(tracer.close()))["otherData"]
+    assert before[0] <= other["t0_perf_counter_s"] <= after[0]
+    assert before[1] <= other["t0_unix_ns"] <= after[1]
+
+
+def test_tracer_end_returns_the_duration_it_measured(tmp_path):
+    ticks = iter([10.0, 10.5, 12.0])  # origin, begin, end
+
+    tracer = Tracer(str(tmp_path / "t.json"), clock=lambda: next(ticks))
+    token = tracer.begin("timed")
+    assert tracer.end(token, args={"k": 1}) == pytest.approx(1.5)
+    (event,) = json.load(open(tracer.close()))["traceEvents"]
+    assert event["ts"] == pytest.approx(0.5e6) and event["dur"] == pytest.approx(1.5e6)
+    # Disabled: nothing recorded, the same seconds — one clock pair a region
+    # on either path (the trainer's data_wait_ms / step_ms are span lengths).
+    ticks = iter([0.0, 1.0, 1.25, 2.0, 2.5])
+    off = Tracer("", clock=lambda: next(ticks))
+    assert off.end(off.begin("x")) == pytest.approx(0.25)
+    with off.span("y") as timed:
+        pass
+    assert timed.seconds == pytest.approx(0.5) and off._events == []
+
+
+def test_span_args_are_read_when_the_span_closes(tmp_path):
+    tracer = Tracer(str(tmp_path / "t.json"))
+    args = {"batch": 0}
+    with tracer.span("h2d", args=args):
+        args["bytes"] = 123
+    (event,) = json.load(open(tracer.close()))["traceEvents"]
+    assert event["args"] == {"batch": 0, "bytes": 123}
+
+
+def test_current_tracer_is_inert_until_a_driver_installs_one(tmp_path):
+    from mpi_pytorch_tpu.obs import trace as obs_trace
+
+    assert not obs_trace.current().enabled
+    tracer = Tracer(str(tmp_path / "t.json"))
+    with obs_trace.use(tracer):
+        assert obs_trace.current() is tracer
+        with obs_trace.current().span("below"):
+            pass
+    assert not obs_trace.current().enabled
+    assert [e["name"] for e in json.load(open(tracer.close()))["traceEvents"]] == ["below"]
+
+
 # ---------------------------------------------------------------------------
 # per-step health records + NaN sentinel
 # ---------------------------------------------------------------------------
@@ -396,11 +452,161 @@ def test_dryrun_telemetry_end_to_end(tmp_path, capsys):
         assert rec["recompiles"] == 0  # AOT step: no silent recompiles
     beats = [r for r in records if r["kind"] == "heartbeat"]
     assert beats and all(b["stragglers"] == [] for b in beats)
+    # One clock pair a region: a record's times ARE its spans' lengths.
+    spans = sorted(
+        (e for e in trace["traceEvents"] if e["name"] in ("ingest", "step")),
+        key=lambda e: e["ts"],
+    )
+    step_ms = [e["dur"] / 1e3 for e in spans if e["name"] == "step"]
+    assert [r["step_ms"] for r in steps] == pytest.approx(step_ms, abs=1e-3)
+    # (Every epoch ends on one more ``ingest``, the one that finds no batch.)
+    waits = [e["dur"] / 1e3 for e in spans if e["name"] == "ingest"]
+    assert [r["data_wait_ms"] for r in steps] == pytest.approx(
+        waits[0:2] + waits[3:5], abs=1e-3
+    )
 
     # The report tool renders it (exit 0) with the phase breakdown.
     assert report_run.main([cfg.metrics_file]) == 0
     out = capsys.readouterr().out
     assert "data-wait" in out and "grad norm" in out and "heartbeats" in out
+
+
+EPOCH_SPANS = ("epoch/control", "epoch/prepare", "epoch/wait", "epoch/account", "epoch/record")
+
+
+@pytest.mark.parametrize("path", ["stream", "cached", "scan"])
+def test_trainer_names_the_epoch_boundary_and_the_compile(tmp_path, path):
+    """``trainer.main``-level run with ``--trace-file``: each ``epoch/*`` span
+    once per epoch on the per-step paths and on the scanned one, the loader's
+    and ``h2d`` spans per batch where batches stream, and the compile span's
+    children inside it (ISSUE 24 part B)."""
+    from mpi_pytorch_tpu.train.trainer import train
+
+    kw = {
+        "stream": {},
+        "cached": {"device_cache": True},
+        "scan": {"device_cache": True, "scan_epoch": True},
+    }[path]
+    cfg = _telemetry_cfg(
+        str(tmp_path), step_metrics=False, heartbeat_every_steps=0,
+        checkpoint_every_epochs=0, **kw,
+    )
+    assert train(cfg).epochs_run == 2
+    data = json.load(open(cfg.trace_file))
+    assert set(data["otherData"]) == {"t0_perf_counter_s", "t0_unix_ns"}
+    events = [e for e in data["traceEvents"] if e["ph"] == "X"]
+
+    def named(name):
+        return sorted((e for e in events if e["name"] == name), key=lambda e: e["ts"])
+
+    for name in EPOCH_SPANS:
+        spans = [e for e in named(name) if not e["args"].get("stop")]
+        assert [e["args"]["epoch"] for e in spans] == [0, 1], name
+    assert [e["args"]["steps"] for e in named("epoch/account")] == [2, 2]
+    # In order inside an epoch, and none inside another.
+    first = [named(name)[0] for name in EPOCH_SPANS]
+    assert all(a["ts"] + a["dur"] <= b["ts"] for a, b in zip(first, first[1:]))
+    assert len(named("step")) == (2 if path == "scan" else 4)
+
+    (compile_span,) = named("compile")
+    programs = [e["args"]["program"] for e in named("lower")]
+    assert programs == {"stream": ["step"], "cached": ["step"], "scan": ["step", "epoch"]}[path]
+    (load,) = named("load_or_compile")
+    assert isinstance(load["args"]["cache_hit"], bool)
+    for child in named("lower") + [load] + named("cost_analysis"):
+        assert compile_span["ts"] <= child["ts"]
+        assert child["ts"] + child["dur"] <= compile_span["ts"] + compile_span["dur"]
+
+    if path == "stream":
+        batches = [(e["args"]["epoch"], e["args"]["batch"]) for e in named("h2d")]
+        assert batches == [(0, 0), (0, 1), (1, 0), (1, 1)]
+        assert all(e["args"]["bytes"] == 16 * 16 * 16 * 3 * 4 + 16 * 4 for e in named("h2d"))
+        assert len(named("loader/decode")) == 4 and len(named("loader/epoch")) == 2
+        assert all(e["args"]["source"] == "synthetic" for e in named("loader/decode"))
+        # h2d sits inside the trainer's ingest, which stays.
+        ingests = named("ingest")
+        for e in named("h2d"):
+            assert any(
+                i["ts"] <= e["ts"] and e["ts"] + e["dur"] <= i["ts"] + i["dur"] for i in ingests
+            )
+    else:
+        # The one pass of the loader is the device cache's build.
+        (build,), (walk,) = named("cache_build"), named("loader/epoch")
+        assert build["ts"] <= walk["ts"] <= build["ts"] + build["dur"]
+        assert not named("h2d")
+
+
+def test_epoch_time_covers_a_scanned_epochs_step_records(tmp_path, monkeypatch):
+    """``time_s`` of a scanned epoch's record (and the rate from it) holds
+    the top of the epoch to the end of ``epoch/wait`` plus the per-step
+    records ``epoch/record`` writes — a slow metrics sink shows in it."""
+    import time
+
+    from mpi_pytorch_tpu.obs.health import StepHealth
+    from mpi_pytorch_tpu.train.trainer import train
+
+    real = StepHealth.on_scan_epoch
+
+    def slow(self, *args, **kw):
+        time.sleep(0.2)
+        return real(self, *args, **kw)
+
+    monkeypatch.setattr(StepHealth, "on_scan_epoch", slow)
+    cfg = _telemetry_cfg(
+        str(tmp_path), heartbeat_every_steps=0, checkpoint_every_epochs=0,
+        device_cache=True, scan_epoch=True,
+    )
+    assert train(cfg).epochs_run == 2
+    events = json.load(open(cfg.trace_file))["traceEvents"]
+    records = [json.loads(line) for line in open(cfg.metrics_file)]
+    epochs = [r for r in records if r["kind"] == "epoch"]
+    assert len(epochs) == 2 and len([r for r in records if r["kind"] == "step"]) == 4
+
+    def span(name, epoch):
+        (e,) = [
+            e for e in events
+            if e["name"] == name and e.get("args", {}).get("epoch") == epoch
+        ]
+        return e["ts"] / 1e6, (e["ts"] + e["dur"]) / 1e6
+
+    for rec in epochs:
+        control, wait, record = (
+            span(name, rec["epoch"]) for name in ("epoch/control", "epoch/wait", "epoch/record")
+        )
+        assert wait[1] - control[1] + 0.2 <= rec["time_s"] <= record[1] - control[0]
+        assert rec["images_per_sec"] == pytest.approx(32 / rec["time_s"])
+
+
+@pytest.mark.parametrize("scan", [False, True])
+def test_profile_dir_traces_steady_state_only(tmp_path, scan):
+    """``--profile-dir`` starts once the first execution of the step program
+    has been awaited — never the compile — and stops two epoch boundaries
+    later, with the program's spans on the host plane: a trace
+    ``benchmark/trace/`` can read."""
+    import glob
+    from collections import Counter
+
+    from jax.profiler import ProfileData
+
+    from mpi_pytorch_tpu.train.trainer import train
+
+    cfg = _telemetry_cfg(
+        str(tmp_path), step_metrics=False, heartbeat_every_steps=0,
+        checkpoint_every_epochs=0, num_epochs=5, device_cache=scan, scan_epoch=scan,
+        profile_dir=str(tmp_path / "profile"),
+    )
+    assert train(cfg).epochs_run == 5
+    (path,) = glob.glob(os.path.join(cfg.profile_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    spans = {e["name"] for e in json.load(open(cfg.trace_file))["traceEvents"]}
+    seen = Counter(
+        event.name
+        for plane in ProfileData.from_file(path).planes if plane.name == "/host:CPU"
+        for line in plane.lines for event in line.events if event.name in spans
+    )
+    assert not {"build", "compile", "lower", "load_or_compile"} & set(seen)
+    # From inside epoch 0 to the awaited end of epoch 2: three waits, two
+    # whole boundaries, and not the epochs after them.
+    assert seen["epoch/wait"] == 3 and seen["epoch/record"] == 2 and seen["epoch/prepare"] == 2
 
 
 def test_poisoned_loss_aborts_cleanly(tmp_path):
