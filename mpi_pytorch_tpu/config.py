@@ -213,14 +213,16 @@ class Config:
     # devices and shards every attention call's sequence axis over it
     # (ops/ring_attention.py, ops/ulysses.py). vit models only.
     sp_strategy: str = "none"
-    # Dense-attention implementation for the vit_* family when sp_strategy
-    # is "none": "full" (vanilla, materializes [B,H,S,S] scores), "flash"
-    # (Pallas block-tiled online-softmax kernel for long sequences —
-    # ops/flash_attention.py), or "fused-small" (Pallas tiny-S kernel:
-    # scores+softmax+AV in one VMEM pass per (batch·head) group, the
-    # S≤128 regime where flash's block machinery loses —
-    # ops/fused_attention_small.py). All TPU-only with an identical-math
-    # fallback on other backends.
+    # Attention of the vit_* family when sp_strategy is "none". "full" is
+    # exact dense attention and needs no setting: on a TPU the single-pass
+    # Pallas kernel runs (scores and probabilities stay in VMEM) wherever a
+    # head's score tile fits it — S padded to the sublane tile <= 512, head
+    # dim <= 128, chosen from the operands' shape — and XLA's materialized
+    # [B,H,S,S] otherwise and on other backends
+    # (ops/fused_attention_small.py). "flash" is the block-tiled
+    # online-softmax kernel for long sequences (ops/flash_attention.py);
+    # "fused-small" names the single-pass kernel for A/B tools: that kernel
+    # or an error naming the shape. Identical math all three ways.
     attn_impl: str = "full"
     # Fuse the q/k/v projections into one [D, 3·H·Dh] matmul (vit family;
     # same param tree, exactly the same math — models/vit.py qkv_fused).

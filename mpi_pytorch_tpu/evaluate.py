@@ -95,10 +95,11 @@ def build_inference(cfg: Config, mesh=None, manifests=None):
         qkv_fused=cfg.qkv_fused,
         stem_s2d=cfg.stem_s2d,
         fused_stem=cfg.fused_stem,
-        # Multi-chip fused kernels: the model shard_maps the Mosaic calls
-        # (fused stem, fused-small attention) over the mesh's data axis
-        # (ops/fused_stem.py / ops/fused_attention_small.py, Multi-chip).
-        dp_mesh=mesh if (cfg.fused_stem or cfg.attn_impl == "fused-small") else None,
+        # Multi-chip kernels: the model shard_maps its Mosaic calls (fused
+        # stem, dense attention's single-pass kernel) over the mesh's data
+        # axis (ops/fused_stem.py / ops/fused_attention_small.py,
+        # Multi-chip); a model with neither ignores the mesh.
+        dp_mesh=mesh,
     )
     state = TrainState.create(
         apply_fn=bundle.model.apply,

@@ -174,11 +174,13 @@ def initialize_model(
                 "attention"
             )
         kw["attn_impl"] = attn_impl
-        if attn_impl == "fused-small" and dp_mesh is not None:
-            # Multi-chip: the attention module shard_maps its Mosaic call
-            # over this mesh's data axis (ops/fused_attention_small.py,
-            # Multi-chip) — the same contract as the fused stem below.
-            kw["dp_mesh"] = dp_mesh
+    if model_name in SP_MODELS and attn_impl != "flash" and dp_mesh is not None:
+        # Multi-chip: the attention module shard_maps its Mosaic call over
+        # this mesh's data axis (ops/fused_attention_small.py, Multi-chip) —
+        # the same contract as the fused stem below. 'full' needs it as
+        # 'fused-small' does: it takes the same kernel wherever the shape
+        # allows.
+        kw["dp_mesh"] = dp_mesh
     if qkv_fused:
         if model_name not in SP_MODELS:
             raise ValueError(

@@ -32,43 +32,49 @@ from mpi_pytorch_tpu.models.common import Dtype
 
 
 class _ProjParams(nn.Module):
-    """Parameter-only twin of ``nn.DenseGeneral((H, Dh))``: declares the
-    SAME variable tree (``<name>/kernel`` [in, H, Dh] lecun-normal,
-    ``<name>/bias`` [H, Dh] zeros — flax folds the init RNG by module
-    path, so even the initial values match), without computing anything.
-    Lets the fused-QKV path own the matmul while checkpoints remain
-    interchangeable with the three-DenseGeneral layout."""
+    """Parameter-only twin of an ``nn.DenseGeneral``: declares the SAME
+    variable tree (``<name>/kernel`` of ``kernel_shape``, lecun-normal over
+    its first ``n_in`` axes as fan-in; ``<name>/bias`` over the rest, zeros —
+    flax folds the init RNG by module path, so even the initial values
+    match), without computing anything. Lets a caller own the matmul (the
+    fused-QKV path; the rows path, whose matmuls leave ``[B, S, H·Dh]`` for
+    the attention kernel) while checkpoints remain interchangeable with the
+    DenseGeneral layout. ``(in, H, Dh)`` with ``n_in=1`` is
+    ``DenseGeneral((H, Dh))``; ``(H, Dh, out)`` with ``n_in=2`` is
+    ``DenseGeneral(out, axis=(-2, -1))``."""
 
-    features: tuple[int, int]
+    kernel_shape: tuple[int, ...]
+    n_in: int = 1
     param_dtype: Dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, in_features: int):
+    def __call__(self):
         import numpy as np
+
+        fan_in = int(np.prod(self.kernel_shape[: self.n_in]))
+        features = self.kernel_shape[self.n_in :]
 
         def kernel_init(rng, shape, dtype):
             # DenseGeneral initializes the kernel in FLATTENED 2-D form
-            # (fan-in = in_features, fan-out = prod(features)) and then
-            # reshapes — calling lecun-normal on the 3-D shape directly
+            # (fan-in = the contracted axes, fan-out = prod(features)) and
+            # then reshapes — calling lecun-normal on the full shape directly
             # would compute fan-in from the wrong axis.
             flat = nn.linear.default_kernel_init(
-                rng, (in_features, int(np.prod(self.features))), dtype
+                rng, (fan_in, int(np.prod(features))), dtype
             )
             return flat.reshape(shape)
 
-        kernel = self.param(
-            "kernel", kernel_init, (in_features,) + self.features, self.param_dtype
-        )
+        kernel = self.param("kernel", kernel_init, self.kernel_shape, self.param_dtype)
         bias = self.param(
-            "bias", nn.initializers.zeros_init(), self.features, self.param_dtype
+            "bias", nn.initializers.zeros_init(), features, self.param_dtype
         )
         return kernel, bias
 
 
 class MultiHeadAttention(nn.Module):
     """MHA whose core attention is pluggable: ``sp_strategy`` of ``none``
-    (single-device attention — vanilla ``full``, the Pallas ``flash``
-    kernel, or the Pallas ``fused-small`` tiny-S kernel, ``attn_impl``),
+    (single-device attention — exact dense ``full``, the Pallas ``flash``
+    kernel, or the single-pass kernel by name ``fused-small``, ``attn_impl``),
     ``ring``, or ``ulysses`` (both SP strategies shard the sequence over
     ``sp_mesh``'s first axis)."""
 
@@ -77,18 +83,18 @@ class MultiHeadAttention(nn.Module):
     param_dtype: Dtype = jnp.float32
     sp_strategy: str = "none"
     sp_mesh: Any = None
-    # "full" materializes [B,H,S,S] scores; "flash" streams k/v blocks
-    # through VMEM with an online softmax (ops/flash_attention.py — Pallas
-    # on TPU, identical-math fallback elsewhere); "fused-small" computes
-    # scores+softmax+AV in one VMEM pass per (batch·head) group — the
-    # tiny-S (S≤128) regime where flash's block machinery loses
-    # (ops/fused_attention_small.py). Same function all three ways.
+    # "full" is exact dense attention, executed by shape: on a TPU the
+    # single-pass kernel (scores and probabilities stay in VMEM) wherever a
+    # head's score tile fits it, XLA's materialized [B,H,S,S] otherwise and
+    # on other backends (ops/fused_attention_small.dense_attention);
+    # "fused-small" is that kernel or an error naming the shape; "flash"
+    # streams k/v blocks through VMEM with an online softmax
+    # (ops/flash_attention.py). Same function all three ways.
     attn_impl: str = "full"
-    # Multi-chip fused-small attention: mesh whose leading (data) axis the
-    # Mosaic call shard_maps over (ops/fused_attention_small.py,
-    # Multi-chip). None = single call (single chip, or an spmd-mode step
-    # whose shard_map already hands the kernel per-shard batches). Only
-    # consulted by attn_impl='fused-small'.
+    # Mesh whose leading (data) axis the single-pass kernel's Mosaic call
+    # shard_maps over (ops/fused_attention_small.py, Multi-chip). None =
+    # single call (single chip, or an spmd-mode step whose shard_map already
+    # hands the kernel per-shard batches). Read by 'full' and 'fused-small'.
     dp_mesh: Any = None
     # One [D, 3·H·Dh] projection matmul instead of three [D, H·Dh] ones:
     # x is read once, one MXU dispatch, same param tree (docs/RESULTS.md
@@ -96,37 +102,56 @@ class MultiHeadAttention(nn.Module):
     # each output column independently.
     qkv_fused: bool = False
 
+    def _dp_mesh(self):
+        # init traces one dummy image: nothing to split over the data axis
+        # (see models/common.FusedStemBNReluPool).
+        return None if self.is_initializing() else self.dp_mesh
+
     def _attend(self, q, k, v) -> jnp.ndarray:
         from mpi_pytorch_tpu.ops.flash_attention import flash_attention
         from mpi_pytorch_tpu.ops.fused_attention_small import (
+            dense_attention,
             fused_attention_small,
         )
-        from mpi_pytorch_tpu.ops.ring_attention import (
-            full_attention,
-            ring_self_attention,
-        )
+        from mpi_pytorch_tpu.ops.ring_attention import ring_self_attention
         from mpi_pytorch_tpu.ops.ulysses import ulysses_self_attention
 
         if self.sp_strategy == "none":
             if self.attn_impl == "flash":
                 return flash_attention(q, k, v)
             elif self.attn_impl == "fused-small":
-                # init traces one dummy image: nothing to split over the
-                # data axis (see models/common.FusedStemBNReluPool).
-                return fused_attention_small(
-                    q, k, v,
-                    dp_mesh=None if self.is_initializing() else self.dp_mesh,
-                )
+                return fused_attention_small(q, k, v, dp_mesh=self._dp_mesh())
             elif self.attn_impl == "full":
-                return full_attention(q, k, v)
+                return dense_attention(
+                    q, k, v, dp_mesh=self._dp_mesh(), num_heads=self.num_heads
+                )
             else:
                 raise ValueError(f"unknown attn_impl {self.attn_impl!r}")
         elif self.sp_strategy == "ring":
             return ring_self_attention(q, k, v, self.sp_mesh)
         elif self.sp_strategy == "ulysses":
             return ulysses_self_attention(q, k, v, self.sp_mesh)
-        else:
-            raise ValueError(f"unknown sp_strategy {self.sp_strategy!r}")
+        raise ValueError(f"unknown sp_strategy {self.sp_strategy!r}")
+
+    def _rows(self, x, head_dim: int) -> bool:
+        """Whether dense attention will take the single-pass kernel for this
+        input: the projections then stay plain ``[B, S, H·Dh]`` matmuls —
+        the layout the kernel blocks as it lies — and no ``[B, S, H, Dh]``
+        array (whose tiled layout costs a copy each way, 84 a step in
+        ViT-B/16: PERF.md section 6, PR 25) exists around it. Same
+        parameters, same products either way."""
+        from mpi_pytorch_tpu.ops.fused_attention_small import (
+            dense_attention_takes_kernel,
+        )
+
+        return (
+            self.sp_strategy == "none"
+            and self.attn_impl == "full"
+            and dense_attention_takes_kernel(
+                x.shape[0], x.shape[1], self.num_heads, head_dim, self.dtype,
+                self._dp_mesh(),
+            )
+        )
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -134,32 +159,40 @@ class MultiHeadAttention(nn.Module):
         if hidden % self.num_heads:
             raise ValueError(f"hidden {hidden} not divisible by {self.num_heads} heads")
         head_dim = hidden // self.num_heads
-        if self.qkv_fused:
-            shapes = (self.num_heads, head_dim)
-            wq, bq = _ProjParams(shapes, self.param_dtype, name="q")(hidden)
-            wk, bk = _ProjParams(shapes, self.param_dtype, name="k")(hidden)
-            wv, bv = _ProjParams(shapes, self.param_dtype, name="v")(hidden)
-            wqkv = jnp.concatenate(
-                [w.reshape(hidden, -1) for w in (wq, wk, wv)], axis=1
-            ).astype(self.dtype)
-            bqkv = jnp.concatenate(
-                [b.reshape(-1) for b in (bq, bk, bv)]
-            ).astype(self.dtype)
-            fused = x.astype(self.dtype) @ wqkv + bqkv  # [B, S, 3·H·Dh]
-            q, k, v = (
-                part.reshape(x.shape[:-1] + (self.num_heads, head_dim))
-                for part in jnp.split(fused, 3, axis=-1)
-            )
+        heads = (self.num_heads, head_dim)
+        rows = self._rows(x, head_dim)
+        if self.qkv_fused or rows:
+            ws, bs = zip(*(
+                (w.reshape(hidden, -1), b.reshape(-1))
+                for w, b in (
+                    _ProjParams((hidden,) + heads, 1, self.param_dtype, name=name)()
+                    for name in ("q", "k", "v")
+                )
+            ))
+            x = x.astype(self.dtype)
+            if self.qkv_fused:
+                wqkv = jnp.concatenate(ws, axis=1).astype(self.dtype)
+                fused = x @ wqkv + jnp.concatenate(bs).astype(self.dtype)
+                q, k, v = jnp.split(fused, 3, axis=-1)  # of [B, S, 3·H·Dh]
+            else:
+                q, k, v = (
+                    x @ w.astype(self.dtype) + b.astype(self.dtype)
+                    for w, b in zip(ws, bs)
+                )
+            if not rows:
+                q, k, v = (part.reshape(x.shape[:-1] + heads) for part in (q, k, v))
         else:
             proj = lambda name: nn.DenseGeneral(
-                (self.num_heads, head_dim), dtype=self.dtype,
-                param_dtype=self.param_dtype, name=name,
+                heads, dtype=self.dtype, param_dtype=self.param_dtype, name=name,
             )
             q, k, v = proj("q")(x), proj("k")(x), proj("v")(x)
         # The dispatch alone is the ``attention`` scope (its backward shows as
         # ``transpose(jvp(…attention))``); the projections stay outside it.
         with jax.named_scope("attention"):
             out = self._attend(q, k, v)
+        if rows:
+            w, b = _ProjParams(heads + (hidden,), 2, self.param_dtype, name="out")()
+            return out @ w.reshape(-1, hidden).astype(self.dtype) + b.astype(self.dtype)
         return nn.DenseGeneral(
             hidden, axis=(-2, -1), dtype=self.dtype,
             param_dtype=self.param_dtype, name="out",
@@ -253,7 +286,7 @@ class EncoderBlock(nn.Module):
     sp_strategy: str = "none"
     sp_mesh: Any = None
     attn_impl: str = "full"
-    dp_mesh: Any = None  # fused-small attention's shard_map mesh (see MHA)
+    dp_mesh: Any = None  # the attention kernel's shard_map mesh (see MHA)
     qkv_fused: bool = False
     num_experts: int = 0
     moe_k: int = 2
@@ -314,7 +347,7 @@ class VisionTransformer(nn.Module):
     sp_strategy: str = "none"
     sp_mesh: Any = None
     attn_impl: str = "full"
-    dp_mesh: Any = None  # fused-small attention's shard_map mesh (see MHA)
+    dp_mesh: Any = None  # the attention kernel's shard_map mesh (see MHA)
     qkv_fused: bool = False
     # MoE: every `moe_every`-th block (0-indexed blocks moe_every-1,
     # 2·moe_every-1, ...; =2 → the odd blocks) swaps its dense MLP for a

@@ -90,6 +90,7 @@ class Tracer:
         self._t0 = clock()
         self._t0_unix_ns = time.time_ns()
         self._events: list[dict] = []
+        self._once: set[tuple] = set()  # instants already written with once=True
         self._lock = threading.Lock()
         self._pid: int | None = None
         self._closed = False
@@ -159,10 +160,20 @@ class Tracer:
         finally:
             timed.seconds = self.end(token, args)
 
-    def instant(self, name: str, args: Mapping[str, Any] | None = None) -> None:
-        """A zero-duration marker (anomalies, heartbeats) on the timeline."""
+    def instant(
+        self, name: str, args: Mapping[str, Any] | None = None, *, once: bool = False
+    ) -> None:
+        """A zero-duration marker (anomalies, heartbeats) on the timeline.
+        ``once``: a marker with this name and these args is written the first
+        time only (a decision made at every trace of one shape)."""
         if not self.enabled:
             return
+        if once:
+            key = (name, tuple(sorted((args or {}).items())))
+            with self._lock:
+                if key in self._once:
+                    return
+                self._once.add(key)
         event = {
             "name": name,
             "cat": "marker",

@@ -245,14 +245,15 @@ def build_training(cfg: Config, mesh=None):
         qkv_fused=cfg.qkv_fused,
         stem_s2d=cfg.stem_s2d,
         fused_stem=cfg.fused_stem,
-        # Multi-chip fused kernels: the model shard_maps the Mosaic calls
-        # (fused stem, fused-small attention) over the mesh's data axis
-        # (ops/fused_stem.py / ops/fused_attention_small.py, Multi-chip).
-        # Threaded in spmd mode too: inside the spmd step's shard_map the
-        # wrappers detect the bound axis and run the per-shard call
-        # directly, while spmd-mode VALIDATION (plain-jit eval over the
-        # same model) still gets the partitioned call.
-        dp_mesh=mesh if (cfg.fused_stem or cfg.attn_impl == "fused-small") else None,
+        # Multi-chip kernels: the model shard_maps its Mosaic calls (fused
+        # stem, dense attention's single-pass kernel) over the mesh's data
+        # axis (ops/fused_stem.py / ops/fused_attention_small.py,
+        # Multi-chip); a model with neither ignores the mesh. Threaded in
+        # spmd mode too: inside the spmd step's shard_map the wrappers
+        # detect the bound axis and run the per-shard call directly, while
+        # spmd-mode VALIDATION (plain-jit eval over the same model) still
+        # gets the partitioned call.
+        dp_mesh=mesh,
     )
     # Total optimizer steps for cosine-style schedules: the globally-computed
     # per-epoch step count (identical on every host) x epochs.

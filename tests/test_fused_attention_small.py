@@ -1,9 +1,11 @@
-"""Fused tiny-S attention (Pallas, interpret mode on CPU) vs the plain
-``full_attention`` reference — values, grads, bf16, padded sequences, the
-bh-grouping lever, the multi-chip shard_map path, and the spmd (bound-axis)
-path. The kernel computes the SAME function as full attention, so every
-check is an exact-to-tolerance comparison (docs/RESULTS.md §4: the staged
-vit_s16 candidate)."""
+"""The single-pass dense attention kernels (Pallas, interpret mode on CPU) vs
+the plain ``full_attention`` reference — values, grads, bf16, S=64 (vit_s16)
+and S=196 (ViT-B/16), both operand layouts (``rows``: the projections'
+``[B, S, H·Dh]`` as it lies, the path; ``grouped``: the ``bh_block`` lever),
+head geometries, the shape dispatch under ``attn_impl="full"``
+(``dense_attention``), the multi-chip shard_map path, and the spmd
+(bound-axis) path. The kernels compute the SAME function as full attention,
+so every check is an exact-to-tolerance comparison."""
 
 import functools
 
@@ -13,8 +15,10 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from mpi_pytorch_tpu.obs import trace as obs_trace
 from mpi_pytorch_tpu.ops.fused_attention_small import (
     _bh_block,
+    dense_attention,
     fused_attention_small,
 )
 from mpi_pytorch_tpu.ops.ring_attention import full_attention
@@ -22,51 +26,97 @@ from mpi_pytorch_tpu.ops.ring_attention import full_attention
 B, S, H, D = 2, 64, 2, 64  # the vit_s16 attention geometry (S=64, Dh=64)
 
 
-def _qkv(seed, b=B, s=S, d=D, dtype=jnp.float32):
+def _qkv(seed, b=B, s=S, h=H, d=D, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
-    mk = lambda: jnp.asarray(rng.standard_normal((b, s, H, d)), dtype)
+    mk = lambda: jnp.asarray(rng.standard_normal((b, s, h, d)), dtype)
     return mk(), mk(), mk()
 
 
-@pytest.mark.parametrize("s", [64, 65, 50, 128])
-def test_values_match_full_attention(s):
-    """S=64 (the vit_s16 regime), odd S=65 (class-token variant — padded
-    rows + a different bh-grouping), padded S=50, and the envelope edge
-    S=128."""
+def _mesh():
+    return Mesh(np.array(jax.devices()).reshape(-1, 1), ("data", "model"))
+
+
+# The two operand layouts: the rows layout is what a plain call takes; an
+# explicit bh_block asks for the grouped one (S_pad <= 128 only).
+LAYOUT = {"rows": {}, "grouped": {"bh_block": 2}}
+
+
+def _all_grads(fn, q, k, v):
+    f = lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_).astype(jnp.float32) ** 2)
+    return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("s,layout", [
+    (64, "rows"), (65, "rows"), (50, "rows"), (128, "rows"), (196, "rows"),
+    (64, "grouped"), (65, "grouped"), (50, "grouped"), (128, "grouped"),
+])
+def test_values_match_full_attention(s, layout):
+    """S=64 (the vit_s16 regime), odd S=65 (class-token variant), S=50 (off
+    the sublane tile: whole-S blocks in the rows layout, padded rows in the
+    grouped one), S=128 (the grouped layout's edge) and S=196 (ViT-B/16 at
+    224 px)."""
     q, k, v = _qkv(0, s=s)
-    got = fused_attention_small(q, k, v, interpret=True)
+    got = fused_attention_small(q, k, v, interpret=True, **LAYOUT[layout])
     want = full_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("s", [64, 50])
-def test_grads_match_full_attention(s):
+@pytest.mark.parametrize("s,layout", [
+    (64, "rows"), (50, "rows"), (196, "rows"), (64, "grouped"), (50, "grouped"),
+])
+def test_grads_match_full_attention(s, layout):
     q, k, v = _qkv(1, s=s)
-
-    def grads(fn):
-        f = lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_).astype(jnp.float32) ** 2)
-        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-
-    g_fused = grads(lambda *a: fused_attention_small(*a, interpret=True))
-    g_full = grads(full_attention)
+    g_fused = _all_grads(
+        lambda *a: fused_attention_small(*a, interpret=True, **LAYOUT[layout]),
+        q, k, v,
+    )
+    g_full = _all_grads(full_attention, q, k, v)
     for a, b in zip(g_fused, g_full):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-5, atol=5e-5)
         assert np.isfinite(np.asarray(a)).all()
 
 
-def test_causal_matches_full_attention():
-    q, k, v = _qkv(2)
-    got = fused_attention_small(q, k, v, causal=True, interpret=True)
-    want = full_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+@pytest.mark.parametrize("s,layout", [(64, "rows"), (196, "rows"), (64, "grouped")])
+def test_causal_matches_full_attention(s, layout):
+    """Values and all three gradients under the causal mask."""
+    q, k, v = _qkv(2, s=s)
+    fused = lambda *a: fused_attention_small(
+        *a, causal=True, interpret=True, **LAYOUT[layout]
+    )
+    full = lambda *a: full_attention(*a, causal=True)
+    np.testing.assert_allclose(np.asarray(fused(q, k, v)),
+                               np.asarray(full(q, k, v)), rtol=2e-5, atol=2e-5)
+    for a, b in zip(_all_grads(fused, q, k, v), _all_grads(full, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("h,d", [(1, 128), (4, 32), (2, 48), (2, 8)])
+def test_head_geometries_match_full_attention(h, d):
+    """The rows layout tells heads apart by lane masks inside 128-lane groups
+    (one head of 128: no mask; four of 32), and inside one group of all
+    H·Dh lanes in a model narrower than a tile (2x48 = 96 lanes, 2x8 = 16):
+    values and gradients either way."""
+    q, k, v = _qkv(12, s=24, h=h, d=d)
+    fused = lambda *a: fused_attention_small(*a, interpret=True)
+    np.testing.assert_allclose(np.asarray(fused(q, k, v)),
+                               np.asarray(full_attention(q, k, v)),
                                rtol=2e-5, atol=2e-5)
+    for a, b in zip(_all_grads(fused, q, k, v),
+                    _all_grads(full_attention, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-5, atol=5e-5)
 
 
-def test_bf16_values_and_grads():
-    q, k, v = _qkv(3, dtype=jnp.bfloat16)
-    got = fused_attention_small(q, k, v, interpret=True)
+@pytest.mark.parametrize("s,layout", [(64, "rows"), (196, "rows"), (64, "grouped")])
+def test_bf16_values_and_grads(s, layout):
+    q, k, v = _qkv(3, s=s, dtype=jnp.bfloat16)
+    fused_attention = functools.partial(
+        fused_attention_small, interpret=True, **LAYOUT[layout]
+    )
+    got = fused_attention(q, k, v)
     want = full_attention(q, k, v)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(
@@ -78,7 +128,7 @@ def test_bf16_values_and_grads():
         f = lambda q_: jnp.sum(fn(q_, k, v).astype(jnp.float32) ** 2)
         return jax.grad(f)(q)
 
-    g_fused = grads(lambda *a: fused_attention_small(*a, interpret=True))
+    g_fused = grads(fused_attention)
     g_full = grads(full_attention)
     np.testing.assert_allclose(
         np.asarray(g_fused, np.float32), np.asarray(g_full, np.float32),
@@ -128,44 +178,115 @@ def test_bh_block_env_gate(monkeypatch):
 
 def test_cpu_fallback_and_envelope():
     """interpret=None off-TPU routes to full_attention exactly; so does a
-    sequence outside the tiny-S envelope (S > 128) even with interpret."""
+    sequence outside the envelope (S_pad > 512) even with interpret."""
     q, k, v = _qkv(6)
     np.testing.assert_array_equal(
         np.asarray(fused_attention_small(q, k, v)),
         np.asarray(full_attention(q, k, v)),
     )
-    q, k, v = _qkv(6, s=196)  # vit at 224px — flash/full own this regime
+    q, k, v = _qkv(6, b=1, s=520)  # a 520x520 float32 score tile is over 1 MB
     np.testing.assert_array_equal(
         np.asarray(fused_attention_small(q, k, v, interpret=True)),
         np.asarray(full_attention(q, k, v)),
     )
 
 
-def test_vit_fused_small_matches_full_through_model(monkeypatch):
-    """A whole ViT forward with attn_impl='fused-small' — routed through the
-    REAL Pallas kernel via MPT_ATTN_INTERPRET — equals attn_impl='full' on
-    the same params: the trainer flag changes execution, never the
-    function."""
+def _dispatch_instants(tmp_path, call):
+    """The ``attn/dispatch`` instants ``call`` leaves in a run's trace file."""
+    import json
+
+    tracer = obs_trace.Tracer(str(tmp_path / "trace.json"))
+    with obs_trace.use(tracer):
+        call()
+    with open(tracer.close()) as f:
+        events = json.load(f)["traceEvents"]
+    return [e["args"] for e in events if e["name"] == "attn/dispatch"]
+
+
+@pytest.mark.parametrize("shape,kernel_env,mesh_axis,want", [
+    # inside the envelope: the kernel, at ViT-B/16's S=196 and vit_s16's S=64
+    ((2, 196, 2, 64), True, 0, {"path": "kernel"}),
+    ((2, 64, 2, 64), True, 0, {"path": "kernel"}),
+    ((1, 512, 1, 128), True, 0, {"path": "kernel"}),  # the envelope's corner
+    ((16, 64, 2, 64), True, 8, {"path": "kernel"}),  # 2 images a device
+    # outside it: XLA's full_attention, and the instant says why
+    ((1, 1024, 2, 64), True, 0, {"path": "xla", "why": "outside_envelope"}),
+    ((1, 513, 2, 64), True, 0, {"path": "xla", "why": "outside_envelope"}),
+    ((2, 16, 2, 256), True, 0, {"path": "xla", "why": "outside_envelope"}),
+    # heads that straddle 128-lane tiles (3x64 = 192 lanes, 16x80 = 1 280)
+    ((2, 24, 3, 64), True, 0, {"path": "xla", "why": "outside_envelope"}),
+    ((2, 24, 16, 80), True, 0, {"path": "xla", "why": "outside_envelope"}),
+    ((2, 196, 2, 64), False, 0, {"path": "xla", "why": "backend"}),
+    ((9, 64, 2, 64), True, 8, {"path": "xla", "why": "batch_not_divisible"}),
+])
+def test_dense_attention_dispatches_by_shape(
+    tmp_path, monkeypatch, shape, kernel_env, mesh_axis, want
+):
+    """What ``attn_impl="full"`` executes is chosen from the operands' shape
+    (and the backend): one ``attn/dispatch`` instant per distinct choice says
+    which path a shape took and why, and the XLA path is ``full_attention``
+    bit for bit."""
+    if kernel_env:  # stands in for the TPU backend: the interpreted kernel
+        monkeypatch.setenv("MPT_ATTN_INTERPRET", "1")
+    b, s, h, d = shape
+    q, k, v = _qkv(13, b=b, s=s, h=h, d=d)
+    mesh = _mesh() if mesh_axis else None
+    call = lambda: dense_attention(q, k, v, dp_mesh=mesh)
+    got = []
+    instants = _dispatch_instants(
+        tmp_path, lambda: got.extend([call(), call()])  # twice: one instant
+    )
+    assert instants == [{**want, "S": s, "Dh": d, "batch": b}]
+    check = np.testing.assert_array_equal if want["path"] == "xla" else (
+        functools.partial(np.testing.assert_allclose, rtol=2e-5, atol=2e-5)
+    )
+    check(np.asarray(got[0]), np.asarray(full_attention(q, k, v)))
+
+
+def test_dense_attention_grads_match_full_attention(monkeypatch):
+    """The dispatch is differentiable through the kernel path (S=196, causal
+    too) exactly as ``full_attention`` is."""
+    monkeypatch.setenv("MPT_ATTN_INTERPRET", "1")
+    q, k, v = _qkv(14, s=196)
+    for causal in (False, True):
+        dense = functools.partial(dense_attention, causal=causal)
+        full = functools.partial(full_attention, causal=causal)
+        for a, b in zip(_all_grads(dense, q, k, v), _all_grads(full, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=5e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("attn_impl,qkv_fused", [
+    ("fused-small", False), ("full", False), ("full", True),
+])
+def test_vit_fused_small_matches_full_through_model(monkeypatch, attn_impl, qkv_fused):
+    """A whole ViT forward and its parameter gradients through the REAL
+    Pallas kernel (via MPT_ATTN_INTERPRET) — asked for by name
+    (``fused-small``) or taken by ``full``'s shape dispatch, whose module then
+    keeps q, k, v and the output [B, S, H·Dh] (fused QKV or three matmuls) —
+    equal plain XLA attention on the same params: the flag and the dispatch
+    change execution, never the function."""
     from mpi_pytorch_tpu.models.vit import VisionTransformer
 
     kw = dict(num_classes=7, patch_size=4, hidden=16, depth=2, num_heads=2,
               mlp_dim=32, dtype=jnp.float32, param_dtype=jnp.float32)
-    full = VisionTransformer(**kw)
-    fused = VisionTransformer(attn_impl="fused-small", **kw)
+    plain = VisionTransformer(**kw)
+    kernel = VisionTransformer(attn_impl=attn_impl, qkv_fused=qkv_fused, **kw)
     x = jnp.asarray(
         np.random.default_rng(7).standard_normal((2, 16, 16, 3)), jnp.float32
     )
-    variables = full.init({"params": jax.random.PRNGKey(0)}, x, train=False)
+    variables = plain.init({"params": jax.random.PRNGKey(0)}, x, train=False)
+    loss = lambda model: lambda v: jnp.sum(model.apply(v, x, train=False) ** 2)
+    want, want_grads = plain.apply(variables, x, train=False), jax.grad(loss(plain))(variables)
 
     monkeypatch.setenv("MPT_ATTN_INTERPRET", "1")
-    got = fused.apply(variables, x, train=False)
-    want = full.apply(variables, x, train=False)
+    got = kernel.apply(variables, x, train=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-
-
-def _mesh():
-    return Mesh(np.array(jax.devices()).reshape(-1, 1), ("data", "model"))
+    for a, b in zip(jax.tree.leaves(jax.grad(loss(kernel))(variables)),
+                    jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("dtype,s", [

@@ -67,6 +67,7 @@ def test_untileable_shapes_raise_on_a_tpu_and_give_way_only_off_it(monkeypatch):
     rows = chip_kernels.check_raises()
     assert [r["status"] for r in rows] == ["raises"] * len(rows), rows
     assert "(8, 4, 4, 60)" in rows[0]["error"]  # the message names the shape
+    assert "(2, 1024, 6, 64)" in rows[1]["error"]
 
 
 def test_model_init_does_not_partition_the_kernels(monkeypatch):
@@ -89,7 +90,79 @@ def test_model_init_does_not_partition_the_kernels(monkeypatch):
         "resnet18", 10, rng=jax.random.PRNGKey(0), image_size=32,
         dtype=jnp.float32, fused_stem=True, dp_mesh=mesh,
     )
-    create_model_bundle(
-        "vit_s16", 10, rng=jax.random.PRNGKey(0), image_size=32,
-        dtype=jnp.float32, attn_impl="fused-small", dp_mesh=mesh,
-    )
+    for attn_impl in ("fused-small", "full"):  # 'full' takes the same kernel
+        create_model_bundle(
+            "vit_s16", 10, rng=jax.random.PRNGKey(0), image_size=32,
+            dtype=jnp.float32, attn_impl=attn_impl, dp_mesh=mesh,
+        )
+
+
+@pytest.mark.parametrize("shape,tpu,calls", [
+    ((128, 196, 12, 64), True, 2),  # ViT-B/16 at 224 px, the benchmark's batch
+    ((256, 64, 6, 64), True, 2),  # vit_s16 at 128 px
+    ((2, 1024, 12, 64), True, 0),  # vit_b16-hires: outside the envelope
+    ((128, 196, 12, 64), False, 0),  # any shape off a TPU
+])
+def test_dense_attention_lowers_to_the_kernel_by_shape(monkeypatch, shape, tpu, calls):
+    """``attn_impl="full"`` (``dense_attention``): inside the envelope on a
+    TPU the program carries the kernel pair, forward and backward; outside
+    it, and on any other backend, it is ``full_attention``'s program to the
+    letter."""
+    import jax.numpy as jnp
+
+    from mpi_pytorch_tpu.ops.fused_attention_small import dense_attention
+    from mpi_pytorch_tpu.ops.ring_attention import full_attention
+    from mpi_pytorch_tpu.utils import hardware
+
+    monkeypatch.setattr(hardware, "tpu_backend", lambda: tpu)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def lowered(attend):
+        def pair(q, k, v, do):
+            out, vjp = jax.vjp(attend, q, k, v)
+            return out, vjp(do)
+
+        return jax.jit(pair).trace(x, x, x, x).lower(lowering_platforms=("tpu",))
+
+    got = lowered(lambda q, k, v: dense_attention(q, k, v))
+    assert hardware.mosaic_call_count(got) == calls
+    if not calls:
+        assert got.as_text() == lowered(lambda q, k, v: full_attention(q, k, v)).as_text()
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """One chip of a described (not attached) ``v5e:2x2``: the TPU's compiler
+    is installed here and compiles for it. Made inside the fixture, never at
+    import: only the worker that runs this file may load the TPU's library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps the compiler away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("shape", [(128, 196, 12, 64), (256, 64, 6, 64)],
+                         ids=["vit_b16", "vit_s16"])
+def test_attention_kernels_compile_for_v5e(one_v5e, shape):
+    """What lowering cannot see: Mosaic ACCEPTS the single-pass pair at the
+    models' real shapes (whole-S blocks of 196 rows, 128-lane slices, the
+    transposed-LHS dk/dv matmuls). Nothing runs; no time comes out of this."""
+    import jax.numpy as jnp
+
+    from mpi_pytorch_tpu.ops.fused_attention_small import fused_attention_small
+    from mpi_pytorch_tpu.utils.hardware import mosaic_call_count
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_v5e)
+
+    def pair(q, k, v, do):
+        out, vjp = jax.vjp(
+            lambda *a: fused_attention_small(*a, interpret=False), q, k, v
+        )
+        return out, vjp(do)
+
+    compiled = jax.jit(pair).lower(x, x, x, x).compile()
+    assert mosaic_call_count(compiled) == 2

@@ -221,3 +221,48 @@ def test_qkv_fused_parity():
     g2 = jax.grad(lambda p: jnp.sum(vf.apply(p, x, train=False) ** 2))(p1)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
+def test_vit_b16_full_takes_the_kernel_and_agrees_with_full_attention(monkeypatch):
+    """``attn_impl="full"`` through ``vit_b16`` at 224 px (196 tokens, 12
+    heads of 64 — inside the single-pass kernel's envelope, so the dispatch
+    takes it, interpreted here) computes what XLA's ``full_attention`` does:
+    logits and every parameter's gradient. Two blocks: the attention shapes
+    are the model's, the depth is the test's."""
+    from mpi_pytorch_tpu.models.vit import vit_b16
+    from mpi_pytorch_tpu.obs import trace as obs_trace
+
+    model = vit_b16(10, depth=2)
+    assert model.attn_impl == "full"
+    x = jnp.asarray(
+        np.random.default_rng(3).standard_normal((2, 224, 224, 3)), jnp.float32
+    )
+    variables = model.init({"params": jax.random.PRNGKey(0)}, x[:1], train=False)
+
+    def logits_and_grads():
+        loss = lambda v: jnp.sum(model.apply(v, x, train=False) ** 2)
+        return model.apply(variables, x, train=False), jax.grad(loss)(variables)
+
+    want, want_grads = logits_and_grads()  # the CPU backend: full_attention
+    monkeypatch.setenv("MPT_ATTN_INTERPRET", "1")
+    # The kernel's path keeps q, k, v and the output [B, S, H·Dh] (plain
+    # matmuls over the SAME parameters): one tree, one initialization.
+    again = model.init({"params": jax.random.PRNGKey(0)}, x[:1], train=False)
+    assert jax.tree.structure(again) == jax.tree.structure(variables)
+    for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(variables)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    tracer = obs_trace.Tracer("unwritten.json")
+    with obs_trace.use(tracer):
+        got, got_grads = logits_and_grads()
+    assert [e["args"] for e in tracer._events if e["name"] == "attn/dispatch"] == [
+        {"path": "kernel", "S": 196, "Dh": 64, "batch": 2}
+    ]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+    # Per parameter, against its own length — but no shorter than a thousandth
+    # of the tree's: the key bias's gradient is zero in exact arithmetic
+    # (softmax ignores a shift of every score of a row) and rounding on both
+    # sides.
+    norm = lambda t: float(np.linalg.norm(np.asarray(t, np.float64)))
+    floor = 1e-3 * norm(jnp.concatenate([g.ravel() for g in jax.tree.leaves(want_grads)]))
+    for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        assert norm(a - b) <= 1e-4 * max(norm(b), floor)

@@ -1,14 +1,16 @@
 """Attention microbench: full (materialized S×S) vs flash (Pallas) vs the
-fused tiny-S kernel, on chip.
+single-pass kernel, on chip.
 
 Default mode sweeps long sequences — the flash kernel's domain:
 
     python tools/bench_attention.py [--seqs 512,1024,2048,4096] [--out f]
 
-``--fused-small`` is the tiny-S staged A/B (docs/RESULTS.md §4, the
-vit_s16 candidate): S ∈ {64, 65, 50, 128} at a (batch·head) count big enough
-to fill the grid, one JSON row per (impl, S) plus one per
-``MPT_ATTN_BH_BLOCK`` lever value for the fused kernel — each fused row
+``--fused-small`` is the single-pass kernel's A/B (PERF.md section 6, PR 25
+has its chip rows): S ∈ {64, 65, 50, 128, 196} at a (batch·head) count big
+enough to fill the grid (``--heads 12 --batch 128 --seqs 196`` is ViT-B/16's
+attention), one JSON row per (impl, S) — ``auto`` is the rows layout, the
+path — plus one per ``MPT_ATTN_BH_BLOCK`` value of the grouped layout (not
+consulted above S_pad 128: those rows time the rows layout again) — each fused row
 CORRECTNESS-GATED against full attention on chip before any timing ships,
 and the ambient ``MPT_ATTN_*`` environment snapshotted/cleared/restored
 around the sweep so an operator's exported lever cannot contaminate a row
@@ -38,8 +40,9 @@ H, D = 6, 64  # vit_s16-shaped heads
 DEFAULT_BATCH = 4          # long-S mode: S×S dominates, tiny B suffices
 FUSED_SMALL_BATCH = 256    # tiny-S mode: enough (b·h) tiles to fill the grid
 
-# (label, env) — the tiny-S bh-grouping lever matrix (MPT_ATTN_BH_BLOCK;
-# ops/fused_attention_small.py _bh_block). "auto" is the kernel default.
+# (label, env) — "auto" is the kernel as the models call it (the rows
+# layout); the rest is the grouped layout's bh-grouping lever matrix
+# (MPT_ATTN_BH_BLOCK; ops/fused_attention_small.py _bh_block).
 FUSED_SMALL_CONFIGS = [
     ("auto", {}),
     ("bh1", {"MPT_ATTN_BH_BLOCK": "1"}),
@@ -91,12 +94,12 @@ def _check_vs_full(fn, q, k, v):
 
 def bench_one(impl: str, seq: int, steps: int, warmup: int, batch: int,
               check: bool = False, label: str | None = None,
-              env: dict | None = None) -> dict:
+              env: dict | None = None, heads: int = H) -> dict:
     fn = _impl_fn(impl)
 
     rng = np.random.default_rng(0)
     mk = lambda: jnp.asarray(
-        rng.standard_normal((batch, seq, H, D)), jnp.bfloat16
+        rng.standard_normal((batch, seq, heads, D)), jnp.bfloat16
     )
     q, k, v = mk(), mk(), mk()
     if check:
@@ -140,7 +143,7 @@ def bench_one(impl: str, seq: int, steps: int, warmup: int, batch: int,
     dt = (time.perf_counter() - t0) / steps
 
     rec = {
-        "impl": impl, "seq": seq, "batch": batch, "heads": H, "head_dim": D,
+        "impl": impl, "seq": seq, "batch": batch, "heads": heads, "head_dim": D,
         "fwd_bwd_ms": round(dt * 1e3, 3),
     }
     if label is not None:
@@ -157,7 +160,8 @@ def sweep_long(args) -> list[dict]:
     for seq in (int(s) for s in args.seqs.split(",") if s):
         for impl in ("full", "flash"):
             try:
-                rec = bench_one(impl, seq, args.steps, args.warmup, args.batch)
+                rec = bench_one(impl, seq, args.steps, args.warmup, args.batch,
+                                heads=args.heads)
             except Exception as e:
                 rec = {"impl": impl, "seq": seq,
                        "error": f"{type(e).__name__}: {e}"[:300]}
@@ -193,6 +197,7 @@ def sweep_fused_small(args) -> list[dict]:
                     rec = bench_one(
                         impl, seq, args.steps, args.warmup, args.batch,
                         check=(impl == "fused-small"), label=label, env=env,
+                        heads=args.heads,
                     )
                 except Exception as e:  # a rejected config is still a row
                     rec = {"impl": impl, "seq": seq, "label": label,
@@ -213,14 +218,18 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seqs", default=None,
                     help="comma-separated sequence lengths "
-                    "(default 512,1024,2048,4096; 64,50,128 with --fused-small)")
+                    "(default 512,1024,2048,4096; 64,65,50,128,196 with "
+                    "--fused-small)")
     ap.add_argument("--batch", type=int, default=None,
                     help=f"batch size (default {DEFAULT_BATCH}; "
                     f"{FUSED_SMALL_BATCH} with --fused-small)")
+    ap.add_argument("--heads", type=int, default=H,
+                    help=f"heads of {D} (default {H}: vit_s16; 12: ViT-B/16)")
     ap.add_argument("--fused-small", action="store_true",
-                    help="tiny-S staged A/B: full/flash vs the fused tiny-S "
-                    "kernel per MPT_ATTN_BH_BLOCK lever (correctness-gated, "
-                    "ambient MPT_ATTN_* cleared per row)")
+                    help="the single-pass kernel's A/B: full/flash vs the "
+                    "kernel's rows layout and per MPT_ATTN_BH_BLOCK value of "
+                    "the grouped one (correctness-gated, ambient MPT_ATTN_* "
+                    "cleared per row)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--warmup", type=int, default=3)
     ap.add_argument("--out", default="")
@@ -229,8 +238,9 @@ def main() -> None:
     if args.seqs is None:
         # 64 = the vit_s16 token count (GAP head, S == patch count); 65 =
         # the class-token variant (odd S → padded rows + bh-group G=1, a
-        # different tiling); 50 = heavy padding; 128 = the envelope edge.
-        args.seqs = "64,65,50,128" if args.fused_small else "512,1024,2048,4096"
+        # different tiling); 50 = heavy padding; 128 = the grouped layout's
+        # edge; 196 = ViT-B/16 at 224 px.
+        args.seqs = "64,65,50,128,196" if args.fused_small else "512,1024,2048,4096"
     if args.batch is None:
         args.batch = FUSED_SMALL_BATCH if args.fused_small else DEFAULT_BATCH
 

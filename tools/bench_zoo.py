@@ -85,11 +85,12 @@ def build_state_and_batch(
         model_name, NUM_CLASSES, rng=jax.random.PRNGKey(0), image_size=image,
         dtype=jnp.bfloat16, param_dtype=jnp.float32, remat_blocks=remat_blocks,
         attn_impl=attn_impl, stem_s2d=stem_s2d, fused_stem=fused_stem,
-        # Multi-chip: the fused kernels (stem, fused-small attention)
-        # shard_map themselves over the data axis (ops/fused_stem.py /
+        # Multi-chip: the kernels (stem, dense attention) shard_map
+        # themselves over the data axis (ops/fused_stem.py /
         # ops/fused_attention_small.py, Multi-chip) instead of degrading to
-        # an activation all-gather around a replicated Mosaic call.
-        dp_mesh=mesh if (fused_stem or attn_impl == "fused-small") else None,
+        # an activation all-gather around a replicated Mosaic call; a model
+        # with neither ignores the mesh.
+        dp_mesh=mesh,
         qkv_fused=qkv_fused,
     )
     state = TrainState.create(

@@ -38,10 +38,13 @@ from mpi_pytorch_tpu.utils.hardware import mosaic_call_count
 
 # Flagship head (resnet18: 512 features -> 64 500 classes) and stem
 # (128 px input -> conv1 output 64x64x64); ViT-S/16 attention at 128 px
-# (64 tokens, 6 heads of 64) plus one long sequence for flash.
+# (64 tokens, 6 heads of 64), ViT-B/16 attention at 224 px (196 tokens, 12
+# heads of 64, at the benchmark's three batches) plus one long sequence for
+# flash.
 D_HEAD, V_HEAD = 512, 64500
 STEM_B, STEM_HW, STEM_C = 512, 64, 64
 ATTN_B, ATTN_S, ATTN_H, ATTN_D = 64, 64, 6, 64
+VITB_S, VITB_H, VITB_BATCHES = 196, 12, (16, 128, 256)
 
 
 class Case(NamedTuple):
@@ -95,10 +98,10 @@ def _stem_case(name: str, env: dict, refused_with: str = "") -> Case:
     )
 
 
-def _attn_args(b, s):
+def _attn_args(b, s, h=ATTN_H):
     def make():
         ks = jax.random.split(jax.random.PRNGKey(1), 4)
-        shape = (b, s, ATTN_H, ATTN_D)
+        shape = (b, s, h, ATTN_D)
         return tuple(jax.random.normal(k, shape, jnp.bfloat16) for k in ks)
 
     return make
@@ -184,6 +187,16 @@ def _cases() -> list[Case]:
             _head_args(512, grads=True),
         ),
     ]
+    for batch in VITB_BATCHES:
+        cases.append(
+            Case(
+                f"fused_attention_small[S=196,B={batch}]",
+                _with_grads(lambda q, k, v: fused_attention_small(q, k, v, interpret=False), 3),
+                _with_grads(full_attention, 3),
+                _attn_args(batch, VITB_S, VITB_H),
+                tol=5e-2,
+            )
+        )
     for rows in (256, 1024, 4096):
         cases.append(
             Case(
@@ -271,8 +284,8 @@ def untileable_calls() -> list[tuple[str, Callable]]:
     return [
         ("stem[C=60]", lambda: stem_affine_relu_pool(
             z((8, 4, 4, 60), jnp.bfloat16), z((60,)), z((60,)))),
-        ("fused_attention_small[S=196]", lambda: fused_attention_small(
-            *(z((2, 196, 6, 64), jnp.bfloat16),) * 3)),
+        ("fused_attention_small[S=1024]", lambda: fused_attention_small(
+            *(z((2, 1024, 6, 64), jnp.bfloat16),) * 3)),
         ("head_predict[rows=1028]", lambda: head_predict(*head)),
         ("head_predict_int8[rows=1028]", lambda: head_predict_int8(
             head[0], z((8, 16), jnp.int8), head[2], head[3], z((16,)) + 1.0, 1.0)),
@@ -290,6 +303,36 @@ def check_raises() -> list[dict]:
             rows.append({"kernel": name, "status": "wrong",
                          "error": "returned a result for a shape it cannot tile"})
     return rows
+
+
+def check_attention_precision() -> dict:
+    """Forward and VJP of the attention pair alone on a seeded
+    ``[16,196,12,64]`` bf16 block, the kernel and XLA's ``full_attention``
+    each against a ``Precision.HIGHEST`` float32 ``jnp`` reference: the
+    kernel's relative L2 error (out, dq, dk, dv) may be no larger than the
+    XLA path's, to a hundredth of it (both round the same bf16 products; the
+    difference is where p and ds are rounded)."""
+    from mpi_pytorch_tpu.ops.fused_attention_small import fused_attention_small
+    from mpi_pytorch_tpu.ops.ring_attention import full_attention
+
+    args = _attn_args(VITB_BATCHES[0], VITB_S, VITB_H)()
+
+    def highest(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            return full_attention(q, k, v)
+
+    flat = lambda tree: [np.asarray(x, np.float64) for x in jax.tree_util.tree_leaves(tree)]
+    want = flat(jax.jit(_with_grads(highest, 3))(*(x.astype(jnp.float32) for x in args)))
+    errors = {}
+    for name, fn in (
+        ("kernel", lambda q, k, v: fused_attention_small(q, k, v, interpret=False)),
+        ("xla", full_attention),
+    ):
+        got = flat(jax.jit(_with_grads(fn, 3))(*args))
+        errors[name] = [float(np.linalg.norm(g - w) / np.linalg.norm(w)) for g, w in zip(got, want)]
+    ok = all(k <= 1.01 * x for k, x in zip(errors["kernel"], errors["xla"]))
+    return {"kernel": "attention_precision[16x196x12x64]", "status": "compiled" if ok else "wrong",
+            "rel_l2_vs_highest": errors}
 
 
 def check_compiler_options() -> dict:
@@ -321,6 +364,8 @@ def main() -> None:
     device = jax.devices()[0]
     print(f"chip_kernels: {device.platform} {device.device_kind} x{jax.device_count()}", flush=True)
     rows = check_raises() + [check_compiler_options()]
+    if only in "attention_precision":
+        rows.append(check_attention_precision())
     for row in rows:
         print(json.dumps(row), flush=True)
     for case in CASES:
