@@ -6,7 +6,7 @@ A run is correct when
 - the compiled train program holds the Mosaic calls the configuration asks
   for (a kernel replaced by its XLA composition shows as a missing call) and,
   on several chips, has a shard on every local device and a split batch;
-- every epoch loss is finite, and every epoch trained the images the cell's
+- every epoch loss is finite, and every epoch trained the samples the cell's
   arithmetic says it should;
 - one seeded batch through the SYSTEM's forward (its evaluation path, at the
   cell's precision, inference mode so both sides use the same BatchNorm
@@ -20,9 +20,15 @@ each within the tolerance the configuration file states with its reason.
 
 What this cannot see: an error that only the cell's own batch size, the
 scanned epoch or the split over several chips brings out (the train step is
-checked on ONE chip at an eighth of the cell's batch per chip; across chips
-the compile record and the finite losses stand in), and a wrong second
-optimizer moment (the first is compared, as the gradient).
+checked on ONE chip at the task's size, for images an eighth of the cell's
+batch per chip; across chips the compile record and the finite losses stand
+in), and a wrong second optimizer moment (the first is compared, as the
+gradient).
+
+What a sample is — the seeded batch, the two checks' sizes, an epoch
+record's count — is the task's (``benchmark/tasks/<task>.py``, by the
+configuration's ``"task"``); the system and the reference take ``(inputs,
+targets)`` whatever those are.
 """
 
 from __future__ import annotations
@@ -30,8 +36,7 @@ from __future__ import annotations
 import importlib
 import math
 
-CHECK_BATCH = 256  # forward agreement
-TRAIN_CHECK_SHARE = 8  # train-step agreement: batch_per_chip / 8 images
+from benchmark import tasks
 
 
 def _first_moment(node):
@@ -70,20 +75,6 @@ def _system(obs: dict, devices=None):
     return cfg, mesh, place_state_on_mesh(state, mesh)
 
 
-def _seeded_batch(obs: dict, mesh, key, batch: int):
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    size, classes = obs["model"]["image_size"], obs["model"]["num_classes"]
-    k_img, k_lab = jax.random.split(key)
-    images = jax.device_put(
-        jax.random.normal(k_img, (batch, size, size, 3), jnp.float32),
-        NamedSharding(mesh, P(mesh.axis_names[0])),
-    )
-    return images, jax.random.randint(k_lab, (batch,), 0, classes)
-
-
 def forward_compare(reference, dtype):
     """The program of ``forward_agreement`` (also compiled by the rehearsal)."""
     import jax
@@ -92,21 +83,21 @@ def forward_compare(reference, dtype):
     from mpi_pytorch_tpu.train.step import eval_logits
 
     @jax.jit
-    def compare(state, images, labels):
-        got = eval_logits(state, images, dtype)
-        want = reference.forward(state.variables, images)
+    def compare(state, inputs, targets):
+        got = eval_logits(state, inputs, dtype)
+        want = reference.forward(state.variables, inputs)
         return {
             "logits_rel_l2": jnp.linalg.norm(got - want) / jnp.linalg.norm(want),
             "loss_abs": jnp.abs(
-                reference.cross_entropy(got, labels) - reference.cross_entropy(want, labels)
+                reference.cross_entropy(got, targets) - reference.cross_entropy(want, targets)
             ),
-            "reference_loss": reference.cross_entropy(want, labels),
+            "reference_loss": reference.cross_entropy(want, targets),
         }
 
     return compare
 
 
-def forward_agreement(obs: dict, config: dict, seed: int, batch: int = CHECK_BATCH) -> dict:
+def forward_agreement(obs: dict, config: dict, seed: int, batch: int) -> dict:
     """Relative L2 error of the system's logits against the reference's, and
     the absolute difference of the two losses, on one seeded batch."""
     import jax
@@ -115,9 +106,11 @@ def forward_agreement(obs: dict, config: dict, seed: int, batch: int = CHECK_BAT
 
     reference = importlib.import_module(f"benchmark.reference.{config['reference']}")
     cfg, mesh, state = _system(obs)
-    images, labels = _seeded_batch(obs, mesh, jax.random.PRNGKey(seed + 17), batch)
+    inputs, targets = tasks.load(config).seeded_batch(
+        obs["model"], mesh, jax.random.PRNGKey(seed + 17), batch
+    )
     compare = forward_compare(reference, _dtype(cfg.compute_dtype))
-    return {k: float(v) for k, v in compare(state, images, labels).items()}
+    return {k: float(v) for k, v in compare(state, inputs, targets).items()}
 
 
 def train_step_agreement(obs: dict, config: dict, seed: int, batch: int) -> dict:
@@ -139,20 +132,22 @@ def train_step_agreement(obs: dict, config: dict, seed: int, batch: int) -> dict
 
     reference = importlib.import_module(f"benchmark.reference.{config['reference']}")
     cfg, mesh, state = _system(obs, devices=jax.local_devices()[:1])
-    images, labels = _seeded_batch(obs, mesh, jax.random.PRNGKey(seed + 23), batch)
+    inputs, targets = tasks.load(config).seeded_batch(
+        obs["model"], mesh, jax.random.PRNGKey(seed + 23), batch
+    )
 
     @jax.jit
-    def want_fn(variables, images, labels):
-        return reference.loss_and_grads(variables, images, labels)
+    def want_fn(variables, inputs, targets):
+        return reference.loss_and_grads(variables, inputs, targets)
 
-    want_loss, want = want_fn(state.variables, images, labels)  # before the step donates the state
+    want_loss, want = want_fn(state.variables, inputs, targets)  # before the step donates the state
     step = make_train_step(
         _dtype(cfg.compute_dtype), remat=(cfg.remat == "full"), accum_steps=1, mesh=mesh,
     )
-    compiled = step.lower(state, (images, labels)).compile(
+    compiled = step.lower(state, (inputs, targets)).compile(
         compiler_options=cfg.parsed_compiler_options()
     )
-    new_state, metrics = compiled(state, (images, labels))
+    new_state, metrics = compiled(state, (inputs, targets))
 
     mu = _first_moment(new_state.opt_state)
     if mu is None or jax.tree_util.tree_structure(mu) != jax.tree_util.tree_structure(want):
@@ -202,18 +197,18 @@ def check(obs: dict, config: dict, seed: int, rehearse: bool = False) -> list[st
             why.append(f"{rec['executable']} has shards on devices {rec['devices']}, not {ids}")
         if len(ids) > 1 and rec["sharded_inputs"] < 2:
             why.append(f"{rec['executable']}: the batch is not split over {len(ids)} devices")
-    images = obs["steps_per_epoch"] * obs["global_batch"]
+    task = tasks.load(config)
+    sizes = tasks.check_sizes(task, config, rehearse)
+    samples = obs["steps_per_epoch"] * obs["global_batch"]
     for _, rec in obs["epoch_marks"]:
         if not math.isfinite(rec["loss"]):
             why.append(f"epoch {rec['epoch']} loss {rec['loss']}")
-        trained = round(rec["images_per_sec"] * rec["time_s"])
-        if trained != images:
-            why.append(f"epoch {rec['epoch']} trained {trained} images, the cell says {images}")
-    agreement = forward_agreement(obs, config, seed, 8 if rehearse else CHECK_BATCH)
+        trained = task.epoch_samples(rec)
+        if trained != samples:
+            why.append(f"epoch {rec['epoch']} trained {trained} samples, the cell says {samples}")
+    agreement = forward_agreement(obs, config, seed, sizes["forward_samples"])
     print(f"benchmark: forward agreement {agreement}", flush=True)
-    trained = train_step_agreement(
-        obs, config, seed, 8 if rehearse else config["batch_per_chip"] // TRAIN_CHECK_SHARE
-    )
+    trained = train_step_agreement(obs, config, seed, sizes["train_samples"])
     print(f"benchmark: train step agreement {trained}", flush=True)
     agreement.update(trained)
     tolerance = config["tolerance"]

@@ -1,5 +1,7 @@
-"""The one general dataset generator: a recipe (data, in a traffic file) in,
-a directory of generated inputs and the entry-point flags that name it out.
+"""The image task's dataset generator (reached through
+``benchmark/tasks/images.py`` and from its recipes, never from the harness
+itself): a recipe (data, in a traffic file) in, a directory of generated
+inputs and the entry-point flags that name it out.
 
 The program receives only what is generated here. Pixels are a function of
 the recipe alone and are built ONCE per checkout (2 GB for the 40 000-image

@@ -127,13 +127,14 @@ def run(ctx: dict) -> dict:
     the correctness check work from."""
     import jax
 
-    from benchmark import datasets
+    from benchmark import tasks
     # First, so that a directory without the system fails before any work.
     from mpi_pytorch_tpu.train import trainer
 
     config, traffic = ctx["config"], ctx["traffic"]
     out = ctx["out_dir"]
     chips = ctx["chips"]
+    task = tasks.load(config)  # what a sample is: the dataset step below is its
     model = config["model"]
     batch = config["batch_per_chip"] * chips
     recipe = dict(traffic["dataset"])
@@ -149,16 +150,10 @@ def run(ctx: dict) -> dict:
         os.environ.update(config["rehearse"]["env"])
         recipe.update(traffic["rehearse"]["dataset"])
         batch = traffic["rehearse"]["batch_per_chip"] * chips
-    flags.update(
-        datasets.ensure(
-            recipe, image_size=model["image_size"], num_classes=model["num_classes"],
-            seed=ctx["seed"], data_root=ctx["data_root"],
-        )
-    )
+    flags.update(task.ensure(recipe, model, seed=ctx["seed"], data_root=ctx["data_root"]))
+    flags.update(task.model_flags(model))
     flags.update(
         {
-            "num-classes": model["num_classes"],
-            "image-size": model["image_size"],
             "seed": ctx["seed"],
             "batch-size": batch,
             "num-epochs": NUM_EPOCHS,
@@ -209,7 +204,7 @@ def run(ctx: dict) -> dict:
     with open(flags["trace-file"]) as f:
         spans = json.load(f)["traceEvents"]
     xplanes = glob.glob(os.path.join(out, "profile", "plugins", "profile", "*", "*.xplane.pb"))
-    n_train = recipe["train_images"]
+    n_train = task.train_samples(recipe)
     marks = [t for t, _ in watcher.epoch_marks[traffic["warmup_epochs"] - 1:]]
     print("benchmark: epoch intervals in the window (s):",
           [round(b - a, 4) for a, b in zip(marks, marks[1:])], flush=True)

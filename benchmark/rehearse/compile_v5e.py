@@ -46,7 +46,7 @@ def main(workload: str) -> None:
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmark import correct
+    from benchmark import correct, tasks
     from benchmark.drivers.train import argv
     from mpi_pytorch_tpu.config import parse_config
     from mpi_pytorch_tpu.parallel.mesh import create_mesh
@@ -63,11 +63,12 @@ def main(workload: str) -> None:
     with open(os.path.join(ROOT, "benchmark", "traffic", cell["traffic"] + ".json")) as f:
         traffic = json.load(f)
     chips, model = cell["chips"], config["model"]
+    task = tasks.load(config)  # the shapes and sample counts below are its
+    sizes = tasks.check_sizes(task, config)
     batch = config["batch_per_chip"] * chips
-    n_train = traffic["dataset"]["train_images"]
-    flags = {**config["flags"], **traffic["flags"], "num-classes": model["num_classes"],
-             "image-size": model["image_size"], "batch-size": batch,
-             "metrics-file": "", "log-file": ""}
+    n_train = task.train_samples(traffic["dataset"])
+    flags = {**config["flags"], **traffic["flags"], **task.model_flags(model),
+             "batch-size": batch, "metrics-file": "", "log-file": ""}
     cfg = parse_config(argv(flags))
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -81,9 +82,8 @@ def main(workload: str) -> None:
     shape = lambda x, s: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype if not hasattr(x, "dtype") else x.dtype, sharding=s)
     state_s = jax.tree_util.tree_map(lambda x: shape(x, replicated), state)
     padded = -(-n_train // chips) * chips
-    size, steps = model["image_size"], n_train // batch
-    dataset = jax.ShapeDtypeStruct((padded, size, size, 3), np.dtype(cfg.input_dtype), sharding=rows)
-    labels = jax.ShapeDtypeStruct((n_train,), np.int32, sharding=replicated)
+    steps = n_train // batch
+    dataset, labels = task.cache_shapes(model, padded, n_train, cfg.input_dtype, rows, replicated)
     idx = jax.ShapeDtypeStruct((steps, batch), np.int32, sharding=replicated)
     valid = jax.ShapeDtypeStruct((steps, batch), np.bool_, sharding=replicated)
 
@@ -101,11 +101,9 @@ def main(workload: str) -> None:
     )
     reference = importlib.import_module("benchmark.reference." + config["reference"])
     correct.forward_compare(reference, trainer._dtype(cfg.compute_dtype)).lower(
-        state_s,
-        jax.ShapeDtypeStruct((correct.CHECK_BATCH, size, size, 3), np.float32, sharding=rows),
-        jax.ShapeDtypeStruct((correct.CHECK_BATCH,), np.int32, sharding=replicated),
+        state_s, *task.batch_shapes(model, sizes["forward_samples"], rows, replicated)
     ).compile()
-    check = compile_check_step(cfg, config, model, topo.devices[0])
+    check = compile_check_step(cfg, config, task, sizes["train_samples"], topo.devices[0])
     entries: dict[str, int] = {}  # bytes by program name (two programs can share one)
     for name in os.listdir(cache):
         if name.endswith("-cache"):
@@ -124,15 +122,15 @@ def main(workload: str) -> None:
     }, indent=1))
 
 
-def compile_check_step(cfg, config: dict, model: dict, device) -> dict:
+def compile_check_step(cfg, config: dict, task, batch: int, device) -> dict:
     """The two programs of ``correct.train_step_agreement`` at their real
-    size on one described chip: the system's train step at an eighth of the
-    cell's batch per chip, and the reference's float32 loss and gradient."""
+    size on one described chip: the system's train step on ``batch`` samples
+    of the task (images: an eighth of the cell's batch per chip), and the
+    reference's float32 loss and gradient."""
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmark import correct
     from mpi_pytorch_tpu.parallel.mesh import create_mesh
     from mpi_pytorch_tpu.train import trainer
     from mpi_pytorch_tpu.train.step import make_train_step
@@ -144,14 +142,12 @@ def compile_check_step(cfg, config: dict, model: dict, device) -> dict:
     state_s = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=one), state
     )
-    batch, size = config["batch_per_chip"] // correct.TRAIN_CHECK_SHARE, model["image_size"]
-    images = jax.ShapeDtypeStruct((batch, size, size, 3), np.float32, sharding=one)
-    labels = jax.ShapeDtypeStruct((batch,), np.int32, sharding=one)
+    inputs, targets = task.batch_shapes(config["model"], batch, one, one)
     step = make_train_step(
         trainer._dtype(cfg.compute_dtype), remat=(cfg.remat == "full"), accum_steps=1, mesh=mesh
-    ).lower(state_s, (images, labels)).compile(compiler_options=cfg.parsed_compiler_options())
+    ).lower(state_s, (inputs, targets)).compile(compiler_options=cfg.parsed_compiler_options())
     reference = importlib.import_module("benchmark.reference." + config["reference"])
-    wanted = jax.jit(reference.loss_and_grads).lower(state_s.variables, images, labels).compile()
+    wanted = jax.jit(reference.loss_and_grads).lower(state_s.variables, inputs, targets).compile()
     return {
         "batch": batch, "mosaic_calls": hardware.mosaic_call_count(step),
         "system_step": str(step.memory_analysis()),

@@ -12,11 +12,14 @@ window, with a ``breakdown``.
 Everything that belongs to one cell is data or a file of its own, found by
 the name in BENCHMARK.json: ``configs/<config>.json`` (the entry's
 ``file``) with its plain reference and FLOPs count ``reference/<arch>.py``
-(the file's ``reference``), ``traffic/<traffic>.json``, ``drivers/<kind>.py``
-(the traffic file's ``driver``), ``recipes/<recipe>.py`` (its dataset's
-``recipe``), ``metrics/<metric>.py`` (or ``.json`` naming another metric's
-reader). Adding a cell, a configuration or a metric adds files and
-BENCHMARK.json entries and edits nothing here.
+(the file's ``reference``) and what its samples are ``tasks/<task>.py`` (the
+file's ``task``; absent: ``images``), ``traffic/<traffic>.json``,
+``drivers/<kind>.py`` (the traffic file's ``driver``), ``recipes/<recipe>.py``
+(its dataset's ``recipe``), ``metrics/<metric>.py`` (or ``.json`` naming
+another metric's reader). Adding a cell, a configuration, a metric or a task
+whose samples are not images (token sequences, say) adds files and
+BENCHMARK.json entries and edits nothing here: the harness never looks inside
+a ``model`` or a recipe, the task does.
 
 A run that finds no TPU, or another number of chips than the cell asks for,
 exits non-zero and prints no result. ``--rehearse`` is the exception made
@@ -76,6 +79,10 @@ def main(argv=None) -> int:
 
     os.chdir(ROOT)
     sys.path.insert(0, ROOT)
+    from benchmark import tasks
+
+    # A check size that comes out below 1 fails here, not after the window.
+    tasks.check_sizes(tasks.load(config), config, args.rehearse)
     import jax
 
     # The TPU and nothing else: without a chip the first device use raises.
@@ -164,6 +171,9 @@ def main(argv=None) -> int:
             "idle_gaps": reduce.idle_gaps(trace, 0),
         }
     sys.stdout.flush()
+    for reason in why_not:  # last on standard error too: what a record of the run keeps
+        print(f"benchmark: NOT CORRECT: {reason}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -103,6 +103,16 @@ def test_the_span_file_lies_on_the_trace_by_its_written_origin(run):
     assert abs(statistics.median(diffs) - shift) < 5_000
 
 
+def test_the_boundary_reads_on_a_cut_with_one_whole_execution(run):
+    """The fixture holds one whole scanned epoch between the ragged ends of
+    its neighbours: two boundaries (7.747 and 7.588 ms idle, 2.4 us of tiny
+    programs in each) and no two whole executions, so the reader of PRs 24-25
+    returned None here; the uncut trace's own reading was 7.67 (PERF.md)."""
+    obs, trace = run
+    assert len(reduce.step_program(trace, 0)) == 1 and len(reduce.program_runs(trace, 0)) == 3
+    assert load_reader("epoch.boundary_ms")(obs, trace) == pytest.approx(7.66746, abs=1e-4)
+
+
 def test_the_boundarys_idle_has_names(run):
     _, trace = run
     gaps = dict(reduce.idle_gaps(trace, 0))

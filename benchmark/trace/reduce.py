@@ -128,18 +128,27 @@ def busy_and_window_s(trace: Trace) -> tuple[float, float]:
     return sum(per_chip) / len(per_chip) / 1e9, (hi - lo) / 1e9
 
 
-def step_program(trace: Trace, device: int) -> list[Event]:
-    """Executions of the program that took most device time — the train
-    step, or the scanned epoch — that lie WHOLLY inside the trace: one the
-    trace's start or end cut short is told by its length (under 98 % of the
-    upper quartile's; whole executions of one program differ by far less)
-    and left out."""
+def program_runs(trace: Trace, device: int) -> list[Event]:
+    """Every execution in the trace of the program that took most device
+    time — the train step, or the scanned epoch — in order of start, those
+    the trace's start or end cut short among them."""
     by_name: dict[str, list[Event]] = defaultdict(list)
     for event in trace.devices[device].modules:
         by_name[event[0]].append(event)
     if not by_name:
         return []
     runs = max(by_name.values(), key=lambda evs: sum(e[2] for e in evs))
+    return sorted(runs, key=lambda e: e[1])
+
+
+def step_program(trace: Trace, device: int) -> list[Event]:
+    """The executions of ``program_runs`` that lie WHOLLY inside the trace:
+    one the trace's start or end cut short is told by its length (under 98 %
+    of the upper quartile's; whole executions of one program differ by far
+    less) and left out."""
+    runs = program_runs(trace, device)
+    if not runs:
+        return []
     typical = sorted(e[2] for e in runs)[int(0.75 * len(runs))]
     return [e for e in runs if e[2] >= 0.98 * typical]
 

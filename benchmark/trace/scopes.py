@@ -191,12 +191,15 @@ def scope_ms(obs: dict, trace: Trace | None, scope: str) -> float | None:
 
 
 def boundary_ms(trace: Trace | None, device: int = 0) -> float | None:
-    """Median idle milliseconds of ``device`` between consecutive whole
+    """Median idle milliseconds of ``device`` between consecutive
     executions of the step program: the gap from one's end to the next's
-    start, less what ran in it (the epoch accounting's tiny programs)."""
+    start, less what ran in it (the epoch accounting's tiny programs). Every
+    gap whose two edges lie in the trace counts, whole neighbours or not: an
+    execution the trace's START cut short still ends where it ended, and one
+    its END cut short began where it began."""
     if trace is None or device not in trace.devices:
         return None
-    runs = reduce.step_program(trace, device)
+    runs = reduce.program_runs(trace, device)
     gaps = [(a[1] + a[2], b[1]) for a, b in zip(runs, runs[1:]) if b[1] > a[1] + a[2]]
     if not gaps:
         return None
