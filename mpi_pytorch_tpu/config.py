@@ -30,6 +30,7 @@ SUPPORTED_MODELS = (
     "vit_s16",
     "vit_b16",
     "vit_moe_s16",
+    "lfm2_moe",
 )
 
 # ImageNet normalization constants (reference ``main.py:62-65``).
@@ -114,6 +115,11 @@ class Config:
 
     # --- model (utils.py:4, :39-45) ---
     model_name: str = "resnet18"
+    # The architecture of a model that is configured, not named (registry
+    # CONFIGURED_MODELS: lfm2_moe): ONE JSON object in the source's own
+    # config.json key names, inline or the path of a file that holds it
+    # (models/lfm2.py Lfm2Config). Empty: the source's published values.
+    model_config: str = ""
     num_classes: int = 64500
     feature_extract: bool = False
     use_pretrained: bool = False  # reference default True needs torchvision weights;
@@ -777,13 +783,40 @@ class Config:
             raise ValueError(
                 f"attn_impl must be full|flash|fused-small, got {self.attn_impl!r}"
             )
-        if self.attn_impl != "full":
-            from mpi_pytorch_tpu.models.registry import SP_MODELS
+        from mpi_pytorch_tpu.models.registry import (
+            ATTN_IMPL_MODELS, CONFIGURED_MODELS, TOKEN_MODELS,
+        )
 
-            if self.model_name not in SP_MODELS:
+        if self.model_config and self.model_name not in CONFIGURED_MODELS:
+            raise ValueError(
+                f"model_config is read by {', '.join(CONFIGURED_MODELS)}; "
+                f"{self.model_name!r} is built from its name alone"
+            )
+        if self.model_name in TOKEN_MODELS:
+            # A token model's samples are packed sequences (data/tokens.py):
+            # they train from the device cache; the image loader, validation
+            # and the spmd step are image paths.
+            if not self.device_cache:
+                raise ValueError(
+                    f"{self.model_name!r} trains on token sequences held in HBM: "
+                    "set device_cache=True (streaming tokens from the host is "
+                    "not implemented)"
+                )
+            if self.validate:
+                raise ValueError(
+                    f"{self.model_name!r}: validation is an image path; set "
+                    "validate=False"
+                )
+            if self.attn_impl == "fused-small":
+                raise ValueError(
+                    f"{self.model_name!r}: attn_impl is full|flash (the single-pass "
+                    "kernel has no causal, grouped-head form)"
+                )
+        if self.attn_impl != "full":
+            if self.model_name not in ATTN_IMPL_MODELS:
                 raise ValueError(
                     f"attn_impl={self.attn_impl!r} applies only to the "
-                    f"attention family ({', '.join(SP_MODELS)}); "
+                    f"attention family ({', '.join(ATTN_IMPL_MODELS)}); "
                     f"{self.model_name!r} has no attention"
                 )
             if self.sp_strategy != "none":

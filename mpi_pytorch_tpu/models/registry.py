@@ -28,6 +28,7 @@ from mpi_pytorch_tpu.models.common import head_filter
 from mpi_pytorch_tpu.models.densenet import densenet121
 from mpi_pytorch_tpu.models.efficientnet import efficientnet_b0
 from mpi_pytorch_tpu.models.inception import inception_v3
+from mpi_pytorch_tpu.models.lfm2 import lfm2_moe
 from mpi_pytorch_tpu.models.mobilenet import mobilenet_v2
 from mpi_pytorch_tpu.models.resnet import resnet18, resnet34
 from mpi_pytorch_tpu.models.squeezenet import squeezenet1_0
@@ -52,10 +53,27 @@ _REGISTRY: dict[str, tuple[Callable[..., nn.Module], int]] = {
     "vit_s16": (vit_s16, 224),
     "vit_b16": (vit_b16, 224),
     "vit_moe_s16": (vit_moe_s16, 224),
+    # A token model: its "input size" is a sequence length (TOKEN_MODELS).
+    "lfm2_moe": (lfm2_moe, 128),
 }
 
 # Architectures with no BatchNorm (their factories take no bn_axis_name).
-BN_FREE_MODELS = ("alexnet", "squeezenet1_0", "vit_s16", "vit_b16", "vit_moe_s16")
+BN_FREE_MODELS = ("alexnet", "squeezenet1_0", "vit_s16", "vit_b16", "vit_moe_s16", "lfm2_moe")
+
+# What a model consumes is the registry's to say, not a flag's: these take
+# ``int32 [B, S]`` token ids and return ``[B, S, vocab]`` next-token logits; a
+# sample is a packed sequence of S + 1 ids (inputs ``[:-1]``, targets
+# ``[1:]``; data/tokens.py, train/step.py::_gather_batch). Every other model
+# takes ``[B, H, W, 3]`` images and returns ``[B, classes]``.
+TOKEN_MODELS = ("lfm2_moe",)
+
+# Architectures whose factories read ``--model-config`` (a JSON object in
+# their source's own key names) instead of one flag per key.
+CONFIGURED_MODELS = ("lfm2_moe",)
+
+# Architectures with an ``attn_impl`` choice (the vit family's three; a
+# token model's causal ``full`` | ``flash``).
+ATTN_IMPL_MODELS = ("vit_s16", "vit_b16", "vit_moe_s16", "lfm2_moe")
 
 # Architectures whose factories accept sp_strategy/sp_mesh (sequence models
 # that can run the SP attention strategies inside training).
@@ -84,13 +102,22 @@ class ModelBundle:
     trainable_mask: Any | None  # pytree of bools over params; None = all trainable
 
 
+def token_vocab(model_name: str, model_config: str) -> int:
+    """The vocabulary a token model is built with, without building it (the
+    trainer checks its sequences against it)."""
+    from mpi_pytorch_tpu.models.lfm2 import Lfm2Config
+
+    assert model_name in TOKEN_MODELS, model_name
+    return Lfm2Config.parse(model_config).vocab_size
+
+
 def available_models() -> tuple[str, ...]:
     return tuple(_REGISTRY)
 
 
 # Architectures whose factories accept remat_blocks (per-block nn.remat).
 # THE owner of this capability — config validation and error messages defer here.
-REMAT_BLOCKS_MODELS = ("resnet18", "resnet34", "densenet121", "vit_s16", "vit_b16")
+REMAT_BLOCKS_MODELS = ("resnet18", "resnet34", "densenet121", "vit_s16", "vit_b16", "lfm2_moe")
 
 
 def supports_remat_blocks(model_name: str) -> bool:
@@ -156,6 +183,7 @@ def initialize_model(
     fused_stem: bool = False,
     dp_mesh: Any = None,
     qkv_fused: bool = False,
+    model_config: str = "",
 ) -> tuple[nn.Module, int]:
     """Reference-parity signature (``models.py:16``): returns (model, input_size)."""
     if model_name not in _REGISTRY:
@@ -167,13 +195,15 @@ def initialize_model(
     if model_name not in BN_FREE_MODELS:
         kw["bn_axis_name"] = bn_axis_name
     if attn_impl != "full":
-        if model_name not in SP_MODELS:
+        if model_name not in ATTN_IMPL_MODELS:
             raise ValueError(
                 f"attn_impl={attn_impl!r} applies only to the attention "
-                f"family ({', '.join(SP_MODELS)}); {model_name!r} has no "
+                f"family ({', '.join(ATTN_IMPL_MODELS)}); {model_name!r} has no "
                 "attention"
             )
         kw["attn_impl"] = attn_impl
+    if model_config:  # config.validate_config has refused it for any other model
+        kw["model_config"] = model_config
     if model_name in SP_MODELS and attn_impl != "flash" and dp_mesh is not None:
         # Multi-chip: the attention module shard_maps its Mosaic call over
         # this mesh's data axis (ops/fused_attention_small.py, Multi-chip) —
@@ -242,21 +272,28 @@ def initialize_model(
 
 
 def init_variables(
-    model: nn.Module, input_size: int, rng: jax.Array, batch_size: int = 1
+    model: nn.Module, input_size: int, rng: jax.Array, batch_size: int = 1,
+    tokens: bool = False,
 ) -> dict:
     """Initialize params + batch_stats. Uses train=True so architectures with
     train-only submodules (inception aux head) create their full param set.
+    ``tokens``: the model is one of ``TOKEN_MODELS`` and is traced on
+    ``int32 [batch, input_size]`` ids.
 
     Jitted so XLA dead-code-eliminates the traced forward pass — only the
     parameter initializers actually run (orders of magnitude faster than
     eager init for the deep architectures, especially on CPU test meshes)."""
-    dummy = jnp.zeros((batch_size, input_size, input_size, 3), jnp.float32)
+    if tokens:
+        dummy = jnp.zeros((batch_size, input_size), jnp.int32)
+    else:
+        dummy = jnp.zeros((batch_size, input_size, input_size, 3), jnp.float32)
     p_rng, d_rng = jax.random.split(rng)
     init_fn = jax.jit(lambda rngs, x: model.init(rngs, x, train=True))
     variables = jax.device_get(init_fn({"params": p_rng, "dropout": d_rng}, dummy))
     # MoE models sow their load-balance aux into a "losses" collection even
     # at init; it is a per-apply output, not model state — drop it.
     variables.pop("losses", None)
+    variables.pop("counters", None)  # per-apply too (models/lfm2.py MoE.sow)
     return variables
 
 
@@ -281,6 +318,7 @@ def create_model_bundle(
     fused_stem: bool = False,
     dp_mesh: Any = None,
     qkv_fused: bool = False,
+    model_config: str = "",
 ) -> tuple[ModelBundle, dict]:
     """Full-fat factory: returns the bundle plus initialized variables."""
     model, canonical = initialize_model(
@@ -289,10 +327,11 @@ def create_model_bundle(
         remat_blocks=remat_blocks, sp_strategy=sp_strategy, sp_mesh=sp_mesh,
         ep_mesh=ep_mesh, attn_impl=attn_impl, stem_s2d=stem_s2d,
         fused_stem=fused_stem, dp_mesh=dp_mesh, qkv_fused=qkv_fused,
+        model_config=model_config,
     )
     size = image_size or (299 if model_name == "inception_v3" else 128)
     rng = rng if rng is not None else jax.random.PRNGKey(0)
-    variables = init_variables(model, size, rng)
+    variables = init_variables(model, size, rng, tokens=model_name in TOKEN_MODELS)
 
     if use_pretrained:
         from mpi_pytorch_tpu.models.pretrained import load_pretrained

@@ -13,7 +13,7 @@ Record kinds (every record also carries ``ts``, the epoch-seconds stamp
 
 | kind      | required                                            | optional |
 |-----------|-----------------------------------------------------|----------|
-| epoch     | epoch, loss, time_s, images_per_sec                 | tflops, mfu_pct |
+| epoch     | epoch, loss, time_s, images_per_sec                 | tflops, mfu_pct, tokens, tokens_per_sec, moe_pairs_held, moe_pairs_absent, moe_load_max |
 | val       | epoch, accuracy, loss                               |          |
 | eval      | accuracy, loss, images, time_s                      |          |
 | step      | epoch, step, loss                                   | grad_norm, data_wait_ms, step_ms, recompiles, hbm_bytes, sync_ms, overlap_frac, dcn_overlap_frac, skipped, steps_skipped |
@@ -295,7 +295,13 @@ REQUIRED: dict[str, dict[str, tuple]] = {
 }
 
 OPTIONAL: dict[str, dict[str, tuple]] = {
-    "epoch": {"tflops": _NUM, "mfu_pct": _NUM},
+    "epoch": {
+        "tflops": _NUM, "mfu_pct": _NUM,
+        # A token model's epoch (images_per_sec then counts sequences), and
+        # its expert layers' counters: the epoch's sums, the largest load.
+        "tokens": _INT, "tokens_per_sec": _NUM,
+        "moe_pairs_held": _INT, "moe_pairs_absent": _INT, "moe_load_max": _INT,
+    },
     "val": {},
     "eval": {},
     "step": {

@@ -24,6 +24,15 @@ Design notes:
   ``lax.scan`` (one extra q@kᵀ per block — FLOPs are cheap, HBM is not),
   so backward memory is O(S·BK) too. XLA fuses the per-block chain well,
   and the scan keeps this correctness-critical code in plain jnp.
+- Grouped key-value heads (``k``/``v`` with fewer heads than ``q``): query
+  head h reads key-value head ``h // (H / Hkv)`` through the kernel's index
+  map — k and v are never repeated in HBM; the backward folds a group's
+  query heads into one einsum, so dk and dv come out summed over the group.
+- Causal: a k block wholly above the diagonal is neither computed (the body
+  is skipped) nor fetched (its index clamps to the last block the q block
+  needs, which the pipeline already holds).
+- The two dots run in the operands' dtype with float32 accumulation (bf16
+  operands take the MXU's bf16 rate; float32 operands stay float32).
 - Sequences that don't divide the block sizes are zero-padded and masked
   (padded KEYS get -1e30 before the softmax; padded q rows are sliced off).
 - Non-TPU backends fall back to ``full_attention`` (identical math, the
@@ -50,6 +59,9 @@ from mpi_pytorch_tpu.ops.kernel_call import kernel_call
 _NEG = -1e30  # finite mask value: keeps the online-softmax recurrence NaN-free
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+# The blocked backward keeps [B·Hkv, G, S, BK] float32 temporaries: its own,
+# smaller k block whatever the forward's.
+BWD_BLOCK_K = 128
 
 
 def _attn_fwd_kernel(
@@ -65,32 +77,38 @@ def _attn_fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32) * scale  # [BQ, D]
-    k = k_ref[0].astype(jnp.float32)  # [BK, D]
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [BQ, BK]
+    def _block():
+        scores = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [BQ, BK]
 
-    k_pos = ik * block_k + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    valid = k_pos < seq_len  # padded keys contribute nothing
+        k_pos = ik * block_k + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        valid = k_pos < seq_len  # padded keys contribute nothing
+        if causal:
+            q_pos = iq * block_q + lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+            valid = valid & (k_pos <= q_pos)
+        scores = jnp.where(valid, scores, _NEG)
+
+        m_prev = m_scr[:, :1]  # [BQ, 1]
+        l_prev = l_scr[:, :1]
+        m_cur = jnp.max(scores, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)  # masked entries: exp(_NEG - m) == 0
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
     if causal:
-        q_pos = iq * block_q + lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-        valid = valid & (k_pos <= q_pos)
-    scores = jnp.where(valid, scores, _NEG)
-
-    m_prev = m_scr[:, :1]  # [BQ, 1]
-    l_prev = l_scr[:, :1]
-    m_cur = jnp.max(scores, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)  # masked entries: exp(_NEG - m) == 0
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        # Blocks wholly above the diagonal hold no valid key for this q block.
+        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_block)
+    else:
+        _block()
 
     @pl.when(ik == n_k - 1)
     def _finalize():
@@ -116,8 +134,10 @@ def _pad_to(x, axis, mult):
 
 
 def _fwd_impl(q3, k3, v3, *, causal, block_q, block_k, interpret):
-    """[BH, S, D] flash forward → (out [BH, S, D], lse [BH, S_pad])."""
+    """[BH, S, D] flash forward → (out [BH, S, D], lse [BH, S_pad]); ``k3``
+    and ``v3`` are [BHkv, S, D], row ``b // (BH / BHkv)`` serving q row b."""
     bh, s, d = q3.shape
+    group = bh // k3.shape[0]
     scale = d**-0.5
     qp = _pad_to(q3, 1, block_q)
     kp = _pad_to(k3, 1, block_k)
@@ -131,14 +151,23 @@ def _fwd_impl(q3, k3, v3, *, causal, block_q, block_k, interpret):
     )
     from jax.experimental.pallas import tpu as pltpu
 
+    if causal:
+        # The last k block a q block needs; later grid steps name it again,
+        # so nothing is fetched for the blocks the body skips.
+        def kv_block(b, iq, ik):
+            return (b // group, jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k), 0)
+    else:
+        def kv_block(b, iq, ik):
+            return (b // group, ik, 0)
+
     out, lse = kernel_call(
         "flash_attn_fwd",
         kernel,
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, iq, ik: (b, ik, 0)),
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
@@ -160,35 +189,41 @@ def _fwd_impl(q3, k3, v3, *, causal, block_q, block_k, interpret):
 
 def _bwd_blocked(q3, k3, v3, out, lse, do, *, causal, block_k):
     """Blocked XLA backward from the saved logsumexp: scan over k blocks,
-    recomputing each block's probabilities — O(S·BK) memory, never S×S."""
+    recomputing each block's probabilities — O(S·BK) memory, never S×S. The
+    ``G = BH / BHkv`` query heads that share a key-value head ride one einsum
+    axis, so dk and dv are summed over the group where they are made."""
     bh, s, d = q3.shape
+    bkv = k3.shape[0]
+    g = bh // bkv
     scale = d**-0.5
-    qf = q3.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
+    qf = q3.astype(jnp.float32).reshape(bkv, g, s, d)
+    dof = do.astype(jnp.float32).reshape(bkv, g, s, d)
     # D_i = Σ_d dOut · Out — the softmax-jacobian diagonal term.
-    delta = jnp.sum(dof * out.astype(jnp.float32), axis=-1, keepdims=True)  # [BH,S,1]
-    lse_r = lse[:, :s, None]  # [BH, S, 1]
+    delta = jnp.sum(
+        dof * out.astype(jnp.float32).reshape(bkv, g, s, d), axis=-1, keepdims=True
+    )  # [BHkv, G, S, 1]
+    lse_r = lse[:, :s].reshape(bkv, g, s, 1)
 
     kp = _pad_to(k3.astype(jnp.float32), 1, block_k)
     vp = _pad_to(v3.astype(jnp.float32), 1, block_k)
     n_k = kp.shape[1] // block_k
-    k_blocks = kp.reshape(bh, n_k, block_k, d).transpose(1, 0, 2, 3)
-    v_blocks = vp.reshape(bh, n_k, block_k, d).transpose(1, 0, 2, 3)
+    k_blocks = kp.reshape(bkv, n_k, block_k, d).transpose(1, 0, 2, 3)
+    v_blocks = vp.reshape(bkv, n_k, block_k, d).transpose(1, 0, 2, 3)
     q_pos = lax.broadcasted_iota(jnp.int32, (s, block_k), 0)
 
     def one_block(dq_acc, xs):
         ib, k_blk, v_blk = xs
-        scores = jnp.einsum("bqd,bkd->bqk", qf * scale, k_blk)
+        scores = jnp.einsum("bgqd,bkd->bgqk", qf * scale, k_blk)
         k_pos = ib * block_k + lax.broadcasted_iota(jnp.int32, (s, block_k), 1)
         valid = k_pos < s
         if causal:
             valid = valid & (k_pos <= q_pos)
-        p = jnp.where(valid, jnp.exp(scores - lse_r), 0.0)  # [BH, S, BK]
-        dv_blk = jnp.einsum("bqk,bqd->bkd", p, dof)
-        dp = jnp.einsum("bqd,bkd->bqk", dof, v_blk)
+        p = jnp.where(valid, jnp.exp(scores - lse_r), 0.0)  # [BHkv, G, S, BK]
+        dv_blk = jnp.einsum("bgqk,bgqd->bkd", p, dof)
+        dp = jnp.einsum("bgqd,bkd->bgqk", dof, v_blk)
         ds = p * (dp - delta)
-        dq_acc = dq_acc + jnp.einsum("bqk,bkd->bqd", ds, k_blk) * scale
-        dk_blk = jnp.einsum("bqk,bqd->bkd", ds, qf) * scale
+        dq_acc = dq_acc + jnp.einsum("bgqk,bkd->bgqd", ds, k_blk) * scale
+        dk_blk = jnp.einsum("bgqk,bgqd->bkd", ds, qf) * scale
         return dq_acc, (dk_blk, dv_blk)
 
     dq, (dk_b, dv_b) = lax.scan(
@@ -196,9 +231,9 @@ def _bwd_blocked(q3, k3, v3, out, lse, do, *, causal, block_k):
         jnp.zeros_like(qf),
         (jnp.arange(n_k), k_blocks, v_blocks),
     )
-    dk = dk_b.transpose(1, 0, 2, 3).reshape(bh, n_k * block_k, d)[:, :s]
-    dv = dv_b.transpose(1, 0, 2, 3).reshape(bh, n_k * block_k, d)[:, :s]
-    return dq.astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype)
+    dk = dk_b.transpose(1, 0, 2, 3).reshape(bkv, n_k * block_k, d)[:, :s]
+    dv = dv_b.transpose(1, 0, 2, 3).reshape(bkv, n_k * block_k, d)[:, :s]
+    return dq.reshape(bh, s, d).astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype)
 
 
 @functools.partial(
@@ -223,7 +258,7 @@ def _flash3_fwd(q3, k3, v3, causal, block_q, block_k, interpret):
 def _flash3_bwd(causal, block_q, block_k, interpret, residuals, do):
     q3, k3, v3, out, lse = residuals
     return _bwd_blocked(
-        q3, k3, v3, out, lse, do, causal=causal, block_k=block_k
+        q3, k3, v3, out, lse, do, causal=causal, block_k=min(block_k, BWD_BLOCK_K)
     )
 
 
@@ -235,7 +270,10 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Flash attention over [B, S, H, D] inputs (the repo layout).
+    """Flash attention over [B, S, H, D] inputs (the repo layout); ``k`` and
+    ``v`` may carry fewer heads, [B, S, Hkv, D] with ``H % Hkv == 0``: query
+    head h then reads key-value head ``h // (H / Hkv)`` (grouped-query
+    attention), by index and without a repeated copy.
 
     ``interpret``: None = Pallas on TPU, ``full_attention`` fallback
     elsewhere (or the Pallas interpreter when ``MPT_FLASH_INTERPRET`` is
@@ -249,16 +287,24 @@ def flash_attention(
         if env_flag("MPT_FLASH_INTERPRET"):
             interpret = True
         elif not tpu_backend():
+            group = q.shape[2] // k.shape[2]
+            if group > 1:  # the XLA composition has one head layout
+                k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
             return full_attention(q, k, v, causal=causal)
         else:
             interpret = False
 
     b, s, h, d = q.shape
+    if h % k.shape[2] or k.shape != v.shape:
+        raise ValueError(
+            f"flash_attention: {h} query heads over k {k.shape} / v {v.shape}: "
+            "the key-value heads must divide the query heads"
+        )
     bq = min(block_q, max(8, s))
     bk = min(block_k, max(8, s))
 
     def to3(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], d)
 
     out3 = _flash3(to3(q), to3(k), to3(v), causal, bq, bk, interpret)
     return out3.reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -49,5 +49,9 @@ def accuracy_count(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
 
 
 def valid_count(labels: jnp.ndarray) -> jnp.ndarray:
-    """Number of non-padding rows in a batch."""
-    return jnp.sum((labels >= 0).astype(jnp.int32))
+    """Number of non-padding rows in a batch; a row of a token batch
+    (``labels [B, S]``) counts when any of its positions is valid."""
+    valid = labels >= 0
+    if labels.ndim > 1:
+        valid = jnp.any(valid.reshape(labels.shape[0], -1), axis=-1)
+    return jnp.sum(valid.astype(jnp.int32))

@@ -23,6 +23,12 @@ coefficient to keep routing uniform.
 
 tests/test_moe.py asserts the 8-shard EP result equals a dense single-device
 evaluation of the same routing, values and gradients.
+
+A second routing lives below the first (``dropless_moe``, for
+``models/lfm2.py``): sigmoid scores, a selection bias, top-k over ALL routed
+experts with NO capacity and NO dropped token, computed for the experts this
+chip HOLDS by grouped matmuls over the routed pairs sorted by expert. The two
+share nothing yet; ROADMAP's Design queue says which cell would settle them.
 """
 
 from __future__ import annotations
@@ -248,3 +254,144 @@ def moe_forward(
     g = pick_group_size(t // n, group_size)
     capacity = capacity if capacity is not None else g
     return _moe_jit(mesh, expert_axis, k, capacity, g)(params, x)
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing over the experts held here (models/lfm2.py)
+# ---------------------------------------------------------------------------
+
+
+def sigmoid_topk_route(x, gate, expert_bias, top_k: int, scaling: float = 1.0):
+    """Scores ``s = sigmoid(x W_g)`` in float32 over ALL routed experts; the
+    top-k of ``s + b`` are SELECTED (``b`` steers the choice and nothing
+    else); the weights are the selected ``s`` over their sum (+ 1e-6), times
+    ``scaling``. Returns (ids ``[T, k]`` int32, weights ``[T, k]`` float32).
+    The scores' matmul asks for HIGHEST precision: a TPU's default would round
+    its float32 operands to bf16, and top-k over 64 close scores flips on less."""
+    s = jax.nn.sigmoid(
+        jnp.dot(x.astype(jnp.float32), gate.astype(jnp.float32), precision=lax.Precision.HIGHEST)
+    )
+    _, sel = lax.top_k(s + lax.stop_gradient(expert_bias.astype(jnp.float32)), top_k)
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    return sel, w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6) * scaling
+
+
+def grouped_matmul(rows, weights, group_sizes):
+    """``rows [R, K]`` sorted by group against ``weights [G, K, N]``: rows of
+    group g meet ``weights[g]``; float32 accumulation, the rows' dtype out.
+    ``jax.lax.ragged_dot``: on a TPU one Mosaic grouped-matmul call whose work
+    follows the rows the groups cover (rows past them are not visited)."""
+    return lax.ragged_dot(rows, weights, group_sizes, preferred_element_type=rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _pair_rows(x, perm, inv_perm, top_k: int):
+    """Row r of the result is the token of routed pair ``perm[r]``. Pairs are
+    numbered CHOICE-major — pair p is choice ``p // T`` of token ``p % T`` —
+    so that ``[k * T, D]`` pair rows split into ``[k, T, D]`` along the major
+    dimension alone (token-major pairs would put ``k`` beside ``D`` in the
+    tiled layout, a padded copy each way: 3 ms a layer at 65 536 pairs).
+    ``inv_perm`` is handed in so that the cotangent is a gather too
+    (``ct[inv_perm]``, summed over a token's choices): the transpose of a
+    gather is a scatter-add that cannot know its indices are a permutation."""
+    return jnp.take(x, perm % x.shape[0], axis=0)
+
+
+def _pair_rows_fwd(x, perm, inv_perm, top_k):
+    return _pair_rows(x, perm, inv_perm, top_k), inv_perm
+
+
+def _pair_rows_bwd(top_k, inv_perm, ct):
+    by_choice = jnp.take(ct, inv_perm, axis=0).reshape(top_k, -1, ct.shape[-1])
+    return jnp.sum(by_choice.astype(jnp.float32), axis=0).astype(ct.dtype), None, None
+
+
+_pair_rows.defvjp(_pair_rows_fwd, _pair_rows_bwd)
+
+
+@jax.custom_vjp
+def _unsort(rows, perm, inv_perm):
+    """``rows[inv_perm]``: sorted rows back in pair order; cotangent ``ct[perm]``."""
+    return jnp.take(rows, inv_perm, axis=0)
+
+
+_unsort.defvjp(
+    lambda rows, perm, inv_perm: (jnp.take(rows, inv_perm, axis=0), perm),
+    lambda perm, ct: (jnp.take(ct, perm, axis=0), None, None),
+)
+
+
+def dropless_moe(
+    x, gate, expert_bias, w1, w3, w2, *, top_k: int, expert_offset: int = 0,
+    scaling: float = 1.0,
+):
+    """The expert layer of one expert-parallel rank, without its exchange.
+
+    ``x [T, D]`` tokens; ``gate [D, E]`` and ``expert_bias [E]`` span ALL
+    ``E`` routed experts; ``w1``/``w3 [H, D, F]`` and ``w2 [H, F, D]`` are the
+    ``H`` experts held here, ids ``expert_offset .. expert_offset + H``. Every
+    token routes over all ``E`` (``sigmoid_topk_route``); the result is the
+    weighted sum over its selected experts HELD HERE of ``W2 (silu(W1 h) *
+    W3 h)``; what absent experts would add is left out (their pairs are
+    counted, below). No capacity: the ``T * k`` routed pairs are sorted by
+    expert — absent ones last — and the held ones run through three grouped
+    matmuls whatever their split over the experts, so adversarial routing
+    (every token to one expert) drops nothing.
+
+    The row buffers are sized for the worst case (``T * k`` rows); the grouped
+    matmuls visit only the rows the held pairs fill. The expert FFN is
+    recomputed in the backward pass (``jax.checkpoint``): its ``[T * k, F]``
+    intermediates would otherwise be kept for every layer at the worst-case
+    size, an eighth of them used.
+
+    Returns ``(y [T, D], counters, selected [T, k])``: ``moe_pairs_held``
+    (pairs computed here), ``moe_pairs_absent`` (pairs routed to experts not
+    held), ``moe_load_max`` (largest per-expert count), int32 scalars; and the
+    ids every token selected, for whoever compares routings.
+    """
+    from mpi_pytorch_tpu.obs import trace as obs_trace
+
+    t, d = x.shape
+    held = w1.shape[0]
+    obs_trace.current().instant(
+        "moe/dispatch",
+        {"experts": int(gate.shape[1]), "held": int(held), "top_k": top_k,
+         "tokens": int(t), "path": "ragged_dot"},
+        once=True,
+    )
+    with jax.named_scope("moe/route"):
+        sel, weight = sigmoid_topk_route(x, gate, expert_bias, top_k, scaling)
+    with jax.named_scope("moe/dispatch"):
+        # [k*T] pair -> held index; pair p = choice p // T of token p % T
+        local = sel.T.reshape(-1) - expert_offset
+        here = (local >= 0) & (local < held)
+        key = jnp.where(here, local, held)  # absent pairs sort last
+        perm = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv_perm = jnp.zeros_like(perm).at[perm].set(jnp.arange(t * top_k, dtype=jnp.int32))
+        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        n_held = jnp.sum(group_sizes)
+        filled = (jnp.arange(t * top_k) < n_held)[:, None]  # rows a held pair fills
+
+    @jax.checkpoint
+    def experts(x, w1, w3, w2):
+        with jax.named_scope("moe/dispatch"):
+            rows = jnp.where(filled, _pair_rows(x, perm, inv_perm, top_k), 0)
+        with jax.named_scope("moe/experts"):
+            hidden = jax.nn.silu(grouped_matmul(rows, w1, group_sizes)) * grouped_matmul(
+                rows, w3, group_sizes
+            )
+            out = grouped_matmul(hidden, w2, group_sizes)
+        with jax.named_scope("moe/combine"):
+            # Rows no group covers hold whatever the buffer held.
+            return _unsort(jnp.where(filled, out, 0), perm, inv_perm)
+
+    out = experts(x, w1.astype(x.dtype), w3.astype(x.dtype), w2.astype(x.dtype))
+    with jax.named_scope("moe/combine"):
+        share = jnp.where(here.reshape(top_k, t), weight.T, 0.0).astype(jnp.float32)
+        y = jnp.sum(out.reshape(top_k, t, d) * share[..., None], axis=0)
+    counters = {
+        "moe_pairs_held": n_held.astype(jnp.int32),
+        "moe_pairs_absent": (t * top_k - n_held).astype(jnp.int32),
+        "moe_load_max": jnp.max(group_sizes).astype(jnp.int32),
+    }
+    return y.astype(x.dtype), counters, sel

@@ -130,6 +130,13 @@ def parse_model_specs(text: str) -> tuple[ModelSpec, ...]:
                 f"tenant {name!r}: unsupported architecture {arch!r}; "
                 f"expected one of {SUPPORTED_MODELS}"
             )
+        from mpi_pytorch_tpu.models.registry import TOKEN_MODELS
+
+        if arch in TOKEN_MODELS:
+            raise ValueError(
+                f"tenant {name!r}: {arch!r} is a token model; serving takes "
+                "image requests only"
+            )
         if kwargs.get("admission", 0) < 0:
             raise ValueError(
                 f"tenant {name!r}: admission must be >= 0 (0 = equal "

@@ -64,8 +64,12 @@ def ingest_images(images, compute_dtype):
       device/host cache): the ImageNet normalize runs ON DEVICE in f32 with
       the exact op order of ``pipeline.normalize_image``, where XLA fuses it
       into the first convolution for free;
-    - float batches were normalized on the host and just cast."""
+    - float batches were normalized on the host and just cast;
+    - int32 batches are a token model's ids (``[B, S]``) and pass as they
+      are: the model's embedding reads them."""
     with jax.named_scope("input"):
+        if images.dtype == jnp.int32:
+            return images
         if images.dtype == jnp.uint8:
             x = images.astype(jnp.float32) / 255.0
             x = (x - jnp.asarray(IMAGENET_MEAN, jnp.float32)) / jnp.asarray(
@@ -76,7 +80,9 @@ def ingest_images(images, compute_dtype):
 
 
 def _loss_and_updates(state: TrainState, images, labels, rng, remat: bool = False):
-    """Shared core: forward (train mode), loss, logits, new batch_stats.
+    """Shared core: forward (train mode), loss, logits, new batch_stats,
+    gradients, and what the model counted in this apply (``{}`` for a model
+    that counts nothing).
 
     ``remat`` wraps the forward in ``jax.checkpoint``: activations are
     recomputed during the backward pass instead of being saved — the
@@ -87,7 +93,10 @@ def _loss_and_updates(state: TrainState, images, labels, rng, remat: bool = Fals
         variables = {"params": params}
         # "losses" collects model-internal auxiliary losses (MoE load-balance
         # terms, models/vit.py MoEMlp.sow); empty for every other model.
-        mutable = ["losses"]
+        # "counters" collects what a layer counts per apply (the expert
+        # layers' routed pairs, models/lfm2.py MoE.sow); it joins the step's
+        # metrics and takes no part in the loss.
+        mutable = ["losses", "counters"]
         if state.batch_stats is not None:
             variables["batch_stats"] = state.batch_stats
             mutable.append("batch_stats")
@@ -104,14 +113,27 @@ def _loss_and_updates(state: TrainState, images, labels, rng, remat: bool = Fals
             )
             loss = classification_loss(out, labels) + aux
         logits = out[0] if isinstance(out, tuple) else out
-        return loss, (new_bs, logits)
+        return loss, (new_bs, logits, _sum_counters(updated.get("counters", {})))
 
     if remat:
         loss_fn = jax.checkpoint(loss_fn)
-    (loss, (new_bs, logits)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+    (loss, (new_bs, logits, counters)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
         state.params
     )
-    return loss, logits, new_bs, grads
+    return loss, logits, new_bs, grads, counters
+
+
+def _sum_counters(collection) -> dict:
+    """One number a name over every layer that sowed it: the sum, or the
+    largest where the name ends in ``_max``."""
+    out: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(collection):
+        name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+        if name in out:
+            out[name] = jnp.maximum(out[name], leaf) if name.endswith("_max") else out[name] + leaf
+        else:
+            out[name] = leaf
+    return out
 
 
 def _apply_updates(state: TrainState, grads, new_bs) -> TrainState:
@@ -127,20 +149,28 @@ def _apply_updates(state: TrainState, grads, new_bs) -> TrainState:
         )
 
 
-def _step_metrics(loss, logits, labels, grads) -> dict:
+def _step_metrics(loss, logits, labels, grads, counters=None) -> dict:
     """The step's own numbers, in every compiler-partitioned step flavor.
+    ``counters``: what the model counted in this apply (``_loss_and_updates``;
+    the expert layers' ``moe_pairs_held`` / ``moe_pairs_absent`` /
+    ``moe_load_max``), under its own names; a token batch (labels ``[B, S]``)
+    also reports ``tokens``, its valid positions — ``count`` stays samples.
     grad_norm: the global (all-parameter) L2 norm — the training-health
     signal the obs layer records per step (obs/health.py). A scalar
     reduction XLA fuses into the backward; negligible next to the matmuls,
     and present in every step flavor so telemetry can't depend on which
     mode a run uses."""
     with jax.named_scope("metrics"):
-        return {
+        metrics = {
             "loss": loss,
             "correct": accuracy_count(logits, labels),
             "count": valid_count(labels),
             "grad_norm": optax.global_norm(grads).astype(jnp.float32),
+            **(counters or {}),
         }
+        if labels.ndim > 1:
+            metrics["tokens"] = jnp.sum((labels >= 0).astype(jnp.int32))
+        return metrics
 
 
 def _step_ok(metrics) -> jax.Array:
@@ -206,11 +236,11 @@ def make_train_step(
             images, labels = batch
             images = ingest_images(images, compute_dtype)
             rng = jax.random.fold_in(state.rng, state.step)
-            loss, logits, new_bs, grads = _loss_and_updates(
+            loss, logits, new_bs, grads, counters = _loss_and_updates(
                 state, images, labels, rng, remat=remat
             )
             new_state = _apply_updates(state, grads, new_bs)
-            metrics = _step_metrics(loss, logits, labels, grads)
+            metrics = _step_metrics(loss, logits, labels, grads, counters)
             if bad_step_skip:
                 ok = _step_ok(metrics)
                 new_state = _guard_bad_step(ok, new_state, state)
@@ -265,7 +295,7 @@ def make_train_step(
             grad_sum, bs, loss_sum, correct, count, i = carry
             mimg, mlab = xs
             st = state.replace(batch_stats=bs) if bs is not None else state
-            loss, logits, new_bs, grads = _loss_and_updates(
+            loss, logits, new_bs, grads, _ = _loss_and_updates(
                 st, mimg, mlab, jax.random.fold_in(base_rng, i), remat=remat
             )
             # Weight each microbatch's mean-grad/mean-loss by its valid-row
@@ -386,6 +416,14 @@ def _gather_batch(mesh, compute_dtype, dataset, labels_all, idx, valid):
             raw = _sharded_cache_take(mesh, dataset, idx)
         else:
             raw = jnp.take(dataset, idx, axis=0)
+        if raw.ndim == 2:
+            # Packed token sequences ``int32 [B, S + 1]`` (data/tokens.py),
+            # keyed on the traced rank like ``ingest_images`` on the dtype:
+            # inputs are all but the last id, targets all but the first; a
+            # padding row's targets are all -1. ``labels_all`` is not read.
+            rows = NamedSharding(mesh, P(mesh.axis_names[0]))
+            tokens = lax.with_sharding_constraint(raw[:, :-1], rows)
+            return tokens, jnp.where(valid[:, None], raw[:, 1:], -1)
     images = ingest_images(raw, compute_dtype)  # opens ``input`` itself
     with jax.named_scope("input"):
         images = lax.with_sharding_constraint(
@@ -405,9 +443,11 @@ def _cached_batch_step(
     both rely on the per-step program equalling the scan body)."""
     images, labels = _gather_batch(mesh, compute_dtype, dataset, labels_all, idx, valid)
     rng = jax.random.fold_in(state.rng, state.step)
-    loss, logits, new_bs, grads = _loss_and_updates(state, images, labels, rng, remat=remat)
+    loss, logits, new_bs, grads, counters = _loss_and_updates(
+        state, images, labels, rng, remat=remat
+    )
     new_state = _apply_updates(state, grads, new_bs)
-    metrics = _step_metrics(loss, logits, labels, grads)
+    metrics = _step_metrics(loss, logits, labels, grads, counters)
     if bad_step_skip:
         # Inside the scanned epoch this guards EVERY scan iteration: a
         # non-finite step mid-scan is discarded on device and the scan
@@ -880,7 +920,7 @@ def make_spmd_train_step(
         rng = jax.random.fold_in(
             jax.random.fold_in(state.rng, state.step), shard_idx
         )
-        loss, logits, new_bs, grads = _loss_and_updates(
+        loss, logits, new_bs, grads, _ = _loss_and_updates(
             state, images, labels, rng, remat=remat
         )
         # Running BN stats: normalization above used LOCAL batch stats

@@ -32,7 +32,9 @@ import numpy as np
 from mpi_pytorch_tpu import checkpoint as ckpt
 from mpi_pytorch_tpu.config import Config
 from mpi_pytorch_tpu.data import DataLoader, load_manifests, manifest_fingerprint
+from mpi_pytorch_tpu.data.tokens import TokenLoader, load_token_manifests
 from mpi_pytorch_tpu.models import create_model_bundle
+from mpi_pytorch_tpu.models.registry import TOKEN_MODELS, token_vocab
 from mpi_pytorch_tpu.obs import (
     FlightRecorder,
     Heartbeat,
@@ -165,6 +167,12 @@ def _p0_scalar(value: float, mesh) -> float:
     return _global_max(value if jax.process_index() == 0 else float("-inf"), mesh)
 
 
+# Step metrics that join the epoch record when the step reports them
+# (train/step.py _step_metrics): a token batch's valid positions and the
+# expert layers' counters (models/lfm2.py).
+EPOCH_EXTRAS = ("tokens", "moe_pairs_held", "moe_pairs_absent", "moe_load_max")
+
+
 def _dtype(name: str):
     return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[name]
 
@@ -175,7 +183,14 @@ def build_training(cfg: Config, mesh=None):
     mesh = mesh or create_mesh(cfg.mesh)
     compute_dtype = _dtype(cfg.compute_dtype)
 
-    train_manifest, test_manifest = load_manifests(cfg)
+    token_model = cfg.model_name in TOKEN_MODELS
+    if token_model:
+        # Packed sequences, checked against the model's vocabulary (data/tokens.py).
+        train_manifest, test_manifest = load_token_manifests(
+            cfg, token_vocab(cfg.model_name, cfg.model_config)
+        )
+    else:
+        train_manifest, test_manifest = load_manifests(cfg)
     # Per-host sharding ≙ rank-0 scatter (main.py:84-91): host p reads only
     # its own shard; no coordinator, no pickled dataframes over the wire.
     host_shard = train_manifest.shard(jax.process_count(), jax.process_index())
@@ -199,7 +214,7 @@ def build_training(cfg: Config, mesh=None):
             f"data-parallel size {data_size}"
         )
 
-    train_loader = DataLoader(
+    train_loader = TokenLoader(host_shard, host_batch) if token_model else DataLoader(
         host_shard,
         batch_size=host_batch,
         image_size=cfg.image_size,
@@ -254,6 +269,7 @@ def build_training(cfg: Config, mesh=None):
         # spmd-mode VALIDATION (plain-jit eval over the same model) still
         # gets the partitioned call.
         dp_mesh=mesh,
+        model_config=cfg.model_config,
     )
     # Total optimizer steps for cosine-style schedules: the globally-computed
     # per-epoch step count (identical on every host) x epochs.
@@ -539,8 +555,13 @@ def build_device_cache(cfg: Config, manifest, loader: DataLoader, mesh):
     n_data = mesh.shape[data_axis]
     n = len(manifest)
     padded = -(-n // n_data) * n_data
-    shape = (padded, *loader.image_size, 3)
     sharding = NamedSharding(mesh, P(data_axis))
+    # A token model's rows are its packed sequences ``int32 [S + 1]`` as they
+    # lie in the pack; its label column is the manifest's row checksums,
+    # which the step does not read.
+    tokens = isinstance(loader, TokenLoader)
+    row = (manifest.tokens.shape[1],) if tokens else (*loader.image_size, 3)
+    shape = (padded, *row)
 
     # This host's addressable slice of the sharded rows: contiguous because
     # ``data`` is the leading (process-major) mesh axis.
@@ -552,9 +573,11 @@ def build_device_cache(cfg: Config, manifest, loader: DataLoader, mesh):
     # Preallocate and fill in place: np.concatenate over a parts list would
     # transiently hold the slice twice, at exactly the scale (GBs) this
     # feature targets. Zeros beyond real_hi are the never-indexed padding.
-    local = np.zeros((hi - lo, *loader.image_size, 3), loader.image_dtype)
+    local = np.zeros((hi - lo, *row), np.int32 if tokens else loader.image_dtype)
     labels_np = manifest.labels.astype(np.int32)
-    if real_hi > lo:
+    if tokens:
+        local[: max(real_hi - lo, 0)] = manifest.tokens[lo:real_hi]
+    elif real_hi > lo:
         ordered = DataLoader(
             manifest.select(np.arange(lo, real_hi)),
             batch_size=loader.batch_size,
@@ -1523,6 +1546,7 @@ def _train_impl(
             health.start_epoch()  # re-arm the recompile counter per epoch
             heartbeat.start_epoch()  # beats never span epoch boundaries
             losses, counts = [], []
+            extras: dict[str, list] = {}  # EPOCH_EXTRAS the steps reported
             loss_v = count_v = None  # [steps] device arrays, set below
             rollback_trigger = None  # (reason, step) breaking the step loop
             tracer.end(control, args={"epoch": epoch})
@@ -1581,6 +1605,7 @@ def _train_impl(
                         jax.block_until_ready(m["loss"])
                 _profile_start(epoch, m["loss"])
                 loss_v, count_v = m["loss"], m["count"]
+                extras = {k: [m[k]] for k in EPOCH_EXTRAS if k in m}
                 skipped_before_epoch = steps_skipped_total
                 if bad_step_skip and "skipped" in m:
                     # Mask skipped steps out of the epoch accounting (a
@@ -1670,6 +1695,9 @@ def _train_impl(
                 else:
                     losses.append(m["loss"])
                     counts.append(m["count"])
+                for k in EPOCH_EXTRAS:
+                    if k in m:
+                        extras.setdefault(k, []).append(m[k])
                 health.on_step(
                     epoch, step_i, m, data_wait_s, step_s,
                     skipped=was_skipped,
@@ -1751,6 +1779,15 @@ def _train_impl(
                 else:
                     n_valid = 0.0
                     epoch_loss = float("nan")
+                # What the steps counted beside the loss (a token model's
+                # tokens, the expert layers' routed pairs): the epoch's sum,
+                # or its largest where the name ends in ``_max``.
+                epoch_extras = {
+                    k: int(getattr(np, "max" if k.endswith("_max") else "sum")(
+                        np.concatenate([np.ravel(v) for v in jax.device_get(vals)])
+                    ))
+                    for k, vals in extras.items()
+                }
             with tracer.span("epoch/record", args={"epoch": epoch}):
                 if scan_inputs is not None:
                     # Per-step records post-hoc from the [n_steps] arrays
@@ -1785,9 +1822,12 @@ def _train_impl(
                     epoch, epoch_loss, dt, ips,
                     f", MFU {mfu:.1f}%" if mfu is not None else "",
                 )
+                if "tokens" in epoch_extras:
+                    epoch_extras["tokens_per_sec"] = epoch_extras["tokens"] / dt if dt > 0 else 0.0
                 metrics.write(
                     {"kind": "epoch", "epoch": epoch, "loss": epoch_loss, "time_s": dt,
-                     "images_per_sec": ips, "tflops": tflops, "mfu_pct": mfu}
+                     "images_per_sec": ips, "tflops": tflops, "mfu_pct": mfu,
+                     **epoch_extras}
                 )
                 if registry is not None:
                     # The MFU-estimate / throughput gauges a fleet controller

@@ -58,6 +58,8 @@ def test_parse_model_specs_syntax():
         parse_model_specs("resnet18,resnet18")
     with pytest.raises(ValueError, match="unsupported architecture"):
         parse_model_specs("not_a_model")
+    with pytest.raises(ValueError, match="token model"):
+        parse_model_specs("lfm2_moe")  # serving takes image requests only
     with pytest.raises(ValueError, match="unknown spec key"):
         parse_model_specs("resnet18:bogus=1")
     with pytest.raises(ValueError, match="precision"):

@@ -107,6 +107,16 @@ def _attn_args(b, s, h=ATTN_H):
     return make
 
 
+def _gqa_args(b, s, h, hkv):
+    """q [b, s, h, D] with k, v [b, s, hkv, D] and a cotangent of q's shape."""
+    def make():
+        ks = jax.random.split(jax.random.PRNGKey(3), 4)
+        shapes = [(b, s, h, ATTN_D), (b, s, hkv, ATTN_D), (b, s, hkv, ATTN_D), (b, s, h, ATTN_D)]
+        return tuple(jax.random.normal(k, shape, jnp.bfloat16) for k, shape in zip(ks, shapes))
+
+    return make
+
+
 def _head_args(rows, grads):
     def make():
         k1, k2, k3, k4, k5 = jax.random.split(jax.random.PRNGKey(2), 5)
@@ -171,6 +181,23 @@ def _cases() -> list[Case]:
             _with_grads(lambda q, k, v: flash_attention(q, k, v, interpret=False), 3),
             _with_grads(full_attention, 3),
             _attn_args(2, 2048),
+            tol=5e-2,
+        ),
+        # LFM2's attention layer: 8 key-value heads for 32 query heads, causal,
+        # the model's 512-wide blocks (models/lfm2.py FLASH_BLOCK).
+        Case(
+            "flash_attention[S=2048,H=32/8,causal]",
+            _with_grads(
+                lambda q, k, v: flash_attention(
+                    q, k, v, causal=True, block_q=512, block_k=512, interpret=False
+                ), 3,
+            ),
+            _with_grads(
+                lambda q, k, v: full_attention(
+                    q, jnp.repeat(k, 4, axis=2), jnp.repeat(v, 4, axis=2), causal=True
+                ), 3,
+            ),
+            _gqa_args(1, 2048, 32, 8),
             tol=5e-2,
         ),
         Case(
