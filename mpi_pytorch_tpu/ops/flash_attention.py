@@ -19,22 +19,36 @@ Design notes:
   (m, l, acc) state lives in VMEM scratch and persists across the k
   iterations (TPU grids iterate sequentially); the last k block finalizes
   ``acc / l`` and also writes the logsumexp per row.
-- Backward is BLOCKED XLA, not a second kernel: with the forward's saved
-  logsumexp, each k-block's probabilities are recomputed inside a
-  ``lax.scan`` (one extra q@kᵀ per block — FLOPs are cheap, HBM is not),
-  so backward memory is O(S·BK) too. XLA fuses the per-block chain well,
-  and the scan keeps this correctness-critical code in plain jnp.
+- Backward is two more Pallas kernels under one scope
+  (``kernel/flash_attn_bwd``), from the forward's residuals (q, k, v, out,
+  logsumexp) and ``delta = rowsum(dOut · out)``: each (q block, k block)
+  tile's probabilities are recomputed as ``exp(q kᵀ · scale − lse)`` and
+  scores, probabilities and dS live in VMEM only. ``flash_attn_bwd_dkv``
+  walks key blocks outermost and, innermost, the query blocks of every
+  query head of the key-value group, accumulating dk and dv in float32
+  scratch and writing each once; its tiles are [BK, BQ], so the per-query
+  ``lse`` and ``delta`` are [1, BQ] rows of narrow [B·H, 1, S] arrays.
+  ``flash_attn_bwd_dq`` runs on the forward's grid (key blocks innermost,
+  dq in float32 scratch) with [BQ, BK] tiles, the two rows turned into
+  columns once a query block. Each kernel recomputes the scores: seven
+  matmuls a tile pair for the algorithm's five. Their block sizes are this
+  module's constants, chosen on the chip whatever the forward's.
 - Grouped key-value heads (``k``/``v`` with fewer heads than ``q``): query
   head h reads key-value head ``h // (H / Hkv)`` through the kernel's index
-  map — k and v are never repeated in HBM; the backward folds a group's
-  query heads into one einsum, so dk and dv come out summed over the group.
-- Causal: a k block wholly above the diagonal is neither computed (the body
-  is skipped) nor fetched (its index clamps to the last block the q block
-  needs, which the pipeline already holds).
-- The two dots run in the operands' dtype with float32 accumulation (bf16
-  operands take the MXU's bf16 rate; float32 operands stay float32).
+  map — k and v are never repeated in HBM; the dk/dv kernel streams a
+  group's query heads past one key block, so dk and dv come out summed over
+  the group.
+- Causal: a tile wholly above the diagonal is neither computed (the body
+  is skipped) nor fetched (its index clamps to a block the pipeline already
+  holds: the last k block a q block needs, forward and dq; the first q block
+  a k block reaches, dk/dv).
+- Every dot runs in the operands' dtype with float32 accumulation (bf16
+  operands take the MXU's bf16 rate; float32 operands stay float32): p and
+  dS are cast to it for the MXU; exp, the mask, dS and every accumulator are
+  float32.
 - Sequences that don't divide the block sizes are zero-padded and masked
-  (padded KEYS get -1e30 before the softmax; padded q rows are sliced off).
+  (padded KEYS get -1e30 before the softmax; padded q rows are sliced off,
+  and carry dOut = 0 in the backward).
 - Non-TPU backends fall back to ``full_attention`` (identical math, the
   reference this kernel is validated against in
   tests/test_flash_attention.py via interpret mode) — mirroring
@@ -59,9 +73,12 @@ from mpi_pytorch_tpu.ops.kernel_call import kernel_call
 _NEG = -1e30  # finite mask value: keeps the online-softmax recurrence NaN-free
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
-# The blocked backward keeps [B·Hkv, G, S, BK] float32 temporaries: its own,
-# smaller k block whatever the forward's.
-BWD_BLOCK_K = 128
+# The backward kernels' (query, key) block sizes, whatever the forward's: the
+# fastest of a sweep over 128..2048 a side on a v5e at S=8192, Dh=64, for the
+# dk/dv kernel and for the dq kernel alike (PERF.md section 6, PR 28); three
+# [1024, 1024] float32 tiles fit the 16 MiB of VMEM a kernel gets, 2048 x 1024
+# does not.
+BWD_BLOCKS = (1024, 1024)
 
 
 def _attn_fwd_kernel(
@@ -184,56 +201,207 @@ def _fwd_impl(q3, k3, v3, *, causal, block_q, block_k, interpret):
         ],
         interpret=interpret,
     )(qp, kp, vp)
-    return out[:, :s], lse[:, :, 0]
+    # Every lane holds the row's value: a max over them reads the kernel's
+    # output as it lies (``lse[:, :, 0]`` made XLA copy all of it into
+    # another layout first, 0.8 ms at [64, 8192, 128]).
+    return out[:, :s], jnp.max(lse, axis=-1)
 
 
-def _bwd_blocked(q3, k3, v3, out, lse, do, *, causal, block_k):
-    """Blocked XLA backward from the saved logsumexp: scan over k blocks,
-    recomputing each block's probabilities — O(S·BK) memory, never S×S. The
-    ``G = BH / BHkv`` query heads that share a key-value head ride one einsum
-    axis, so dk and dv are summed over the group where they are made."""
+def _mask_as_forward(scores, *, causal, seq_len, q0, k0, q_axis):
+    """``scores`` at -1e30 wherever the forward masks: padded keys and,
+    causal, keys after the query. ``q_axis`` is the tile's query axis (0 in a
+    [BQ, BK] tile, 1 in a [BK, BQ] one)."""
+    k_pos = k0 + lax.broadcasted_iota(jnp.int32, scores.shape, 1 - q_axis)
+    valid = k_pos < seq_len
+    if causal:
+        q_pos = q0 + lax.broadcasted_iota(jnp.int32, scores.shape, q_axis)
+        valid = valid & (k_pos <= q_pos)
+    return jnp.where(valid, scores, _NEG)
+
+
+def _attn_bwd_dkv_kernel(
+    q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+    *, scale: float, causal: bool, seq_len: int, block_q: int, block_k: int,
+    n_q: int, group: int,
+):
+    """dk and dv of one key block: the query blocks of the ``group`` query
+    heads that share the key-value head stream past it, innermost. Tiles are
+    [BK, BQ] (keys on sublanes), so the per-query ``lse`` and ``delta`` are
+    [1, BQ] rows and both accumulating matmuls are plain ``a @ b``."""
+    ik, ig, iq = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+
+    @pl.when((ig == 0) & (iq == 0))
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def _block():
+        q, do = q_ref[0], do_ref[0]
+        scores = jax.lax.dot_general(
+            k_ref[0], q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [BK, BQ]
+        scores = _mask_as_forward(
+            scores, causal=causal, seq_len=seq_len,
+            q0=iq * block_q, k0=ik * block_k, q_axis=1,
+        )
+        p = jnp.exp(scores - lse_ref[0])  # masked entries: exp(_NEG - lse) == 0
+        dp = jax.lax.dot_general(
+            v_ref[0], do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - delta_ref[0])
+        dv_scr[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk_scr[:] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    if causal:
+        # Query blocks wholly before the key block see none of its keys.
+        pl.when(iq * block_q + block_q - 1 >= ik * block_k)(_block)
+    else:
+        _block()
+
+    @pl.when((ig == group - 1) & (iq == n_q - 1))
+    def _finalize():
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _attn_bwd_dq_kernel(
+    q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref, dq_scr, lse_scr, delta_scr,
+    *, scale: float, causal: bool, seq_len: int, block_q: int, block_k: int,
+    n_k: int,
+):
+    """dq of one query block on the forward's grid (key blocks innermost).
+    Tiles are [BQ, BK] as in the forward, so ``dq += ds @ k`` is plain; the
+    [1, BQ] rows of ``lse`` and ``delta`` are turned into columns once a
+    query block."""
+    iq, ik = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ik == 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+        lanes = lse_scr.shape[::-1]
+        lse_scr[:] = jnp.broadcast_to(lse_ref[0], lanes).T
+        delta_scr[:] = jnp.broadcast_to(delta_ref[0], lanes).T
+
+    def _block():
+        k = k_ref[0]
+        scores = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [BQ, BK]
+        scores = _mask_as_forward(
+            scores, causal=causal, seq_len=seq_len,
+            q0=iq * block_q, k0=ik * block_k, q_axis=0,
+        )
+        p = jnp.exp(scores - lse_scr[:, :1])
+        dp = jax.lax.dot_general(
+            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - delta_scr[:, :1])
+        dq_scr[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    if causal:
+        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_block)
+    else:
+        _block()
+
+    @pl.when(ik == n_k - 1)
+    def _finalize():
+        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
+
+
+def _bwd_blocks(s: int) -> tuple[int, int]:
+    """The backward kernels' (query, key) block sizes for a sequence of ``s``:
+    the module's constants, clipped to the sequence in whole 128-lane tiles
+    (each is the lane dimension of one kernel's score tiles)."""
+    whole = -(-s // 128) * 128
+    return min(BWD_BLOCKS[0], whole), min(BWD_BLOCKS[1], whole)
+
+
+def _bwd_impl(q3, k3, v3, out, lse, do, *, causal, interpret):
+    """dq, dk, dv from the forward's residuals: two Pallas kernels under one
+    scope, probabilities recomputed from the saved logsumexp in VMEM, blocks
+    wholly above the causal diagonal neither computed nor fetched. ``k3`` /
+    ``v3`` are [BHkv, S, D]; dk and dv come out summed over each group."""
+    from jax.experimental.pallas import tpu as pltpu
+
     bh, s, d = q3.shape
     bkv = k3.shape[0]
-    g = bh // bkv
+    group = bh // bkv
     scale = d**-0.5
-    qf = q3.astype(jnp.float32).reshape(bkv, g, s, d)
-    dof = do.astype(jnp.float32).reshape(bkv, g, s, d)
     # D_i = Σ_d dOut · Out — the softmax-jacobian diagonal term.
-    delta = jnp.sum(
-        dof * out.astype(jnp.float32).reshape(bkv, g, s, d), axis=-1, keepdims=True
-    )  # [BHkv, G, S, 1]
-    lse_r = lse[:, :s].reshape(bkv, g, s, 1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    lse = lse[:, :s]
 
-    kp = _pad_to(k3.astype(jnp.float32), 1, block_k)
-    vp = _pad_to(v3.astype(jnp.float32), 1, block_k)
-    n_k = kp.shape[1] // block_k
-    k_blocks = kp.reshape(bkv, n_k, block_k, d).transpose(1, 0, 2, 3)
-    v_blocks = vp.reshape(bkv, n_k, block_k, d).transpose(1, 0, 2, 3)
-    q_pos = lax.broadcasted_iota(jnp.int32, (s, block_k), 0)
-
-    def one_block(dq_acc, xs):
-        ib, k_blk, v_blk = xs
-        scores = jnp.einsum("bgqd,bkd->bgqk", qf * scale, k_blk)
-        k_pos = ib * block_k + lax.broadcasted_iota(jnp.int32, (s, block_k), 1)
-        valid = k_pos < s
-        if causal:
-            valid = valid & (k_pos <= q_pos)
-        p = jnp.where(valid, jnp.exp(scores - lse_r), 0.0)  # [BHkv, G, S, BK]
-        dv_blk = jnp.einsum("bgqk,bgqd->bkd", p, dof)
-        dp = jnp.einsum("bgqd,bkd->bgqk", dof, v_blk)
-        ds = p * (dp - delta)
-        dq_acc = dq_acc + jnp.einsum("bgqk,bkd->bgqd", ds, k_blk) * scale
-        dk_blk = jnp.einsum("bgqk,bgqd->bkd", ds, qf) * scale
-        return dq_acc, (dk_blk, dv_blk)
-
-    dq, (dk_b, dv_b) = lax.scan(
-        one_block,
-        jnp.zeros_like(qf),
-        (jnp.arange(n_k), k_blocks, v_blocks),
+    bq, bk = _bwd_blocks(s)
+    # Padded query rows carry dOut = 0 (and lse = delta = 0): they add nothing
+    # to dk or dv, and their dq rows are sliced off.
+    rows = lambda x: _pad_to(x, 1, bq)[:, None, :]  # [BH, 1, S_pad]: narrow in HBM
+    args = (
+        _pad_to(q3, 1, bq), _pad_to(do, 1, bq), rows(lse), rows(delta),
+        _pad_to(k3, 1, bk), _pad_to(v3, 1, bk),
     )
-    dk = dk_b.transpose(1, 0, 2, 3).reshape(bkv, n_k * block_k, d)[:, :s]
-    dv = dv_b.transpose(1, 0, 2, 3).reshape(bkv, n_k * block_k, d)[:, :s]
-    return dq.reshape(bh, s, d).astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype)
+    n_q, n_k = args[0].shape[1] // bq, args[4].shape[1] // bk
+
+    def q_block(ik, iq):
+        # Causal: the first query block a key block reaches; earlier grid steps
+        # name it too, so nothing is fetched for the blocks the body skips.
+        return jnp.maximum(iq, (ik * bk) // bq) if causal else iq
+
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, ik, ig, iq: (b * group + ig, q_block(ik, iq), 0))
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, ik, ig, iq: (b * group + ig, 0, q_block(ik, iq)))
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, ik, ig, iq: (b, ik, 0))
+    dk, dv = kernel_call(
+        "flash_attn_bwd",
+        functools.partial(
+            _attn_bwd_dkv_kernel, scale=scale, causal=causal, seq_len=s,
+            block_q=bq, block_k=bk, n_q=n_q, group=group,
+        ),
+        name="flash_attn_bwd_dkv",
+        grid=(bkv, n_k, group, n_q),
+        in_specs=[q_spec, q_spec, row_spec, row_spec, kv_spec, kv_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(args[4].shape, k3.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
+        interpret=interpret,
+    )(*args)
+
+    def k_block(iq, ik):  # causal: the last key block a query block needs, as the forward
+        return jnp.minimum(ik, (iq * bq + bq - 1) // bk) if causal else ik
+
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, iq, ik: (b, iq, 0))
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, iq, ik: (b, 0, iq))
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, iq, ik: (b // group, k_block(iq, ik), 0))
+    dq = kernel_call(
+        "flash_attn_bwd",
+        functools.partial(
+            _attn_bwd_dq_kernel, scale=scale, causal=causal, seq_len=s,
+            block_q=bq, block_k=bk, n_k=n_k,
+        ),
+        name="flash_attn_bwd_dq",
+        grid=(bh, n_q, n_k),
+        in_specs=[q_spec, q_spec, row_spec, row_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(args[0].shape, q3.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((bq, d), jnp.float32),  # dq accumulator
+            pltpu.VMEM((bq, 128), jnp.float32),  # lse, a column
+            pltpu.VMEM((bq, 128), jnp.float32),  # delta, a column
+        ],
+        interpret=interpret,
+    )(*args)
+    return dq[:, :s], dk[:, :s], dv[:, :s]
 
 
 @functools.partial(
@@ -257,9 +425,7 @@ def _flash3_fwd(q3, k3, v3, causal, block_q, block_k, interpret):
 
 def _flash3_bwd(causal, block_q, block_k, interpret, residuals, do):
     q3, k3, v3, out, lse = residuals
-    return _bwd_blocked(
-        q3, k3, v3, out, lse, do, causal=causal, block_k=min(block_k, BWD_BLOCK_K)
-    )
+    return _bwd_impl(q3, k3, v3, out, lse, do, causal=causal, interpret=interpret)
 
 
 _flash3.defvjp(_flash3_fwd, _flash3_bwd)

@@ -120,6 +120,66 @@ def test_flash_tiny_s_bf16():
     )
 
 
+def _bwd_case(name, *, causal, group, s, dtype, blocks):
+    return pytest.param(causal, group, s, dtype, blocks, id=name)
+
+
+# (query, key) blocks of the backward kernels: never square, so a swapped
+# index or block size shows.
+BWD_CASES = [
+    _bwd_case(
+        f"{'causal' if causal else 'dense'}-g{group}-s{s}-{np.dtype(dtype).name}-{bq}x{bk}",
+        causal=causal, group=group, s=s, dtype=dtype, blocks=(bq, bk),
+    )
+    for causal in (False, True)
+    for group in (1, 4)
+    for s in (64, 50)  # divides the blocks; padded
+    for dtype in (jnp.float32, jnp.bfloat16)
+    for bq, bk in ((16, 32), (32, 16))
+] + [
+    # S <= block: one block a side, padded keys and the diagonal in the same tile.
+    _bwd_case("one-block-a-side", causal=True, group=4, s=50, dtype=jnp.float32, blocks=(1024, 1024)),
+    # Square blocks: the first query block sees exactly one key block and the
+    # last key block is seen by exactly one query block (the clamps' edges).
+    _bwd_case("clamp-edge", causal=True, group=4, s=64, dtype=jnp.float32, blocks=(16, 16)),
+]
+
+
+@pytest.mark.parametrize("causal,group,s,dtype,blocks", BWD_CASES)
+def test_flash_backward_kernels_match_grad_of_full_attention(
+    monkeypatch, causal, group, s, dtype, blocks
+):
+    """dq, dk, dv of the two backward kernels (interpreted) against
+    ``jax.grad`` of ``full_attention`` with k and v repeated over the group:
+    float32 tight; bf16 against the float32 gradient at the same bf16 inputs,
+    as loose as the operands' rounding of p and ds."""
+    from mpi_pytorch_tpu.ops import flash_attention as module
+
+    monkeypatch.setattr(module, "BWD_BLOCKS", blocks)
+    b, hkv, d = 2, 2, 8
+    h = hkv * group
+    ks = jax.random.split(jax.random.PRNGKey(40 + s + group), 4)
+    q, co = (jax.random.normal(key, (b, s, h, d), jnp.float32).astype(dtype) for key in ks[::3])
+    k, v = (jax.random.normal(key, (b, s, hkv, d), jnp.float32).astype(dtype) for key in ks[1:3])
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=16, block_k=32, interpret=True)
+
+    def full(q, k, v):
+        return full_attention(q, jnp.repeat(k, group, 2), jnp.repeat(v, group, 2), causal=causal)
+
+    got = jax.vjp(flash, q, k, v)[1](co)
+    f32 = lambda *xs: (x.astype(jnp.float32) for x in xs)
+    want = jax.vjp(full, *f32(q, k, v))[1](*f32(co))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for name, a, w in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == w.shape and a.dtype == dtype, name
+        a = np.asarray(a, np.float32)
+        assert np.isfinite(a).all(), name
+        rel = np.linalg.norm(a - np.asarray(w)) / np.linalg.norm(np.asarray(w))
+        assert rel < tol, (name, rel)
+
+
 def test_flash_cpu_fallback_is_full_attention():
     """interpret=None off-TPU must route to full_attention (identical
     output, no Pallas involved) — the production CPU/GPU gating."""

@@ -57,6 +57,26 @@ def test_model_matches_the_reference_logits_loss_and_every_gradient_leaf(attn_im
             assert _rel(g, w) < 1e-4, name
 
 
+def test_every_gradient_leaf_through_many_blocks_of_the_flash_kernels(monkeypatch):
+    """The model's 64 tokens in 16-wide forward and 32 x 16 backward blocks:
+    forward, dk/dv and dq kernels each walk several blocks a side, skip those
+    above the diagonal and sum dk and dv over the 2 query heads of a group."""
+    from mpi_pytorch_tpu.models import lfm2
+    from mpi_pytorch_tpu.ops import flash_attention
+
+    monkeypatch.setenv("MPT_FLASH_INTERPRET", "1")
+    monkeypatch.setattr(lfm2, "FLASH_BLOCK", 16)
+    monkeypatch.setattr(flash_attention, "BWD_BLOCKS", (32, 16))
+    model = lfm2_moe(0, model_config=json.dumps(TINY), attn_impl="flash")
+    x, y = _tokens(2)
+    variables = {"params": model.init(jax.random.PRNGKey(0), x)["params"]}
+    got = jax.grad(lambda p: ref.cross_entropy(model.apply({"params": p}, x), y))(variables["params"])
+    _, want = ref.loss_and_grads(variables, x, y, expert_offset=4)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
+        if not jax.tree_util.keystr(path).endswith("['expert_bias']"):
+            assert _rel(g, w) < 1e-4, jax.tree_util.keystr(path)
+
+
 def test_remat_blocks_is_the_same_function():
     x, y = _tokens(2)
     plain = lfm2_moe(0, model_config=json.dumps(TINY))

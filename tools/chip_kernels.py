@@ -117,6 +117,30 @@ def _gqa_args(b, s, h, hkv):
     return make
 
 
+def _gqa_flash_case(b, s, h, hkv) -> Case:
+    """Causal flash attention with grouped heads at the model's 512-wide
+    blocks, forward and gradients; the reference repeats k and v."""
+    from mpi_pytorch_tpu.ops.flash_attention import flash_attention
+    from mpi_pytorch_tpu.ops.ring_attention import full_attention
+
+    group = h // hkv
+    return Case(
+        f"flash_attention[S={s},H={h}/{hkv},causal]",
+        _with_grads(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, block_q=512, block_k=512, interpret=False
+            ), 3,
+        ),
+        _with_grads(
+            lambda q, k, v: full_attention(
+                q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2), causal=True
+            ), 3,
+        ),
+        _gqa_args(b, s, h, hkv),
+        tol=5e-2,
+    )
+
+
 def _head_args(rows, grads):
     def make():
         k1, k2, k3, k4, k5 = jax.random.split(jax.random.PRNGKey(2), 5)
@@ -184,22 +208,11 @@ def _cases() -> list[Case]:
             tol=5e-2,
         ),
         # LFM2's attention layer: 8 key-value heads for 32 query heads, causal,
-        # the model's 512-wide blocks (models/lfm2.py FLASH_BLOCK).
-        Case(
-            "flash_attention[S=2048,H=32/8,causal]",
-            _with_grads(
-                lambda q, k, v: flash_attention(
-                    q, k, v, causal=True, block_q=512, block_k=512, interpret=False
-                ), 3,
-            ),
-            _with_grads(
-                lambda q, k, v: full_attention(
-                    q, jnp.repeat(k, 4, axis=2), jnp.repeat(v, 4, axis=2), causal=True
-                ), 3,
-            ),
-            _gqa_args(1, 2048, 32, 8),
-            tol=5e-2,
-        ),
+        # the model's 512-wide blocks (models/lfm2.py FLASH_BLOCK); then the
+        # benchmark cell's sequence (lfm2_train_hbm_8k) for one key-value group
+        # of one sequence: the float32 reference's scores are 1 GB.
+        _gqa_flash_case(1, 2048, 32, 8),
+        _gqa_flash_case(1, 8192, 4, 1),
         Case(
             "fused_attention_small",
             _with_grads(lambda q, k, v: fused_attention_small(q, k, v, interpret=False), 3),
