@@ -15,24 +15,6 @@ import os
 from dataclasses import dataclass, field
 from typing import Any, Sequence
 
-# Architectures with full parity to the reference zoo (``models.py:30-95``),
-# plus the beyond-parity vit_* family (sequence models; SP-capable encoder).
-SUPPORTED_MODELS = (
-    "resnet18",
-    "resnet34",
-    "alexnet",
-    "vgg11_bn",
-    "squeezenet1_0",
-    "densenet121",
-    "inception_v3",
-    "mobilenet_v2",
-    "efficientnet_b0",
-    "vit_s16",
-    "vit_b16",
-    "vit_moe_s16",
-    "lfm2_moe",
-)
-
 # ImageNet normalization constants (reference ``main.py:62-65``).
 IMAGENET_MEAN = (0.485, 0.456, 0.406)
 IMAGENET_STD = (0.229, 0.224, 0.225)
@@ -115,10 +97,10 @@ class Config:
 
     # --- model (utils.py:4, :39-45) ---
     model_name: str = "resnet18"
-    # The architecture of a model that is configured, not named (registry
-    # CONFIGURED_MODELS: lfm2_moe): ONE JSON object in the source's own
-    # config.json key names, inline or the path of a file that holds it
-    # (models/lfm2.py Lfm2Config). Empty: the source's published values.
+    # The architecture of a model that is configured, not named (the ones
+    # whose registry ModelSpec accepts ``model_config``): ONE JSON object in
+    # the source's own config.json key names, inline or the path of a file
+    # that holds it (models/lfm2.py Lfm2Config). Empty: the source's published values.
     model_config: str = ""
     num_classes: int = 64500
     feature_extract: bool = False
@@ -203,8 +185,8 @@ class Config:
     # Rematerialization strategy: "none" | "full" | "blocks".
     # "full" wraps the whole forward in jax.checkpoint (measured NOT to pay
     # for these CNNs — docs/RESULTS.md §4b); "blocks" checkpoints each
-    # residual block / dense layer / encoder block (resnet18/34,
-    # densenet121, vit_s16/b16 — registry.REMAT_BLOCKS_MODELS), recomputing
+    # residual block / dense layer / encoder block (the models whose
+    # registry ModelSpec accepts ``remat_blocks``), recomputing
     # one block at a time during backward — the placement that can actually
     # cut activation memory.
     remat: str = "none"
@@ -252,20 +234,20 @@ class Config:
     # through them GPipe-style (parallel/pipeline.py) — composed with DP over
     # the remaining devices. Same param tree, same checkpoints: PP is purely
     # an execution strategy (the apply_fn is swapped, nothing else). Dense
-    # ViT models only (registry.PP_MODELS); auto mode only.
+    # ViT models only (ModelSpec flag ``pp_stages``); auto mode only.
     pp_stages: int = 1
     # Microbatches streamed through the pipeline per step; 0 → 2*pp_stages.
     # The GPipe bubble fraction is (S-1)/(M+S-1): raise M to amortize it.
     pp_microbatches: int = 0
-    # Space-to-depth stem for the resnet family (registry.S2D_MODELS): the
-    # 7×7/stride-2 conv on 3 input channels becomes an exactly-equivalent
-    # 4×4/stride-1 conv on 12 channels (MLPerf conv0 trick) — keeps the
+    # Space-to-depth stem for the resnet family (ModelSpec flag
+    # ``stem_s2d``): the 7×7/stride-2 conv on 3 input channels becomes an
+    # exactly-equivalent 4×4/stride-1 conv on 12 channels (MLPerf conv0 trick) — keeps the
     # MXU's contracting dimension filled at the stem. Checkpoints carry the
     # (4,4,12,64) kernel; pretrained 7×7 weights load through the exact
     # transform (models/resnet.py s2d_stem_kernel). Requires even image size.
     stem_s2d: bool = False
-    # Fused stem for the identical-7×7-stem family (registry.
-    # FUSED_STEM_MODELS: resnet18/34 — the measured winners — plus
+    # Fused stem for the identical-7×7-stem family (ModelSpec flag
+    # ``fused_stem``: resnet18/34 — the measured winners — plus
     # densenet121, whose torchvision stem features.conv0..pool0 is the same
     # geometry; capability-enabled, A/B staged — docs/RESULTS.md §4):
     # BN+relu+maxpool(3,2,1) as one Pallas kernel pair (ops/fused_stem.py) —
@@ -705,11 +687,11 @@ class Config:
     mesh: MeshConfig = field(default_factory=MeshConfig)
 
     def validate_config(self) -> None:
-        if self.model_name not in SUPPORTED_MODELS:
-            raise ValueError(
-                f"unsupported model {self.model_name!r}; expected one of {SUPPORTED_MODELS}"
-                " (parity with reference models.py:97-99, but raising instead of exit())"
-            )
+        # What the model is, is the registry's to say (models/registry.py
+        # ModelSpec); an unknown name is refused there.
+        from mpi_pytorch_tpu.models.registry import check_build_flags, model_spec
+
+        spec = model_spec(self.model_name)
         if self.num_classes < 2:
             raise ValueError(f"num_classes must be >= 2, got {self.num_classes}")
         if self.batch_size < 1:
@@ -783,16 +765,20 @@ class Config:
             raise ValueError(
                 f"attn_impl must be full|flash|fused-small, got {self.attn_impl!r}"
             )
-        from mpi_pytorch_tpu.models.registry import (
-            ATTN_IMPL_MODELS, CONFIGURED_MODELS, TOKEN_MODELS,
+        # Every "flag X does not apply to model Y" rule is the registry's
+        # (pp_stages joins below, once its own range is checked).
+        check_build_flags(
+            self.model_name,
+            model_config=self.model_config,
+            attn_impl=self.attn_impl,
+            sp_strategy=self.sp_strategy,
+            ep_mesh=self.expert_parallel or None,
+            qkv_fused=self.qkv_fused,
+            remat_blocks=self.remat == "blocks",
+            stem_s2d=self.stem_s2d,
+            fused_stem=self.fused_stem,
         )
-
-        if self.model_config and self.model_name not in CONFIGURED_MODELS:
-            raise ValueError(
-                f"model_config is read by {', '.join(CONFIGURED_MODELS)}; "
-                f"{self.model_name!r} is built from its name alone"
-            )
-        if self.model_name in TOKEN_MODELS:
+        if spec.sample == "tokens":
             # A token model's samples are packed sequences (data/tokens.py):
             # they train from the device cache; the image loader, validation
             # and the spmd step are image paths.
@@ -807,25 +793,13 @@ class Config:
                     f"{self.model_name!r}: validation is an image path; set "
                     "validate=False"
                 )
-            if self.attn_impl == "fused-small":
-                raise ValueError(
-                    f"{self.model_name!r}: attn_impl is full|flash (the single-pass "
-                    "kernel has no causal, grouped-head form)"
-                )
-        if self.attn_impl != "full":
-            if self.model_name not in ATTN_IMPL_MODELS:
-                raise ValueError(
-                    f"attn_impl={self.attn_impl!r} applies only to the "
-                    f"attention family ({', '.join(ATTN_IMPL_MODELS)}); "
-                    f"{self.model_name!r} has no attention"
-                )
-            if self.sp_strategy != "none":
-                raise ValueError(
-                    f"attn_impl={self.attn_impl!r} is the dense-attention "
-                    "path (data-parallel over chips); the SP strategies "
-                    "(--sp-strategy) already compute attention blockwise "
-                    "across chips — choose one"
-                )
+        if self.attn_impl != "full" and self.sp_strategy != "none":
+            raise ValueError(
+                f"attn_impl={self.attn_impl!r} is the dense-attention "
+                "path (data-parallel over chips); the SP strategies "
+                "(--sp-strategy) already compute attention blockwise "
+                "across chips — choose one"
+            )
         if self.optimizer not in ("adam", "sgd", "adamw"):
             raise ValueError(f"optimizer must be adam|sgd|adamw, got {self.optimizer!r}")
         if self.lr_schedule not in ("constant", "cosine", "warmup_cosine"):
@@ -1330,41 +1304,12 @@ class Config:
                 "straggler_threshold is a multiple of the median step time "
                 f"and must be > 1.0, got {self.straggler_threshold}"
             )
-        if self.remat == "blocks":
-            from mpi_pytorch_tpu.models.registry import (
-                REMAT_BLOCKS_MODELS,
-                supports_remat_blocks,
+        if self.stem_s2d and (self.width % 2 or self.height % 2):
+            raise ValueError(
+                "stem_s2d folds 2×2 spatial patches into channels and "
+                f"requires even image dims, got {self.width}x{self.height}"
             )
-
-            if not supports_remat_blocks(self.model_name):
-                raise ValueError(
-                    f"remat='blocks' is not implemented for {self.model_name!r} "
-                    f"(supported: {', '.join(REMAT_BLOCKS_MODELS)}); "
-                    "use remat='full' or 'none'"
-                )
-        if self.stem_s2d:
-            from mpi_pytorch_tpu.models.registry import S2D_MODELS
-
-            if self.model_name not in S2D_MODELS:
-                raise ValueError(
-                    f"stem_s2d is only implemented for the 7×7-stem family "
-                    f"({', '.join(S2D_MODELS)}); {self.model_name!r} has no "
-                    "such stem"
-                )
-            if self.width % 2 or self.height % 2:
-                raise ValueError(
-                    "stem_s2d folds 2×2 spatial patches into channels and "
-                    f"requires even image dims, got {self.width}x{self.height}"
-                )
         if self.fused_stem:
-            from mpi_pytorch_tpu.models.registry import FUSED_STEM_MODELS
-
-            if self.model_name not in FUSED_STEM_MODELS:
-                raise ValueError(
-                    f"fused_stem is only implemented for the 7×7-stem family "
-                    f"({', '.join(FUSED_STEM_MODELS)}); {self.model_name!r} "
-                    "has no such stem"
-                )
             # conv1 output dim: 7×7/s2/p3 → (N-1)//2 + 1; with stem_s2d
             # the equivalent 4×4/s1 conv gives N/2 (even N already required).
             def post_conv(n: int) -> int:
@@ -1388,15 +1333,16 @@ class Config:
                 f"batch_size {self.batch_size} not divisible by "
                 f"accum_steps {self.accum_steps}"
             )
-        if self.model_name == "inception_v3" and (self.width, self.height) not in (
-            (128, 128),  # the untouched default: image_size upgrades it to 299
-            (299, 299),
+        if spec.required_size and (self.width, self.height) not in (
+            (128, 128),  # the untouched default: image_size upgrades it
+            (spec.required_size,) * 2,
         ):
             raise ValueError(
-                f"inception_v3 requires 299x299 inputs (aux-logits pooling); "
+                f"{self.model_name} requires {spec.required_size}x"
+                f"{spec.required_size} inputs (aux-logits pooling); "
                 f"an explicit --width/--height/--image-size of "
                 f"{self.width}x{self.height} would be silently overridden — "
-                "drop the flag or pass 299"
+                f"drop the flag or pass {spec.required_size}"
             )
         if self.spmd_mode and self.mesh.model_parallel > 1:
             raise ValueError(
@@ -1431,14 +1377,7 @@ class Config:
         if self.pp_microbatches and self.pp_stages <= 1:
             raise ValueError("pp_microbatches only applies with pp_stages > 1")
         if self.pp_stages > 1:
-            from mpi_pytorch_tpu.models.registry import PP_MODELS
-
-            if self.model_name not in PP_MODELS:
-                raise ValueError(
-                    f"pp_stages > 1 pipelines a depth-homogeneous encoder trunk; "
-                    f"{self.model_name!r} is not pipeline-shaped "
-                    f"(supported: {', '.join(PP_MODELS)})"
-                )
+            check_build_flags(self.model_name, pp_stages=self.pp_stages)
             if self.spmd_mode:
                 raise ValueError(
                     "pp_stages > 1 requires the auto-partitioned step "
@@ -1449,12 +1388,6 @@ class Config:
                     "pp_stages > 1 cannot nest the SP attention strategies "
                     "inside pipeline stages (both shard the same devices); "
                     "choose one of --pp-stages / --sp-strategy"
-                )
-            if self.expert_parallel:
-                raise ValueError(
-                    "pp_stages > 1 with expert_parallel would nest all_to_all "
-                    "inside pipeline stages; choose one of --pp-stages / "
-                    "--expert-parallel"
                 )
             if self.accum_steps > 1:
                 raise ValueError(
@@ -1497,9 +1430,10 @@ class Config:
         and is latently broken in the reference (SURVEY §3 quirks). We keep
         128×128 for the six and use 299×299 for inception so it actually works.
         """
-        if self.model_name == "inception_v3":
-            return (299, 299)
-        return (self.height, self.width)
+        from mpi_pytorch_tpu.models.registry import model_spec
+
+        required = model_spec(self.model_name).required_size
+        return (required, required) if required else (self.height, self.width)
 
     def parsed_compiler_options(self) -> dict[str, Any] | None:
         """``compiler_options`` as the dict jax's ``Lowered.compile`` takes,
@@ -1690,24 +1624,23 @@ def parse_config(argv: Sequence[str] | None = None, **overrides: Any) -> Config:
 
     # Explicit-dimension check that validate_config cannot do (the dataclass
     # can't tell an explicit 128 from the untouched default): any explicitly
-    # requested size for inception_v3 other than its required 299 errors —
-    # including 128, which the image_size property would otherwise silently
-    # upgrade.
+    # requested size other than the one a model requires (inception_v3's
+    # 299) errors — including 128, which the image_size property would
+    # otherwise silently upgrade.
     dims_explicit = (
         alias is not None
         or ns.get("width") is not None
         or ns.get("height") is not None
         or any(k in os.environ for k in ("MPT_IMAGE_SIZE", "MPT_WIDTH", "MPT_HEIGHT"))
     )
-    if (
-        cfg.model_name == "inception_v3"
-        and dims_explicit
-        and (cfg.width, cfg.height) != (299, 299)
-    ):
+    from mpi_pytorch_tpu.models.registry import model_spec
+
+    required = model_spec(cfg.model_name).required_size
+    if required and dims_explicit and (cfg.width, cfg.height) != (required, required):
         raise ValueError(
-            f"inception_v3 requires 299x299 inputs (aux-logits pooling); the "
-            f"requested {cfg.width}x{cfg.height} would be silently "
-            "overridden — drop the size flags or pass 299"
+            f"{cfg.model_name} requires {required}x{required} inputs "
+            f"(aux-logits pooling); the requested {cfg.width}x{cfg.height} "
+            f"would be silently overridden — drop the size flags or pass {required}"
         )
 
     cfg.validate_config()

@@ -228,6 +228,43 @@ class DataLoader:
         n = len(self.manifest)
         return n // self.batch_size if self.drop_remainder else -(-n // self.batch_size)
 
+    @property
+    def cache_row(self) -> tuple[tuple[int, ...], np.dtype]:
+        """(shape, dtype) of one row of the device cache
+        (``trainer.build_device_cache``): a decoded image."""
+        return (*self.image_size, 3), self.image_dtype
+
+    def fill_cache_rows(self, manifest, lo: int, hi: int, out: np.ndarray) -> set[int]:
+        """Rows ``[lo, hi)`` of ``manifest`` into ``out[: hi - lo]``, in
+        place: one ordered decode pass with this loader's settings and its
+        metrics writer. Returns the offsets (from ``lo``) of quarantined
+        rows, which hold substitute pixels."""
+        if hi <= lo:
+            return set()
+        ordered = DataLoader(
+            manifest.select(np.arange(lo, hi)),
+            batch_size=self.batch_size,
+            image_size=self.image_size,
+            shuffle=False,
+            drop_remainder=False,
+            synthetic=self.synthetic,
+            num_workers=self.num_workers,
+            prefetch=self.prefetch,
+            image_dtype=str(np.dtype(self.image_dtype)),
+            native_decode=self.native_decode,
+            decode_prescale=self.decode_prescale,
+            packed_dir=self.packed_dir,
+            max_bad_samples=self.max_bad_samples,
+            quarantine_file=self.quarantine_file,
+        )
+        ordered.metrics = self.metrics
+        row = 0
+        for batch_images, _ in ordered.epoch(0):
+            out[row : row + batch_images.shape[0]] = batch_images
+            row += batch_images.shape[0]
+        assert row == hi - lo, (row, lo, hi)
+        return ordered._quarantined
+
     def _sample_name(self, i: int) -> str:
         if self.synthetic:
             return f"synthetic:{int(self.manifest.labels[i])}@{i}"

@@ -1,4 +1,4 @@
-"""Token datasets for the token models (``models/registry.TOKEN_MODELS``).
+"""Token datasets for the token models (``ModelSpec.sample == "tokens"``).
 
 A sample is one PACKED sequence of ``S + 1`` int32 token ids: documents laid
 end to end, no padding; the step reads inputs ``[:, :-1]`` and targets
@@ -55,12 +55,25 @@ class TokenManifest:
 @dataclasses.dataclass
 class TokenLoader:
     """What the trainer holds where an image run holds its ``DataLoader``:
-    this host's shard and the metrics writer. Token models train from the
-    device cache (``config.validate_config``), so nothing iterates it."""
+    this host's shard, the metrics writer and the device cache's row
+    contract. Token models train from the device cache
+    (``config.validate_config``), so nothing iterates it."""
 
     manifest: TokenManifest
     batch_size: int
     metrics: object = None
+
+    @property
+    def cache_row(self) -> tuple[tuple[int, ...], np.dtype]:
+        """(shape, dtype) of one row of the device cache: a packed sequence
+        ``int32 [S + 1]`` as it lies in the pack."""
+        return self.manifest.tokens.shape[1:], np.dtype(np.int32)
+
+    def fill_cache_rows(self, manifest, lo: int, hi: int, out: np.ndarray) -> set[int]:
+        """Rows ``[lo, hi)`` of ``manifest`` into ``out[: hi - lo]``, in
+        place; nothing is decoded, so nothing is ever quarantined."""
+        out[: max(hi - lo, 0)] = manifest.tokens[lo:hi]
+        return set()
 
 
 def synthetic_tokens(n: int, seq_len: int, vocab: int, seed: int) -> np.ndarray:

@@ -318,3 +318,8 @@ def lfm2_moe(num_classes: int, *, model_config: str = "", **kw: Any) -> Lfm2Moe:
     vocabulary is the configuration's."""
     del num_classes
     return Lfm2Moe(cfg=Lfm2Config.parse(model_config), **kw)
+
+
+def lfm2_vocab(model_config: str) -> int:
+    """The vocabulary ``lfm2_moe`` would be built with (``ModelSpec.vocab``)."""
+    return Lfm2Config.parse(model_config).vocab_size

@@ -28,67 +28,125 @@ from mpi_pytorch_tpu.models.common import head_filter
 from mpi_pytorch_tpu.models.densenet import densenet121
 from mpi_pytorch_tpu.models.efficientnet import efficientnet_b0
 from mpi_pytorch_tpu.models.inception import inception_v3
-from mpi_pytorch_tpu.models.lfm2 import lfm2_moe
+from mpi_pytorch_tpu.models.lfm2 import lfm2_moe, lfm2_vocab
 from mpi_pytorch_tpu.models.mobilenet import mobilenet_v2
 from mpi_pytorch_tpu.models.resnet import resnet18, resnet34
 from mpi_pytorch_tpu.models.squeezenet import squeezenet1_0
 from mpi_pytorch_tpu.models.vgg import vgg11_bn
 from mpi_pytorch_tpu.models.vit import vit_b16, vit_moe_s16, vit_s16
 
-# name → (factory, canonical input size). Input sizes mirror models.py
-# (:37,:45,:54,:63,:72,:81,:95); as in the reference they are advisory — the
-# config's resize wins (main.py:64) — except inception which truly needs 299.
-# The vit_* family is beyond reference parity (the reference has no
-# attention): its encoder can run the SP strategies inside training.
-_REGISTRY: dict[str, tuple[Callable[..., nn.Module], int]] = {
-    "resnet18": (resnet18, 224),
-    "resnet34": (resnet34, 128),
-    "alexnet": (alexnet, 224),
-    "vgg11_bn": (vgg11_bn, 224),
-    "squeezenet1_0": (squeezenet1_0, 224),
-    "densenet121": (densenet121, 224),
-    "inception_v3": (inception_v3, 299),
-    "mobilenet_v2": (mobilenet_v2, 224),
-    "efficientnet_b0": (efficientnet_b0, 224),
-    "vit_s16": (vit_s16, 224),
-    "vit_b16": (vit_b16, 224),
-    "vit_moe_s16": (vit_moe_s16, 224),
-    # A token model: its "input size" is a sequence length (TOKEN_MODELS).
-    "lfm2_moe": (lfm2_moe, 128),
+# What the optional build flags are when nobody asked for them: a flag is
+# "set" when its value differs. ``check_build_flags`` reads this, and the
+# drivers never repeat it.
+_FLAG_OFF = {
+    "attn_impl": "full",
+    "sp_strategy": "none",
+    "ep_mesh": None,
+    "qkv_fused": False,
+    "remat_blocks": False,
+    "stem_s2d": False,
+    "fused_stem": False,
+    "model_config": "",
+    "pp_stages": 1,
 }
 
-# Architectures with no BatchNorm (their factories take no bn_axis_name).
-BN_FREE_MODELS = ("alexnet", "squeezenet1_0", "vit_s16", "vit_b16", "vit_moe_s16", "lfm2_moe")
 
-# What a model consumes is the registry's to say, not a flag's: these take
-# ``int32 [B, S]`` token ids and return ``[B, S, vocab]`` next-token logits; a
-# sample is a packed sequence of S + 1 ids (inputs ``[:-1]``, targets
-# ``[1:]``; data/tokens.py, train/step.py::_gather_batch). Every other model
-# takes ``[B, H, W, 3]`` images and returns ``[B, classes]``.
-TOKEN_MODELS = ("lfm2_moe",)
+@dataclasses.dataclass(frozen=True)
+class ModelSpec:
+    """What one architecture IS: everything config validation, the trainer
+    and the serving zoo may ask about a model by name. Adding a model is its
+    own file plus one entry of ``_REGISTRY``."""
 
-# Architectures whose factories read ``--model-config`` (a JSON object in
-# their source's own key names) instead of one flag per key.
-CONFIGURED_MODELS = ("lfm2_moe",)
+    factory: Callable[..., nn.Module]
+    # Canonical input size, mirroring models.py (:37,:45,:54,:63,:72,:81,:95).
+    # Advisory as in the reference — the config's resize wins (main.py:64) —
+    # unless ``required_size`` says otherwise. A token model's "size" is a
+    # sequence length.
+    input_size: int
+    # The optional build flags this model accepts, of ``_FLAG_OFF``'s names
+    # (``attn_impl`` apart: see ``attn_impls``) and ``dp_mesh``:
+    #   sp_strategy   (with sp_mesh) the SP attention strategies in training
+    #   qkv_fused     one [D, 3D] projection matmul (models/vit.py)
+    #   ep_mesh       MoE MLPs, shardable over experts; the train loss then
+    #                 includes the sown load-balance term
+    #   remat_blocks  per-block nn.remat
+    #   stem_s2d      the 7×7/s2 stem conv re-expressed exactly as a 4×4/s1
+    #                 12-channel conv (models/resnet.py s2d_stem_*)
+    #   fused_stem    the bn1+relu+maxpool Pallas pair (ops/fused_stem.py): the
+    #                 7×7/s2/p3 + BN + relu + 3×3/s2/p1-pool stem family
+    #   model_config  the factory reads ``--model-config``, one JSON object in
+    #                 its source's own key names
+    #   pp_stages     the trunk is a stack of depth-homogeneous blocks that
+    #                 parallel/pp_vit.py can split into stages (not the MoE
+    #                 variant: its sown aux loss cannot cross the pipeline's
+    #                 shard_map, and alternating blocks break the stacking)
+    #   dp_mesh       the module shard_maps a Mosaic call over the mesh's data
+    #                 axis; never refused, handed over only where a kernel
+    #                 uses it (``initialize_model``)
+    flags: frozenset = frozenset()
+    # The ``attn_impl`` values it takes; () = no attention, no such flag.
+    attn_impls: tuple[str, ...] = ()
+    # What a sample is. "images": ``[B, H, W, 3]`` in, ``[B, classes]`` out.
+    # "tokens": ``int32 [B, S]`` ids in, ``[B, S, vocab]`` next-token logits
+    # out; a sample is a packed sequence of S + 1 ids (inputs ``[:-1]``,
+    # targets ``[1:]``; data/tokens.py, train/step.py::_gather_batch).
+    sample: str = "images"
+    # vocab(model_config) -> int for a token model, without building it (the
+    # trainer checks its sequences against it). Lives beside the model.
+    vocab: Callable[[str], int] | None = None
+    batchnorm: bool = True  # the factory takes bn_axis_name
+    required_size: int | None = None  # inception truly needs 299
+    aux_logits: bool = False  # a second head in training (inception)
+    # The fused stem is a MEASURED chip win here (docs/RESULTS.md §4d), so
+    # ``fused_stem_default`` turns it on; densenet121 has the capability but
+    # ships behind --fused-stem until its own A/B row lands.
+    fused_stem_measured: bool = False
 
-# Architectures with an ``attn_impl`` choice (the vit family's three; a
-# token model's causal ``full`` | ``flash``).
-ATTN_IMPL_MODELS = ("vit_s16", "vit_b16", "vit_moe_s16", "lfm2_moe")
+    def accepts(self, flag: str, value: Any) -> bool:
+        if flag == "attn_impl":
+            return value in self.attn_impls
+        return flag in self.flags
 
-# Architectures whose factories accept sp_strategy/sp_mesh (sequence models
-# that can run the SP attention strategies inside training).
-SP_MODELS = ("vit_s16", "vit_b16", "vit_moe_s16")
 
-# Architectures with MoE MLPs (their factories accept ep_mesh for expert
-# parallelism; their train loss includes the sown load-balance aux term).
-MOE_MODELS = ("vit_moe_s16",)
+_RESNET = dict(
+    flags=frozenset({"remat_blocks", "stem_s2d", "fused_stem", "dp_mesh"}),
+    fused_stem_measured=True,
+)
 
-# Architectures whose trunk is a stack of depth-homogeneous blocks that
-# pipeline parallelism can split into stages (parallel/pp_vit.py). The MoE
-# variant is excluded: its sown aux-loss collection cannot cross the
-# pipeline's shard_map boundary, and its alternating block structure breaks
-# the stacked-stage layout.
-PP_MODELS = ("vit_s16", "vit_b16")
+
+def _vit(*more: str) -> dict:
+    """What every vit_* entry shares, plus the flags only some take."""
+    return dict(
+        flags=frozenset({"sp_strategy", "qkv_fused", "dp_mesh", *more}),
+        attn_impls=("full", "flash", "fused-small"),
+        batchnorm=False,
+    )
+
+
+# The vit_* family is beyond reference parity (the reference has no
+# attention); lfm2_moe is the token model.
+_REGISTRY: dict[str, ModelSpec] = {
+    "resnet18": ModelSpec(resnet18, 224, **_RESNET),
+    "resnet34": ModelSpec(resnet34, 128, **_RESNET),
+    "alexnet": ModelSpec(alexnet, 224, batchnorm=False),
+    "vgg11_bn": ModelSpec(vgg11_bn, 224),
+    "squeezenet1_0": ModelSpec(squeezenet1_0, 224, batchnorm=False),
+    "densenet121": ModelSpec(
+        densenet121, 224, flags=frozenset({"remat_blocks", "fused_stem", "dp_mesh"})
+    ),
+    "inception_v3": ModelSpec(inception_v3, 299, required_size=299, aux_logits=True),
+    "mobilenet_v2": ModelSpec(mobilenet_v2, 224),
+    "efficientnet_b0": ModelSpec(efficientnet_b0, 224),
+    "vit_s16": ModelSpec(vit_s16, 224, **_vit("remat_blocks", "pp_stages")),
+    "vit_b16": ModelSpec(vit_b16, 224, **_vit("remat_blocks", "pp_stages")),
+    "vit_moe_s16": ModelSpec(vit_moe_s16, 224, **_vit("ep_mesh")),
+    "lfm2_moe": ModelSpec(
+        lfm2_moe, 128, flags=frozenset({"remat_blocks", "model_config"}),
+        # causal, grouped heads: the single-pass kernel has no such form
+        attn_impls=("full", "flash"), sample="tokens", vocab=lfm2_vocab,
+        batchnorm=False,
+    ),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,49 +160,34 @@ class ModelBundle:
     trainable_mask: Any | None  # pytree of bools over params; None = all trainable
 
 
-def token_vocab(model_name: str, model_config: str) -> int:
-    """The vocabulary a token model is built with, without building it (the
-    trainer checks its sequences against it)."""
-    from mpi_pytorch_tpu.models.lfm2 import Lfm2Config
-
-    assert model_name in TOKEN_MODELS, model_name
-    return Lfm2Config.parse(model_config).vocab_size
-
-
 def available_models() -> tuple[str, ...]:
     return tuple(_REGISTRY)
 
 
-# Architectures whose factories accept remat_blocks (per-block nn.remat).
-# THE owner of this capability — config validation and error messages defer here.
-REMAT_BLOCKS_MODELS = ("resnet18", "resnet34", "densenet121", "vit_s16", "vit_b16", "lfm2_moe")
+def model_spec(model_name: str) -> ModelSpec:
+    if model_name not in _REGISTRY:
+        raise ValueError(
+            f"unsupported model {model_name!r}; expected one of {tuple(_REGISTRY)}"
+            " (parity with reference models.py:97-99, but raising instead of exit())"
+        )
+    return _REGISTRY[model_name]
 
 
-def supports_remat_blocks(model_name: str) -> bool:
-    return model_name in REMAT_BLOCKS_MODELS
-
-
-# Architectures whose factories accept stem_s2d (space-to-depth stem — the
-# exact re-expression of the 7×7/s2 3-channel stem conv as a 4×4/s1
-# 12-channel conv; models/resnet.py s2d_stem_input/s2d_stem_kernel).
-S2D_MODELS = ("resnet18", "resnet34")
-
-# Architectures whose factories accept fused_stem (the bn1+relu+maxpool
-# Pallas kernel pair, ops/fused_stem.py — the identical 7×7/s2/p3 + BN +
-# relu + 3×3/s2/p1-pool stem family; the fused module mirrors flax
-# BatchNorm's variable tree so checkpoints interchange). densenet121's
-# torchvision stem (features.conv0..pool0) is geometrically the same stem,
-# so the kernel applies — see MEASURED_FUSED_STEM_MODELS for why its bench
-# default differs.
-FUSED_STEM_MODELS = ("resnet18", "resnet34", "densenet121")
-
-# The subset whose fused stem is a MEASURED chip win (docs/RESULTS.md §4d:
-# resnet18 24.7k → 26.1k img/s). densenet121 is capability-enabled but
-# default-off: its stem tail is only ≈3% of its roofline bound and the
-# step already runs at 1.11× bound (docs/RESULTS.md §4), so it ships
-# behind --fused-stem until its own A/B row lands — the fused-head
-# discipline (measure first, default only wins).
-MEASURED_FUSED_STEM_MODELS = ("resnet18", "resnet34")
+def check_build_flags(model_name: str, **flags: Any) -> None:
+    """THE owner of every "flag X does not apply to model Y" refusal:
+    ``Config.validate_config`` and ``initialize_model`` both call it, with
+    whichever of ``_FLAG_OFF``'s flags they hold. A flag left at its off
+    value is never refused."""
+    spec = model_spec(model_name)
+    for flag, value in flags.items():
+        if value == _FLAG_OFF[flag] or spec.accepts(flag, value):
+            continue
+        takers = [n for n, s in _REGISTRY.items() if s.accepts(flag, value)]
+        shown = f"{flag}={value!r}" if isinstance(value, (str, bool, int)) else flag
+        raise ValueError(
+            f"{shown} does not apply to model {model_name!r}; the models that "
+            f"accept it: {', '.join(takers) or 'none'}"
+        )
 
 
 def fused_stem_default(model_name: str) -> bool:
@@ -153,12 +196,10 @@ def fused_stem_default(model_name: str) -> bool:
     case of '0'/'false'/'no'/'off' (``utils/env.py`` is the one definition;
     advisor r5: 'False'/'no' used to silently mean ON). The trainer/eval
     CLIs stay explicit via ``--fused-stem``."""
-    import jax
-
     from mpi_pytorch_tpu.utils.env import env_flag
 
     return (
-        model_name in MEASURED_FUSED_STEM_MODELS
+        model_spec(model_name).fused_stem_measured
         and env_flag("MPT_FUSED_STEM", default=True)
         and jax.default_backend() == "tpu"
     )
@@ -186,89 +227,38 @@ def initialize_model(
     model_config: str = "",
 ) -> tuple[nn.Module, int]:
     """Reference-parity signature (``models.py:16``): returns (model, input_size)."""
-    if model_name not in _REGISTRY:
+    spec = model_spec(model_name)
+    given = dict(
+        attn_impl=attn_impl, sp_strategy=sp_strategy, ep_mesh=ep_mesh,
+        qkv_fused=qkv_fused, remat_blocks=remat_blocks, stem_s2d=stem_s2d,
+        fused_stem=fused_stem, model_config=model_config,
+    )
+    check_build_flags(model_name, **given)
+    if sp_strategy != "none" and sp_mesh is None:
         raise ValueError(
-            f"unsupported model {model_name!r}; expected one of {tuple(_REGISTRY)}"
+            f"sp_strategy={sp_strategy!r} requires sp_mesh (the mesh whose "
+            "first axis shards the sequence)"
         )
-    factory, input_size = _REGISTRY[model_name]
+    if fused_stem and bn_axis_name is not None:
+        raise ValueError("fused_stem does not support sync-BN (bn_axis_name)")
     kw: dict[str, Any] = dict(dtype=dtype, param_dtype=param_dtype)
-    if model_name not in BN_FREE_MODELS:
+    kw.update({f: v for f, v in given.items() if v != _FLAG_OFF[f]})
+    if spec.batchnorm:
         kw["bn_axis_name"] = bn_axis_name
-    if attn_impl != "full":
-        if model_name not in ATTN_IMPL_MODELS:
-            raise ValueError(
-                f"attn_impl={attn_impl!r} applies only to the attention "
-                f"family ({', '.join(ATTN_IMPL_MODELS)}); {model_name!r} has no "
-                "attention"
-            )
-        kw["attn_impl"] = attn_impl
-    if model_config:  # config.validate_config has refused it for any other model
-        kw["model_config"] = model_config
-    if model_name in SP_MODELS and attn_impl != "flash" and dp_mesh is not None:
-        # Multi-chip: the attention module shard_maps its Mosaic call over
-        # this mesh's data axis (ops/fused_attention_small.py, Multi-chip) —
-        # the same contract as the fused stem below. 'full' needs it as
-        # 'fused-small' does: it takes the same kernel wherever the shape
-        # allows.
-        kw["dp_mesh"] = dp_mesh
-    if qkv_fused:
-        if model_name not in SP_MODELS:
-            raise ValueError(
-                f"qkv_fused applies only to the attention family "
-                f"({', '.join(SP_MODELS)}); {model_name!r} has no attention"
-            )
-        kw["qkv_fused"] = True
     if sp_strategy != "none":
-        if model_name not in SP_MODELS:
-            raise ValueError(
-                f"sp_strategy={sp_strategy!r} applies only to sequence models "
-                f"({', '.join(SP_MODELS)}); {model_name!r} has no sequence axis"
-            )
-        if sp_mesh is None:
-            raise ValueError(
-                f"sp_strategy={sp_strategy!r} requires sp_mesh (the mesh whose "
-                "first axis shards the sequence)"
-            )
-        kw["sp_strategy"] = sp_strategy
         kw["sp_mesh"] = sp_mesh
-    if ep_mesh is not None:
-        if model_name not in MOE_MODELS:
-            raise ValueError(
-                f"ep_mesh applies only to MoE models ({', '.join(MOE_MODELS)}); "
-                f"{model_name!r} has no experts to shard"
-            )
-        kw["ep_mesh"] = ep_mesh
-    if remat_blocks:
-        if not supports_remat_blocks(model_name):
-            raise ValueError(
-                f"remat='blocks' is not implemented for {model_name!r} "
-                f"(supported: {', '.join(REMAT_BLOCKS_MODELS)}); "
-                "use remat='full' or 'none'"
-            )
-        kw["remat_blocks"] = True
-    if stem_s2d:
-        if model_name not in S2D_MODELS:
-            raise ValueError(
-                f"stem_s2d is only implemented for the 7×7-stem family "
-                f"({', '.join(S2D_MODELS)}); {model_name!r} has no such stem"
-            )
-        kw["stem_s2d"] = True
-    if fused_stem:
-        if model_name not in FUSED_STEM_MODELS:
-            raise ValueError(
-                f"fused_stem is only implemented for the 7×7-stem family "
-                f"({', '.join(FUSED_STEM_MODELS)}); {model_name!r} has no such stem"
-            )
-        if bn_axis_name is not None:
-            raise ValueError("fused_stem does not support sync-BN (bn_axis_name)")
-        kw["fused_stem"] = True
-        if dp_mesh is not None:
-            # Multi-chip: the stem module shard_maps its Mosaic call over
-            # this mesh's data axis (ops/fused_stem.py, Multi-chip). Only
-            # meaningful with fused_stem — silently ignored otherwise.
-            kw["dp_mesh"] = dp_mesh
-    model = factory(num_classes, **kw)
-    return model, input_size
+    if (
+        dp_mesh is not None
+        and "dp_mesh" in spec.flags
+        and (fused_stem or (spec.attn_impls and attn_impl != "flash"))
+    ):
+        # Multi-chip: the module shard_maps its Mosaic call over this mesh's
+        # data axis (ops/fused_stem.py, ops/fused_attention_small.py,
+        # Multi-chip) — so it goes only where such a call exists: the fused
+        # stem, or dense attention ('full' takes the same kernel as
+        # 'fused-small' wherever the shape allows; 'flash' takes no mesh).
+        kw["dp_mesh"] = dp_mesh
+    return spec.factory(num_classes, **kw), spec.input_size
 
 
 def init_variables(
@@ -277,8 +267,8 @@ def init_variables(
 ) -> dict:
     """Initialize params + batch_stats. Uses train=True so architectures with
     train-only submodules (inception aux head) create their full param set.
-    ``tokens``: the model is one of ``TOKEN_MODELS`` and is traced on
-    ``int32 [batch, input_size]`` ids.
+    ``tokens``: the model's ``ModelSpec.sample`` is "tokens" and it is traced
+    on ``int32 [batch, input_size]`` ids.
 
     Jitted so XLA dead-code-eliminates the traced forward pass — only the
     parameter initializers actually run (orders of magnitude faster than
@@ -329,9 +319,10 @@ def create_model_bundle(
         fused_stem=fused_stem, dp_mesh=dp_mesh, qkv_fused=qkv_fused,
         model_config=model_config,
     )
-    size = image_size or (299 if model_name == "inception_v3" else 128)
+    spec = model_spec(model_name)
+    size = image_size or spec.required_size or 128
     rng = rng if rng is not None else jax.random.PRNGKey(0)
-    variables = init_variables(model, size, rng, tokens=model_name in TOKEN_MODELS)
+    variables = init_variables(model, size, rng, tokens=spec.sample == "tokens")
 
     if use_pretrained:
         from mpi_pytorch_tpu.models.pretrained import load_pretrained
@@ -350,7 +341,7 @@ def create_model_bundle(
         model=model,
         input_size=size,
         name=model_name,
-        has_aux_logits=(model_name == "inception_v3"),
+        has_aux_logits=spec.aux_logits,
         trainable_mask=mask,
     )
     return bundle, variables

@@ -22,7 +22,7 @@ other backend, XLA's ``full_attention`` runs unchanged. ``fused_attention_small`
 (``attn_impl="fused-small"``) is this kernel or a ``ValueError`` naming the
 shape.
 
-**Rows layout (the path).** The projections' matmuls leave ``[B, S, H·Dh]``;
+**Layout.** The projections' matmuls leave ``[B, S, H·Dh]``;
 the kernels block THAT array — ``(bb, S, w)`` blocks on a ``(B/bb, H·Dh/w)``
 grid, a block dim equal to the array's whole S needs no padding — and write
 ``o``/``dq``/``dk``/``dv`` in the same layout: no transpose or pad of an
@@ -51,15 +51,6 @@ ever shifted; a model narrower than one tile (H·Dh ≤ 128) is one group.
 - Twelve layers call the same two kernels at the same shape: each is one
   jitted function per shape, lowered once and called from every site.
 
-**Grouped layout (the lever).** The first design stacks ``G`` (batch, head)
-pairs into one ``[G·S_pad, D]`` tile of a transposed ``[B·H, S_pad, D]``
-copy and masks the cross-head blocks of one ``[G·S_pad, G·S_pad]`` score
-matmul (at S=64, G=2 fills a 128×128 MXU tile). It pays 4 XLA transposes
-and pads forward and 7 backward per layer, so it is kept only as the A/B
-lever ``bh_block`` / ``MPT_ATTN_BH_BLOCK`` for S_pad ≤ 128
-(``tools/bench_attention.py --fused-small``; PERF.md section 6 has the
-chip rows) and is never consulted above that.
-
 Non-TPU backends take ``full_attention`` (identical math — the reference
 these kernels are pinned against in tests/test_fused_attention_small.py via
 interpret mode); ``MPT_ATTN_INTERPRET=1`` drives the real kernels through the
@@ -79,7 +70,6 @@ exactly. Inside an ALREADY shard_map'd context over the same axis (the
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -94,9 +84,6 @@ _NEG = -1e30  # finite mask value — exp(_NEG - m) underflows to exactly 0
 # scores, probabilities and their cotangents of a head sit in VMEM together.
 MAX_SEQ_PAD = 512
 MAX_HEAD_DIM = 128
-# The grouped layout's own bound: stacking heads only pays while two of them
-# fill one 128-wide tile.
-MAX_GROUPED_SEQ_PAD = 128
 _LANES = 128
 
 
@@ -295,192 +282,6 @@ _attn_rows.defvjp(_attn_rows_fwd, _attn_rows_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Grouped layout: [B·H/G, G·S_pad, D] tiles (the bh_block lever, S_pad ≤ 128).
-# ---------------------------------------------------------------------------
-
-
-def _bh_block(bh: int, s_pad: int, override: int | None = None) -> int:
-    """(batch·head) pairs per grid step. Default fills the 128-lane /
-    128×128-MXU tile: G = 128 // S_pad (≥1), reduced until it divides the
-    (per-shard) B·H count. ``override`` (the ``bh_block`` kwarg) beats the
-    ``MPT_ATTN_BH_BLOCK`` env gate beats the default
-    (tools/bench_attention.py --fused-small sweeps them)."""
-    raw = os.environ.get("MPT_ATTN_BH_BLOCK")
-    if override is not None:
-        g = override
-    elif raw:
-        g = int(raw)
-    else:
-        g = max(1, 128 // s_pad)
-    # VMEM envelope: the kernel holds (G·S_pad)² f32 score/probability
-    # tiles; cap G·S_pad at 512 (≤1 MB per tile) so an aggressive override
-    # degrades to a buildable grouping instead of a Mosaic compile failure
-    # mid-run.
-    g = max(1, min(g, bh, max(1, MAX_SEQ_PAD // s_pad)))
-    while bh % g:
-        g -= 1
-    return g
-
-
-def _mask_bias(g: int, s_pad: int, seq_len: int, causal: bool) -> jnp.ndarray:
-    """[G·S_pad, G·S_pad] additive f32 bias: 0 on (same-head, valid-key
-    [, causal]) entries, −1e30 elsewhere. Built in XLA from static ints —
-    no integer div/mod ever reaches the Mosaic kernel body."""
-    r = g * s_pad
-    rows = lax.broadcasted_iota(jnp.int32, (r, r), 0)
-    cols = lax.broadcasted_iota(jnp.int32, (r, r), 1)
-    valid = (rows // s_pad == cols // s_pad) & (cols % s_pad < seq_len)
-    if causal:
-        valid &= cols % s_pad <= rows % s_pad
-    return jnp.where(valid, 0.0, _NEG).astype(jnp.float32)
-
-
-def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, *, scale):
-    q, k, v = q_ref[0], k_ref[0], v_ref[0]  # [R, D]
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale + bias_ref[...]  # [R, R]
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)  # masked entries: exp(_NEG - m) == 0
-    l = jnp.sum(p, axis=-1, keepdims=True)  # ≥ 1 valid key per row ⇒ l > 0
-    o = lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    o_ref[0] = (o / l).astype(o_ref.dtype)
-
-
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, bias_ref,
-                dq_ref, dk_ref, dv_ref, *, scale):
-    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]  # [R, D]
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale + bias_ref[...]
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)  # normalized probs [R, R]
-    dp = lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # do·vᵀ [R, R]
-    delta = jnp.sum(p * dp, axis=-1, keepdims=True)  # = Σ_d do·o, [R, 1]
-    ds = (p * (dp - delta)).astype(q.dtype)
-    dq_ref[0] = (lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale).astype(dq_ref.dtype)
-    dk_ref[0] = (lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale).astype(dk_ref.dtype)
-    dv_ref[0] = lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(dv_ref.dtype)
-
-
-def _tile_specs(n: int, r: int, d: int):
-    """(in_specs for [N, R, D] operands + the shared [R, R] bias, grid)."""
-    tile = pl.BlockSpec((1, r, d), lambda i: (i, 0, 0))
-    bias = pl.BlockSpec((r, r), lambda i: (0, 0))
-    return tile, bias, (n,)
-
-
-def _fwd_impl(qg, kg, vg, *, seq_len, s_pad, g, causal, interpret):
-    n, r, d = qg.shape
-    bias = _mask_bias(g, s_pad, seq_len, causal)
-    tile, bspec, grid = _tile_specs(n, r, d)
-    return kernel_call(
-        "attn_small_fwd",
-        functools.partial(_fwd_kernel, scale=d**-0.5),
-        grid=grid,
-        in_specs=[tile, tile, tile, bspec],
-        out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct((n, r, d), qg.dtype),
-        interpret=interpret,
-    )(qg, kg, vg, bias)
-
-
-def _bwd_impl(qg, kg, vg, dog, *, seq_len, s_pad, g, causal, interpret):
-    n, r, d = qg.shape
-    bias = _mask_bias(g, s_pad, seq_len, causal)
-    tile, bspec, grid = _tile_specs(n, r, d)
-    return kernel_call(
-        "attn_small_bwd",
-        functools.partial(_bwd_kernel, scale=d**-0.5),
-        grid=grid,
-        in_specs=[tile, tile, tile, tile, bspec],
-        out_specs=[tile, tile, tile],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, r, d), qg.dtype),
-            jax.ShapeDtypeStruct((n, r, d), kg.dtype),
-            jax.ShapeDtypeStruct((n, r, d), vg.dtype),
-        ],
-        interpret=interpret,
-    )(qg, kg, vg, dog, bias)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _attn_grouped(qg, kg, vg, seq_len, s_pad, g, causal, interpret):
-    """[N, G·S_pad, D] grouped attention, N = B·H // G."""
-    return _fwd_impl(
-        qg, kg, vg, seq_len=seq_len, s_pad=s_pad, g=g, causal=causal,
-        interpret=interpret,
-    )
-
-
-def _attn_grouped_fwd(qg, kg, vg, seq_len, s_pad, g, causal, interpret):
-    out = _fwd_impl(
-        qg, kg, vg, seq_len=seq_len, s_pad=s_pad, g=g, causal=causal,
-        interpret=interpret,
-    )
-    return out, (qg, kg, vg)  # probabilities are recomputed, not saved
-
-
-def _attn_grouped_bwd(seq_len, s_pad, g, causal, interpret, res, dog):
-    qg, kg, vg = res
-    return _bwd_impl(
-        qg, kg, vg, dog, seq_len=seq_len, s_pad=s_pad, g=g, causal=causal,
-        interpret=interpret,
-    )
-
-
-_attn_grouped.defvjp(_attn_grouped_fwd, _attn_grouped_bwd)
-
-
-def _grouped_call(q, k, v, *, d, causal, bh_block, interpret):
-    """The grouped layout over [B, S, H·D] operands: heads split off and
-    transposed, padded to the sublane tile (a 56-row bf16 block is the class
-    of chip-only block-spec bug the flash kernel's lse output hit,
-    docs/RESULTS.md §4c) and stacked ``g`` heads to a tile."""
-    b, s, hd = q.shape
-    h = hd // d
-    s_pad = _seq_pad(s, q.dtype)
-    g = _bh_block(b * h, s_pad, bh_block)
-
-    def to_grouped(x):
-        x3 = x.reshape(b, s, h, d).transpose(0, 2, 1, 3).reshape(b * h, s, d)
-        if s_pad != s:
-            x3 = jnp.pad(x3, ((0, 0), (0, s_pad - s), (0, 0)))
-        return x3.reshape(b * h // g, g * s_pad, d)
-
-    outg = _attn_grouped(
-        to_grouped(q), to_grouped(k), to_grouped(v), s, s_pad, g, causal,
-        interpret,
-    )
-    out3 = outg.reshape(b * h, s_pad, d)[:, :s]
-    return out3.reshape(b, h, s, d).transpose(0, 2, 1, 3).reshape(b, s, hd)
-
-
-def _attn_call(q, k, v, *, d, causal, bh_block, interpret):
-    """One (per-shard) kernel invocation over [B, S, H·D] operands, heads of
-    ``d`` lanes."""
-    lever = bh_block is not None or bool(os.environ.get("MPT_ATTN_BH_BLOCK"))
-    if lever and _seq_pad(q.shape[1], q.dtype) <= MAX_GROUPED_SEQ_PAD:
-        return _grouped_call(
-            q, k, v, d=d, causal=causal, bh_block=bh_block, interpret=interpret
-        )
-    return _attn_rows(q, k, v, d, causal, interpret)
-
-
-# ---------------------------------------------------------------------------
 # Public entry points.
 # ---------------------------------------------------------------------------
 
@@ -496,14 +297,15 @@ def _data_axis_size(dp_mesh) -> int:
     return 1 if axis_is_manual(axis) else dp_mesh.shape[axis]
 
 
-def _kernel(q, k, v, *, h, d, causal, bh_block, interpret, dp_mesh, n_data):
+def _kernel(q, k, v, *, h, d, causal, interpret, dp_mesh, n_data):
     """The kernel over ``h`` heads of ``d`` lanes — operands [B, S, H, D] or
     [B, S, H·D], read as rows either way (free where they are rows already),
     the result in the operands' form — split over the data axis where there
     is one."""
-    call = functools.partial(
-        _attn_call, d=d, causal=causal, bh_block=bh_block, interpret=interpret
-    )
+
+    def call(q, k, v):
+        return _attn_rows(q, k, v, d, causal, interpret)
+
     if n_data > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -584,8 +386,8 @@ def dense_attention(
     why, interpret, n_data = _dispatch(b, s, h, d, q.dtype, dp_mesh)
     if not why:
         return _kernel(
-            q, k, v, h=h, d=d, causal=causal, bh_block=None,
-            interpret=interpret, dp_mesh=dp_mesh, n_data=n_data,
+            q, k, v, h=h, d=d, causal=causal, interpret=interpret,
+            dp_mesh=dp_mesh, n_data=n_data,
         )
     heads = (b, s, h, d)
     return full_attention(
@@ -594,16 +396,12 @@ def dense_attention(
 
 
 def fused_attention_small(
-    q, k, v, *, causal: bool = False, bh_block: int | None = None,
-    interpret: bool | None = None, dp_mesh=None,
+    q, k, v, *, causal: bool = False, interpret: bool | None = None,
+    dp_mesh=None,
 ) -> jnp.ndarray:
     """The single-pass kernel over [B, S, H, D] inputs, asked for by name
     (``attn_impl="fused-small"``, the A/B tools): on a TPU it is this kernel
     or a ``ValueError`` naming the shape; elsewhere ``full_attention``.
-
-    ``bh_block``: the grouped layout's (batch·head) pairs per grid step
-    (module docstring, "Grouped layout"; None = the rows layout unless
-    ``MPT_ATTN_BH_BLOCK`` is set). Never consulted where S_pad > 128.
 
     ``interpret``: None = Pallas on TPU, ``full_attention`` fallback
     elsewhere (or the Pallas interpreter when ``MPT_ATTN_INTERPRET`` is
@@ -639,6 +437,6 @@ def fused_attention_small(
         if interpret is None:
             return full_attention(q, k, v, causal=causal)
     return _kernel(
-        q, k, v, h=h, d=d, causal=causal, bh_block=bh_block,
-        interpret=interpret, dp_mesh=dp_mesh, n_data=n_data,
+        q, k, v, h=h, d=d, causal=causal, interpret=interpret,
+        dp_mesh=dp_mesh, n_data=n_data,
     )

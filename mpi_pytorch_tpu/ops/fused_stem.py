@@ -96,26 +96,18 @@ same model) still gets the partitioned call instead of a global-batch
 replicated one.
 
 Byte-bound levers (docs/RESULTS.md §4d; the fwd runs 4.25 ms vs a 2.0 ms
-byte bound, the bwd ~6.1 vs 3.6): four candidates are implemented behind
+byte bound, the bwd ~6.1 vs 3.6): three candidates are implemented behind
 env gates, each microbenched by ``tools/bench_stem.py --levers`` and
 recorded as a ship-or-rejection row in §4d —
 
-- ``MPT_STEM_BF16_POOL=1``  — pooling compares/phases in bf16 (halves the
-  in-VMEM f32 working set; the affine stays f32). REFUSED by Mosaic on
-  v5e — "Target does not support this comparison": the chip has no bf16
-  vector compare — so on this chip the lever is a compile error, never a
-  silent f32 fallback (PR 21);
 - ``MPT_STEM_LANES=256``    — 256-image batch block (two full vregs per op);
 - ``MPT_STEM_IDX_INT8=1``   — int8 window-argmax storage (k ∈ [0, 8] needs
   4 bits; halves the idx tensor's HBM traffic vs bf16);
 - ``MPT_STEM_C_BLOCK=16``   — 16-channel sublane block (half the grid
   steps at the same per-step tile bytes).
 
-All four preserve the reference semantics, pinned per-lever (values and
-all three gradients) in tests/test_fused_stem.py. Three are exact
-re-tilings; bf16 pooling is pinned tightly against pooling over
-bf16-ROUNDED activations (rounding is monotone, so window winners and
-first-match tie semantics transfer exactly).
+All three are exact re-tilings that preserve the reference semantics, pinned
+per-lever (values and all three gradients) in tests/test_fused_stem.py.
 """
 
 from __future__ import annotations
@@ -148,7 +140,6 @@ def _levers() -> dict:
     return {
         "c_block": int(os.environ.get("MPT_STEM_C_BLOCK", str(_C_BLOCK))),
         "lanes": int(os.environ.get("MPT_STEM_LANES", "128")),
-        "bf16_pool": env_flag("MPT_STEM_BF16_POOL"),
         "idx_int8": env_flag("MPT_STEM_IDX_INT8"),
     }
 
@@ -228,8 +219,7 @@ def _interleave(e, o, axis):
 def _pool_argmax_t(z):
     """3×3/s2/p1 max-pool + first-match argmax of ``z`` [H, W, C, B]
     (T-space). Returns (pooled [H/2, W/2, C, B], k [same], k = dh·3+dw).
-    Dtype-generic: runs in ``z.dtype`` (f32, or bf16 under the
-    MPT_STEM_BF16_POOL lever — phase codes 0..8 are exact in bf16)."""
+    Runs in ``z.dtype`` (float32 from ``_fwd_kernel``)."""
     neg = jnp.asarray(_NEG, z.dtype)
     # --- column pass at every row: fold over dw ∈ {0,1,2} -------------
     cm = _shift(z, 1, -1, neg)  # z[w-1]  (dw=0 candidate)
@@ -262,26 +252,19 @@ def _pool_argmax_t(z):
     return bv, bdh * 3.0 + bdw
 
 
-def _fwd_kernel(yt_ref, a_ref, b_ref, out_ref, idx_ref, *, bf16_pool=False):
+def _fwd_kernel(yt_ref, a_ref, b_ref, out_ref, idx_ref):
     yt = yt_ref[...].astype(jnp.float32)  # [H, W, C_blk, B_blk]
     a = a_ref[...].reshape(1, 1, a_ref.shape[0], 1)
     b = b_ref[...].reshape(1, 1, b_ref.shape[0], 1)
     z = jax.nn.relu(yt * a + b)
-    if bf16_pool:
-        # Lever: the affine is exact in f32; the pool fold's working set
-        # (3 candidate tensors + phases) drops to half the VMEM bytes. The
-        # pooled VALUE is bf16-rounded — the same rounding the bf16 output
-        # store applies anyway — and near-ties within bf16 eps may pick a
-        # different (equal-value) window than the f32 fold.
-        z = z.astype(jnp.bfloat16)
     best, bestk = _pool_argmax_t(z)
     out_ref[...] = best.astype(out_ref.dtype)
     if idx_ref is not None:
         idx_ref[...] = bestk.astype(idx_ref.dtype)
 
 
-def _primal_kernel(yt_ref, a_ref, b_ref, out_ref, *, bf16_pool=False):
-    _fwd_kernel(yt_ref, a_ref, b_ref, out_ref, None, bf16_pool=bf16_pool)
+def _primal_kernel(yt_ref, a_ref, b_ref, out_ref):
+    _fwd_kernel(yt_ref, a_ref, b_ref, out_ref, None)
 
 
 def _bwd_kernel(g_ref, idx_ref, pooled_ref, yt_ref, a_ref,
@@ -368,7 +351,7 @@ def _fwd_impl(yt, a, b, *, want_idx, interpret):
     if want_idx:
         return kernel_call(
             "stem_fwd",
-            functools.partial(_fwd_kernel, bf16_pool=lev["bf16_pool"]),
+            _fwd_kernel,
             grid=grid,
             in_specs=in_specs,
             out_specs=[out_spec, out_spec],
@@ -381,7 +364,7 @@ def _fwd_impl(yt, a, b, *, want_idx, interpret):
         )(yt, a2, b2)
     return kernel_call(
         "stem_fwd",  # one kernel to a trace reader, with or without idx
-        functools.partial(_primal_kernel, bf16_pool=lev["bf16_pool"]),
+        _primal_kernel,
         name="stem_fwd_primal",
         grid=grid,
         in_specs=in_specs,

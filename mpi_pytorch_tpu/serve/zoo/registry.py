@@ -68,7 +68,7 @@ class ModelSpec:
     """One serving tenant: the unit of routing, admission, and retuning."""
 
     model: str  # tenant name (the routing key; defaults to the arch)
-    arch: str  # architecture (config.SUPPORTED_MODELS)
+    arch: str  # architecture (models/registry.available_models)
     checkpoint_dir: str = ""  # "" = serve fresh init (smoke/CI) or cfg's
     precision: str = ""  # "" = the fleet cfg's serve_precision
     buckets: str = ""  # "" = the fleet cfg's serve_buckets
@@ -81,7 +81,7 @@ def parse_model_specs(text: str) -> tuple[ModelSpec, ...]:
     """``--serve-models`` string → validated specs (see module docstring
     for the syntax). Raises ``ValueError`` on malformed entries, unknown
     architectures, or duplicate tenant names."""
-    from mpi_pytorch_tpu.config import SUPPORTED_MODELS
+    from mpi_pytorch_tpu.models.registry import available_models, model_spec
 
     specs: list[ModelSpec] = []
     for entry in (e.strip() for e in text.split(",") if e.strip()):
@@ -125,14 +125,12 @@ def parse_model_specs(text: str) -> tuple[ModelSpec, ...]:
                     f"tenant {name!r}: unknown spec key {key!r} (expected "
                     "ckpt|precision|buckets|admission|cold|shard)"
                 )
-        if arch not in SUPPORTED_MODELS:
+        if arch not in available_models():
             raise ValueError(
                 f"tenant {name!r}: unsupported architecture {arch!r}; "
-                f"expected one of {SUPPORTED_MODELS}"
+                f"expected one of {available_models()}"
             )
-        from mpi_pytorch_tpu.models.registry import TOKEN_MODELS
-
-        if arch in TOKEN_MODELS:
+        if model_spec(arch).sample == "tokens":
             raise ValueError(
                 f"tenant {name!r}: {arch!r} is a token model; serving takes "
                 "image requests only"

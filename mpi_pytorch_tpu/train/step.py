@@ -149,6 +149,14 @@ def _apply_updates(state: TrainState, grads, new_bs) -> TrainState:
         )
 
 
+# The step's own metrics (``_step_metrics``, ``_with_skip_flag``), which the
+# trainer folds into an epoch's loss and counts itself. Whatever else a step
+# reports — a token batch's ``tokens``, the counters a model sows — joins the
+# epoch record under its own name: summed, or maxed where the name ends in
+# ``_max`` (obs/schema.py owns which keys a record may carry).
+STEP_METRICS = ("loss", "correct", "count", "grad_norm", "skipped")
+
+
 def _step_metrics(loss, logits, labels, grads, counters=None) -> dict:
     """The step's own numbers, in every compiler-partitioned step flavor.
     ``counters``: what the model counted in this apply (``_loss_and_updates``;

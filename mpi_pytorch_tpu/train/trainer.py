@@ -34,7 +34,7 @@ from mpi_pytorch_tpu.config import Config
 from mpi_pytorch_tpu.data import DataLoader, load_manifests, manifest_fingerprint
 from mpi_pytorch_tpu.data.tokens import TokenLoader, load_token_manifests
 from mpi_pytorch_tpu.models import create_model_bundle
-from mpi_pytorch_tpu.models.registry import TOKEN_MODELS, token_vocab
+from mpi_pytorch_tpu.models.registry import model_spec
 from mpi_pytorch_tpu.obs import (
     FlightRecorder,
     Heartbeat,
@@ -64,6 +64,7 @@ from mpi_pytorch_tpu.train.state import (
     zero_unshard_opt_state,
 )
 from mpi_pytorch_tpu.train.step import (
+    STEP_METRICS,
     bucket_overlap_frac,
     grad_bucket_plan,
     hier_dcn_overlap_frac,
@@ -167,12 +168,6 @@ def _p0_scalar(value: float, mesh) -> float:
     return _global_max(value if jax.process_index() == 0 else float("-inf"), mesh)
 
 
-# Step metrics that join the epoch record when the step reports them
-# (train/step.py _step_metrics): a token batch's valid positions and the
-# expert layers' counters (models/lfm2.py).
-EPOCH_EXTRAS = ("tokens", "moe_pairs_held", "moe_pairs_absent", "moe_load_max")
-
-
 def _dtype(name: str):
     return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[name]
 
@@ -182,18 +177,6 @@ def build_training(cfg: Config, mesh=None):
     the trainer, the eval pipeline, and the graft entry points."""
     mesh = mesh or create_mesh(cfg.mesh)
     compute_dtype = _dtype(cfg.compute_dtype)
-
-    token_model = cfg.model_name in TOKEN_MODELS
-    if token_model:
-        # Packed sequences, checked against the model's vocabulary (data/tokens.py).
-        train_manifest, test_manifest = load_token_manifests(
-            cfg, token_vocab(cfg.model_name, cfg.model_config)
-        )
-    else:
-        train_manifest, test_manifest = load_manifests(cfg)
-    # Per-host sharding ≙ rank-0 scatter (main.py:84-91): host p reads only
-    # its own shard; no coordinator, no pickled dataframes over the wire.
-    host_shard = train_manifest.shard(jax.process_count(), jax.process_index())
 
     if cfg.batch_size % jax.process_count() != 0:
         raise ValueError(
@@ -214,24 +197,39 @@ def build_training(cfg: Config, mesh=None):
             f"data-parallel size {data_size}"
         )
 
-    train_loader = TokenLoader(host_shard, host_batch) if token_model else DataLoader(
-        host_shard,
-        batch_size=host_batch,
-        image_size=cfg.image_size,
-        shuffle=cfg.shuffle,
-        seed=cfg.seed,
-        drop_remainder=cfg.drop_remainder,
-        synthetic=cfg.synthetic_data,
-        num_workers=cfg.loader_workers,
-        prefetch=cfg.prefetch_batches,
-        image_dtype=cfg.input_dtype,
-        native_decode=cfg.native_decode,
-        decode_prescale=cfg.decode_prescale,
-        host_cache=cfg.host_cache,
-        packed_dir=cfg.packed_dir,
-        max_bad_samples=cfg.max_bad_samples,
-        quarantine_file=cfg.quarantine_file,
-    )
+    # What a sample is, is the model's to say (registry ModelSpec.sample);
+    # manifests and loader follow from it here and nowhere else. Per-host
+    # sharding ≙ rank-0 scatter (main.py:84-91): host p reads only its own
+    # shard; no coordinator, no pickled dataframes over the wire.
+    spec = model_spec(cfg.model_name)
+    if spec.sample == "tokens":
+        # Packed sequences, checked against the model's vocabulary (data/tokens.py).
+        train_manifest, test_manifest = load_token_manifests(
+            cfg, spec.vocab(cfg.model_config)
+        )
+        train_loader = TokenLoader(
+            train_manifest.shard(jax.process_count(), jax.process_index()), host_batch
+        )
+    else:
+        train_manifest, test_manifest = load_manifests(cfg)
+        train_loader = DataLoader(
+            train_manifest.shard(jax.process_count(), jax.process_index()),
+            batch_size=host_batch,
+            image_size=cfg.image_size,
+            shuffle=cfg.shuffle,
+            seed=cfg.seed,
+            drop_remainder=cfg.drop_remainder,
+            synthetic=cfg.synthetic_data,
+            num_workers=cfg.loader_workers,
+            prefetch=cfg.prefetch_batches,
+            image_dtype=cfg.input_dtype,
+            native_decode=cfg.native_decode,
+            decode_prescale=cfg.decode_prescale,
+            host_cache=cfg.host_cache,
+            packed_dir=cfg.packed_dir,
+            max_bad_samples=cfg.max_bad_samples,
+            quarantine_file=cfg.quarantine_file,
+        )
 
     bundle, variables = create_model_bundle(
         cfg.model_name,
@@ -535,7 +533,7 @@ def device_prefetch(
         yield buf.popleft()
 
 
-def build_device_cache(cfg: Config, manifest, loader: DataLoader, mesh):
+def build_device_cache(cfg: Config, manifest, loader, mesh):
     """Materialize the train split as a device-resident dataset with rows
     SHARDED over the data axis — per-device HBM is ``dataset/n_data``, not a
     full replica per chip — plus replicated (tiny) labels. One decode pass
@@ -556,11 +554,10 @@ def build_device_cache(cfg: Config, manifest, loader: DataLoader, mesh):
     n = len(manifest)
     padded = -(-n // n_data) * n_data
     sharding = NamedSharding(mesh, P(data_axis))
-    # A token model's rows are its packed sequences ``int32 [S + 1]`` as they
-    # lie in the pack; its label column is the manifest's row checksums,
-    # which the step does not read.
-    tokens = isinstance(loader, TokenLoader)
-    row = (manifest.tokens.shape[1],) if tokens else (*loader.image_size, 3)
+    # What a row is, is the loader's to say (``cache_row``): a decoded image,
+    # or a token model's packed sequence — whose label column is the
+    # manifest's row checksums, which the step does not read.
+    row, dtype = loader.cache_row
     shape = (padded, *row)
 
     # This host's addressable slice of the sharded rows: contiguous because
@@ -573,53 +570,29 @@ def build_device_cache(cfg: Config, manifest, loader: DataLoader, mesh):
     # Preallocate and fill in place: np.concatenate over a parts list would
     # transiently hold the slice twice, at exactly the scale (GBs) this
     # feature targets. Zeros beyond real_hi are the never-indexed padding.
-    local = np.zeros((hi - lo, *row), np.int32 if tokens else loader.image_dtype)
+    local = np.zeros((hi - lo, *row), dtype)
     labels_np = manifest.labels.astype(np.int32)
-    if tokens:
-        local[: max(real_hi - lo, 0)] = manifest.tokens[lo:real_hi]
-    elif real_hi > lo:
-        ordered = DataLoader(
-            manifest.select(np.arange(lo, real_hi)),
-            batch_size=loader.batch_size,
-            image_size=loader.image_size,
-            shuffle=False,
-            drop_remainder=False,
-            synthetic=loader.synthetic,
-            num_workers=loader.num_workers,
-            prefetch=loader.prefetch,
-            image_dtype=str(np.dtype(loader.image_dtype)),
-            native_decode=loader.native_decode,
-            decode_prescale=loader.decode_prescale,
-            packed_dir=loader.packed_dir,
-            max_bad_samples=loader.max_bad_samples,
-            quarantine_file=loader.quarantine_file,
-        )
-        ordered.metrics = loader.metrics
-        row = 0
-        for batch_images, _ in ordered.epoch(0):
-            local[row : row + batch_images.shape[0]] = batch_images
-            row += batch_images.shape[0]
-        assert row == real_hi - lo, (row, lo, real_hi)
-        if ordered._quarantined:
-            if jax.process_count() > 1:
-                # Each host decodes only its own row range, so a per-host
-                # label mask would make the REPLICATED labels array differ
-                # across hosts — silent divergence inside every collective
-                # step. Abort loudly instead (the quarantine trail names
-                # the files); multi-host runs must fix the data or take
-                # the streaming/host-cache path, whose masking is local.
-                from mpi_pytorch_tpu.data.pipeline import BadSampleLimitError
+    quarantined = loader.fill_cache_rows(manifest, lo, real_hi, local)
+    if quarantined:
+        if jax.process_count() > 1:
+            # Each host decodes only its own row range, so a per-host
+            # label mask would make the REPLICATED labels array differ
+            # across hosts — silent divergence inside every collective
+            # step. Abort loudly instead (the quarantine trail names
+            # the files); multi-host runs must fix the data or take
+            # the streaming/host-cache path, whose masking is local.
+            from mpi_pytorch_tpu.data.pipeline import BadSampleLimitError
 
-                raise BadSampleLimitError(
-                    f"{len(ordered._quarantined)} sample(s) quarantined "
-                    "while building the multi-host device cache — per-host "
-                    "label masking cannot stay consistent across hosts; "
-                    "repair/remove the corrupt files (see the quarantine "
-                    "log) or drop --device-cache"
-                )
-            # Quarantined rows hold substitute pixels — mask their labels.
-            labels_np = labels_np.copy()
-            labels_np[lo + np.fromiter(ordered._quarantined, int)] = -1
+            raise BadSampleLimitError(
+                f"{len(quarantined)} sample(s) quarantined "
+                "while building the multi-host device cache — per-host "
+                "label masking cannot stay consistent across hosts; "
+                "repair/remove the corrupt files (see the quarantine "
+                "log) or drop --device-cache"
+            )
+        # Quarantined rows hold substitute pixels — mask their labels.
+        labels_np = labels_np.copy()
+        labels_np[lo + np.fromiter(quarantined, int)] = -1
 
     rep = NamedSharding(mesh, P())
     if jax.process_count() == 1:
@@ -1546,7 +1519,7 @@ def _train_impl(
             health.start_epoch()  # re-arm the recompile counter per epoch
             heartbeat.start_epoch()  # beats never span epoch boundaries
             losses, counts = [], []
-            extras: dict[str, list] = {}  # EPOCH_EXTRAS the steps reported
+            extras: dict[str, list] = {}  # what the steps counted beside STEP_METRICS
             loss_v = count_v = None  # [steps] device arrays, set below
             rollback_trigger = None  # (reason, step) breaking the step loop
             tracer.end(control, args={"epoch": epoch})
@@ -1605,7 +1578,7 @@ def _train_impl(
                         jax.block_until_ready(m["loss"])
                 _profile_start(epoch, m["loss"])
                 loss_v, count_v = m["loss"], m["count"]
-                extras = {k: [m[k]] for k in EPOCH_EXTRAS if k in m}
+                extras = {k: [v] for k, v in m.items() if k not in STEP_METRICS}
                 skipped_before_epoch = steps_skipped_total
                 if bad_step_skip and "skipped" in m:
                     # Mask skipped steps out of the epoch accounting (a
@@ -1695,9 +1668,9 @@ def _train_impl(
                 else:
                     losses.append(m["loss"])
                     counts.append(m["count"])
-                for k in EPOCH_EXTRAS:
-                    if k in m:
-                        extras.setdefault(k, []).append(m[k])
+                for k, v in m.items():
+                    if k not in STEP_METRICS:
+                        extras.setdefault(k, []).append(v)
                 health.on_step(
                     epoch, step_i, m, data_wait_s, step_s,
                     skipped=was_skipped,

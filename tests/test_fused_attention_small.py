@@ -1,8 +1,6 @@
 """The single-pass dense attention kernels (Pallas, interpret mode on CPU) vs
 the plain ``full_attention`` reference — values, grads, bf16, S=64 (vit_s16)
-and S=196 (ViT-B/16), both operand layouts (``rows``: the projections'
-``[B, S, H·Dh]`` as it lies, the path; ``grouped``: the ``bh_block`` lever),
-head geometries, the shape dispatch under ``attn_impl="full"``
+and S=196 (ViT-B/16), head geometries, the shape dispatch under ``attn_impl="full"``
 (``dense_attention``), the multi-chip shard_map path, and the spmd
 (bound-axis) path. The kernels compute the SAME function as full attention,
 so every check is an exact-to-tolerance comparison."""
@@ -17,7 +15,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from mpi_pytorch_tpu.obs import trace as obs_trace
 from mpi_pytorch_tpu.ops.fused_attention_small import (
-    _bh_block,
     dense_attention,
     fused_attention_small,
 )
@@ -36,40 +33,28 @@ def _mesh():
     return Mesh(np.array(jax.devices()).reshape(-1, 1), ("data", "model"))
 
 
-# The two operand layouts: the rows layout is what a plain call takes; an
-# explicit bh_block asks for the grouped one (S_pad <= 128 only).
-LAYOUT = {"rows": {}, "grouped": {"bh_block": 2}}
-
-
 def _all_grads(fn, q, k, v):
     f = lambda q_, k_, v_: jnp.sum(fn(q_, k_, v_).astype(jnp.float32) ** 2)
     return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
 
 
-@pytest.mark.parametrize("s,layout", [
-    (64, "rows"), (65, "rows"), (50, "rows"), (128, "rows"), (196, "rows"),
-    (64, "grouped"), (65, "grouped"), (50, "grouped"), (128, "grouped"),
-])
-def test_values_match_full_attention(s, layout):
+@pytest.mark.parametrize("s", [64, 65, 50, 128, 196])
+def test_values_match_full_attention(s):
     """S=64 (the vit_s16 regime), odd S=65 (class-token variant), S=50 (off
-    the sublane tile: whole-S blocks in the rows layout, padded rows in the
-    grouped one), S=128 (the grouped layout's edge) and S=196 (ViT-B/16 at
-    224 px)."""
+    the sublane tile: a block of the array's whole S needs no padding),
+    S=128 and S=196 (ViT-B/16 at 224 px)."""
     q, k, v = _qkv(0, s=s)
-    got = fused_attention_small(q, k, v, interpret=True, **LAYOUT[layout])
+    got = fused_attention_small(q, k, v, interpret=True)
     want = full_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("s,layout", [
-    (64, "rows"), (50, "rows"), (196, "rows"), (64, "grouped"), (50, "grouped"),
-])
-def test_grads_match_full_attention(s, layout):
+@pytest.mark.parametrize("s", [64, 50, 196])
+def test_grads_match_full_attention(s):
     q, k, v = _qkv(1, s=s)
     g_fused = _all_grads(
-        lambda *a: fused_attention_small(*a, interpret=True, **LAYOUT[layout]),
-        q, k, v,
+        lambda *a: fused_attention_small(*a, interpret=True), q, k, v
     )
     g_full = _all_grads(full_attention, q, k, v)
     for a, b in zip(g_fused, g_full):
@@ -78,13 +63,11 @@ def test_grads_match_full_attention(s, layout):
         assert np.isfinite(np.asarray(a)).all()
 
 
-@pytest.mark.parametrize("s,layout", [(64, "rows"), (196, "rows"), (64, "grouped")])
-def test_causal_matches_full_attention(s, layout):
+@pytest.mark.parametrize("s", [64, 196])
+def test_causal_matches_full_attention(s):
     """Values and all three gradients under the causal mask."""
     q, k, v = _qkv(2, s=s)
-    fused = lambda *a: fused_attention_small(
-        *a, causal=True, interpret=True, **LAYOUT[layout]
-    )
+    fused = lambda *a: fused_attention_small(*a, causal=True, interpret=True)
     full = lambda *a: full_attention(*a, causal=True)
     np.testing.assert_allclose(np.asarray(fused(q, k, v)),
                                np.asarray(full(q, k, v)), rtol=2e-5, atol=2e-5)
@@ -95,7 +78,7 @@ def test_causal_matches_full_attention(s, layout):
 
 @pytest.mark.parametrize("h,d", [(1, 128), (4, 32), (2, 48), (2, 8)])
 def test_head_geometries_match_full_attention(h, d):
-    """The rows layout tells heads apart by lane masks inside 128-lane groups
+    """The kernel tells heads apart by lane masks inside 128-lane groups
     (one head of 128: no mask; four of 32), and inside one group of all
     H·Dh lanes in a model narrower than a tile (2x48 = 96 lanes, 2x8 = 16):
     values and gradients either way."""
@@ -110,12 +93,10 @@ def test_head_geometries_match_full_attention(h, d):
                                    rtol=5e-5, atol=5e-5)
 
 
-@pytest.mark.parametrize("s,layout", [(64, "rows"), (196, "rows"), (64, "grouped")])
-def test_bf16_values_and_grads(s, layout):
+@pytest.mark.parametrize("s", [64, 196])
+def test_bf16_values_and_grads(s):
     q, k, v = _qkv(3, s=s, dtype=jnp.bfloat16)
-    fused_attention = functools.partial(
-        fused_attention_small, interpret=True, **LAYOUT[layout]
-    )
+    fused_attention = functools.partial(fused_attention_small, interpret=True)
     got = fused_attention(q, k, v)
     want = full_attention(q, k, v)
     assert got.dtype == jnp.bfloat16
@@ -134,46 +115,6 @@ def test_bf16_values_and_grads(s, layout):
         np.asarray(g_fused, np.float32), np.asarray(g_full, np.float32),
         rtol=5e-2, atol=5e-1,
     )
-
-
-@pytest.mark.parametrize("g", [1, 2, 4])
-def test_bh_block_lever_is_exact(g):
-    """The bh-grouping lever re-tiles the grid; the masked off-diagonal
-    blocks must contribute exactly nothing (values AND grads)."""
-    q, k, v = _qkv(4)
-    got = fused_attention_small(q, k, v, bh_block=g, interpret=True)
-    want = full_attention(q, k, v)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-    g_fused = jax.grad(
-        lambda q_: jnp.sum(
-            fused_attention_small(q_, k, v, bh_block=g, interpret=True) ** 2
-        )
-    )(q)
-    g_full = jax.grad(lambda q_: jnp.sum(full_attention(q_, k, v) ** 2))(q)
-    np.testing.assert_allclose(np.asarray(g_fused), np.asarray(g_full),
-                               rtol=5e-5, atol=5e-5)
-
-
-def test_bh_block_env_gate(monkeypatch):
-    """MPT_ATTN_BH_BLOCK overrides the default; non-divisors are reduced."""
-    assert _bh_block(12, 64) == 2
-    assert _bh_block(12, 128) == 1
-    assert _bh_block(12, 56) == 2
-    monkeypatch.setenv("MPT_ATTN_BH_BLOCK", "4")
-    assert _bh_block(12, 64) == 4
-    assert _bh_block(9, 64) == 3  # 4 does not divide 9 → reduced
-    # the explicit kwarg beats the env gate
-    assert _bh_block(12, 64, override=6) == 6
-    # VMEM envelope: G·S_pad capped at 512, so an aggressive override
-    # degrades to a buildable grouping instead of a compile failure
-    assert _bh_block(12288, 64, override=64) == 8
-    assert _bh_block(12288, 128, override=64) == 4
-    q, k, v = _qkv(5)
-    got = fused_attention_small(q, k, v, interpret=True)
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(full_attention(q, k, v)),
-                               rtol=2e-5, atol=2e-5)
 
 
 def test_cpu_fallback_and_envelope():
@@ -414,7 +355,7 @@ def test_attn_impl_config_validation():
 
     ok = parse_config(["--model-name", "vit_s16", "--attn-impl", "fused-small"])
     assert ok.attn_impl == "fused-small"
-    with pytest.raises(ValueError, match="no\\s+attention|has no"):
+    with pytest.raises(ValueError, match="attn_impl='fused-small' does not apply to model 'resnet18'"):
         parse_config(["--attn-impl", "fused-small"])  # default resnet18
     with pytest.raises(ValueError, match="choose one"):
         parse_config(["--model-name", "vit_s16", "--attn-impl", "fused-small",

@@ -66,33 +66,9 @@ def test_bf16_storage_roundtrip(rng):
     )
 
 
-def _bf16_pool_reference(y, a, b):
-    """XLA reference for the MPT_STEM_BF16_POOL lever: pooling over the
-    bf16-ROUNDED post-relu activations. Rounding is monotone (a ≥ b ⇒
-    bf16(a) ≥ bf16(b)), so the window winner and reduce_window's row-major
-    first-match tie semantics transfer exactly — value AND gradient
-    routing are pinned tightly against this, not loosely against f32.
-
-    The rounding is STRAIGHT-THROUGH (stop_gradient) to mirror the kernel
-    exactly: bf16 values pick the winner, but the backward routes the
-    FULL-PRECISION f32 cotangent — a plain .astype chain would instead
-    bf16-round the cotangent sums at positions winning several windows."""
-    from jax import lax
-
-    from mpi_pytorch_tpu.ops.fused_stem import nn_max_pool_f32
-
-    z = jax.nn.relu(y.astype(jnp.float32) * a + b)
-    z = z + lax.stop_gradient(
-        z.astype(jnp.bfloat16).astype(jnp.float32) - z
-    )
-    return nn_max_pool_f32(z).astype(y.dtype)
-
-
 _LEVERS = [
-    # (env, value, reference): the §4d byte-bound lever gates. bf16
-    # pooling is pinned against the bf16-rounded reference (see above);
-    # the other three are exact re-tilings pinned against the f32 one.
-    ("MPT_STEM_BF16_POOL", "1", _bf16_pool_reference),
+    # (env, value, reference): the §4d byte-bound lever gates — exact
+    # re-tilings, pinned against the f32 reference.
     ("MPT_STEM_LANES", "256", _reference_impl),
     ("MPT_STEM_IDX_INT8", "1", _reference_impl),
     ("MPT_STEM_C_BLOCK", "16", _reference_impl),
@@ -115,7 +91,7 @@ def test_levers_match_reference(rng, monkeypatch, env, val, reference, tie_heavy
     # Power-of-two scales make y·a EXACT, so a+b is the affine's only f32
     # rounding and FMA ≡ mul+add — otherwise the kernel's and the XLA
     # reference's 1-ulp f32 contraction differences land on bf16 rounding
-    # boundaries and the bf16-pool comparison sees spurious bf16-ulp flips.
+    # boundaries.
     a = jnp.asarray(2.0 ** rng.integers(-1, 2, C).astype(np.float32))
     b = jnp.asarray(
         (rng.standard_normal(C).astype(np.float32) * 0.1)
@@ -425,18 +401,15 @@ def test_densenet_fused_stem_registry_and_default():
     """densenet121 is fused-stem CAPABLE (--fused-stem builds it) but NOT a
     bench default until its chip A/B lands (docs/RESULTS.md §4: stem tail
     ≈3% of its roofline bound — the fused-head discipline)."""
-    from mpi_pytorch_tpu.models.registry import (
-        FUSED_STEM_MODELS,
-        MEASURED_FUSED_STEM_MODELS,
-        initialize_model,
-    )
+    from mpi_pytorch_tpu.models.registry import initialize_model, model_spec
 
-    assert "densenet121" in FUSED_STEM_MODELS
-    assert "densenet121" not in MEASURED_FUSED_STEM_MODELS
+    spec = model_spec("densenet121")
+    assert spec.accepts("fused_stem", True) and not spec.fused_stem_measured
+    assert model_spec("resnet18").fused_stem_measured
     model, _ = initialize_model("densenet121", 5, fused_stem=True)
     assert model.fused_stem
     # fused_stem_default is platform-gated (TPU); on the CPU test mesh it
-    # must be False for every model regardless of the measured tuple.
+    # must be False for every model regardless of ``fused_stem_measured``.
     from mpi_pytorch_tpu.models.registry import fused_stem_default
 
     assert not fused_stem_default("densenet121")

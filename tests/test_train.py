@@ -365,6 +365,25 @@ def test_scan_epoch_matches_per_step_cache(tmp_path):
     np.testing.assert_allclose(sa.epoch_losses, sb.epoch_losses, rtol=5e-3)
 
 
+def test_step_metrics_names_what_the_step_itself_reports():
+    """``STEP_METRICS`` is the set the trainer folds into loss and counts
+    itself; every other key a step reports joins the epoch record under its
+    own name — so the set must be exactly what the step's own code emits."""
+    import jax.numpy as jnp
+
+    from mpi_pytorch_tpu.train.step import STEP_METRICS, _step_metrics, _with_skip_flag
+
+    grads = {"w": jnp.ones(3)}
+    images = _step_metrics(jnp.float32(1.0), jnp.zeros((4, 3)), jnp.array([0, 1, 2, -1]), grads)
+    assert set(_with_skip_flag(images, jnp.bool_(True))) == set(STEP_METRICS)
+    tokens = _step_metrics(
+        jnp.float32(1.0), jnp.zeros((2, 5, 3)), jnp.array([[0, 1, 2, -1, -1]] * 2), grads,
+        {"moe_load_max": jnp.int32(3)},
+    )
+    assert set(tokens) - set(STEP_METRICS) == {"tokens", "moe_load_max"}
+    assert int(tokens["tokens"]) == 6 and int(tokens["count"]) == 2
+
+
 def test_scan_epoch_requires_device_cache():
     with pytest.raises(ValueError, match="scan_epoch"):
         Config(scan_epoch=True).validate_config()
@@ -475,7 +494,7 @@ def test_cached_eval_matches_streaming_eval(tmp_path):
 
 
 def test_remat_blocks_rejects_non_resnet():
-    with pytest.raises(ValueError, match="not implemented for"):
+    with pytest.raises(ValueError, match="remat_blocks=True does not apply to model 'alexnet'"):
         Config(remat="blocks", model_name="alexnet").validate_config()
 
 

@@ -152,5 +152,5 @@ def test_moe_vit_handles_awkward_token_counts():
 
 
 def test_registry_rejects_ep_on_dense_model(ep_mesh):
-    with pytest.raises(ValueError, match="MoE"):
+    with pytest.raises(ValueError, match="ep_mesh does not apply to model 'vit_s16'.*vit_moe_s16"):
         initialize_model("vit_s16", 10, ep_mesh=ep_mesh)

@@ -8,9 +8,7 @@ Default mode sweeps long sequences — the flash kernel's domain:
 ``--fused-small`` is the single-pass kernel's A/B (PERF.md section 6, PR 25
 has its chip rows): S ∈ {64, 65, 50, 128, 196} at a (batch·head) count big
 enough to fill the grid (``--heads 12 --batch 128 --seqs 196`` is ViT-B/16's
-attention), one JSON row per (impl, S) — ``auto`` is the rows layout, the
-path — plus one per ``MPT_ATTN_BH_BLOCK`` value of the grouped layout (not
-consulted above S_pad 128: those rows time the rows layout again) — each fused row
+attention), one JSON row per (impl, S) — each fused row
 CORRECTNESS-GATED against full attention on chip before any timing ships,
 and the ambient ``MPT_ATTN_*`` environment snapshotted/cleared/restored
 around the sweep so an operator's exported lever cannot contaminate a row
@@ -40,14 +38,10 @@ H, D = 6, 64  # vit_s16-shaped heads
 DEFAULT_BATCH = 4          # long-S mode: S×S dominates, tiny B suffices
 FUSED_SMALL_BATCH = 256    # tiny-S mode: enough (b·h) tiles to fill the grid
 
-# (label, env) — "auto" is the kernel as the models call it (the rows
-# layout); the rest is the grouped layout's bh-grouping lever matrix
-# (MPT_ATTN_BH_BLOCK; ops/fused_attention_small.py _bh_block).
+# (label, env) — "auto" is the kernel as the models call it; a lever under
+# test adds its row here.
 FUSED_SMALL_CONFIGS = [
     ("auto", {}),
-    ("bh1", {"MPT_ATTN_BH_BLOCK": "1"}),
-    ("bh2", {"MPT_ATTN_BH_BLOCK": "2"}),
-    ("bh4", {"MPT_ATTN_BH_BLOCK": "4"}),
 ]
 
 
@@ -227,9 +221,8 @@ def main() -> None:
                     help=f"heads of {D} (default {H}: vit_s16; 12: ViT-B/16)")
     ap.add_argument("--fused-small", action="store_true",
                     help="the single-pass kernel's A/B: full/flash vs the "
-                    "kernel's rows layout and per MPT_ATTN_BH_BLOCK value of "
-                    "the grouped one (correctness-gated, ambient MPT_ATTN_* "
-                    "cleared per row)")
+                    "kernel (correctness-gated, ambient MPT_ATTN_* cleared "
+                    "per row)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--warmup", type=int, default=3)
     ap.add_argument("--out", default="")
@@ -237,9 +230,8 @@ def main() -> None:
 
     if args.seqs is None:
         # 64 = the vit_s16 token count (GAP head, S == patch count); 65 =
-        # the class-token variant (odd S → padded rows + bh-group G=1, a
-        # different tiling); 50 = heavy padding; 128 = the grouped layout's
-        # edge; 196 = ViT-B/16 at 224 px.
+        # the class-token variant (odd S); 50 = off the sublane tile; 128 =
+        # one full lane tile of keys; 196 = ViT-B/16 at 224 px.
         args.seqs = "64,65,50,128,196" if args.fused_small else "512,1024,2048,4096"
     if args.batch is None:
         args.batch = FUSED_SMALL_BATCH if args.fused_small else DEFAULT_BATCH

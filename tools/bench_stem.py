@@ -4,8 +4,8 @@ Default mode: the fused-vs-reference A/B (fwd and fwd+bwd) that produced
 the §4d round-5 numbers.
 
 ``--levers``: one JSON row per §4d byte-bound lever configuration
-(docs/RESULTS.md §4d, round 6) — r5-default, bf16-pool, lanes-256,
-idx-int8, c-block-16, and all-four — each correctness-checked against the
+(docs/RESULTS.md §4d, round 6) — r5-default, lanes-256, idx-int8,
+c-block-16, and all-three — each correctness-checked against the
 XLA reference on chip before timing, so every lever lands in the table as
 a measured ship-or-rejection row, never a silent drop. Lever gates are
 read from the env at TRACE time (ops/fused_stem.py:_levers), so each
@@ -28,14 +28,12 @@ B, H, W, C = 2048, 64, 64, 64
 # (label, env) — the §4d lever matrix. Values mirror the MPT_STEM_* gates.
 LEVER_CONFIGS = [
     ("r5-default", {}),
-    ("bf16-pool", {"MPT_STEM_BF16_POOL": "1"}),
     ("lanes-256", {"MPT_STEM_LANES": "256"}),
     ("idx-int8", {"MPT_STEM_IDX_INT8": "1"}),
     ("c-block-16", {"MPT_STEM_C_BLOCK": "16"}),
     (
-        "all-four",
+        "all-three",
         {
-            "MPT_STEM_BF16_POOL": "1",
             "MPT_STEM_LANES": "256",
             "MPT_STEM_IDX_INT8": "1",
             "MPT_STEM_C_BLOCK": "16",
@@ -83,8 +81,7 @@ def timeit(f, *args, n=30):
 def check(fus_fwd, fus_fb, ref_fwd, ref_fb, y, a, b, co):
     """On-chip correctness gate before any timing ships. bf16 storage
     tolerances (2e-2 values / 3e-1 grad atol) — identical to the round-5
-    A/B gate; the bf16-pool lever stays within them because the stored
-    output is bf16-rounded either way."""
+    A/B gate."""
     rf = ref_fwd(y, a, b)
     ff = fus_fwd(y, a, b)
     np.testing.assert_allclose(

@@ -12,11 +12,8 @@ Two readers share ``CASES``:
   (``lowering_platforms=("tpu",)``) and finds its ``tpu_custom_call`` —
   Pallas API drift caught in seconds, before a chip is asked.
 
-A kernel Mosaic refuses is a failing row here until it is repaired — or,
-where the repair is not small, until its case records the compiler's
-message (``refused_with``): then the row passes only while Mosaic still says
-exactly that, and fails the day the kernel compiles, so the record cannot
-outlive the refusal. Either way it never quietly becomes its reference.
+A kernel Mosaic refuses is a failing row here until it is repaired or
+deleted: it never quietly becomes its reference.
 """
 
 from __future__ import annotations
@@ -54,7 +51,6 @@ class Case(NamedTuple):
     make_args: Callable[[], tuple]  # seeded inputs (jax.random: eval_shape-able)
     env: dict = {}  # MPT_* levers, read at trace time (never mutated)
     tol: float = 2e-2  # relative L2, bf16 storage on inputs/outputs
-    refused_with: str = ""  # the compiler's message, where Mosaic is KNOWN to refuse
 
 
 def _with_grads(f: Callable, n_diff: int) -> Callable:
@@ -85,7 +81,7 @@ def _stem_args():
     return y, a, b, co
 
 
-def _stem_case(name: str, env: dict, refused_with: str = "") -> Case:
+def _stem_case(name: str, env: dict) -> Case:
     from mpi_pytorch_tpu.ops.fused_stem import _reference_impl, stem_affine_relu_pool
 
     return Case(
@@ -94,7 +90,6 @@ def _stem_case(name: str, env: dict, refused_with: str = "") -> Case:
         _with_grads(_reference_impl, 3),
         _stem_args,
         env=env,
-        refused_with=refused_with,
     )
 
 
@@ -185,11 +180,6 @@ def _cases() -> list[Case]:
 
     cases = [
         _stem_case("stem", {}),
-        # v5e has no bf16 vector compare (PR 21); ROADMAP D4 deletes the lever.
-        _stem_case(
-            "stem[MPT_STEM_BF16_POOL=1]", {"MPT_STEM_BF16_POOL": "1"},
-            refused_with="Target does not support this comparison",
-        ),
         _stem_case("stem[MPT_STEM_LANES=256]", {"MPT_STEM_LANES": "256"}),
         _stem_case("stem[MPT_STEM_IDX_INT8=1]", {"MPT_STEM_IDX_INT8": "1"}),
         _stem_case("stem[MPT_STEM_C_BLOCK=16]", {"MPT_STEM_C_BLOCK": "16"}),
@@ -294,18 +284,11 @@ def run_case(case: Case) -> dict:
         got = jax.block_until_ready(compiled(*args))
     except Exception as e:  # noqa: BLE001 — the report boundary: every kernel gets a row
         traceback.print_exc()
-        recorded = bool(case.refused_with) and case.refused_with in str(e)
-        row.update(
-            status="refused as recorded" if recorded else "refused",
-            error=f"{type(e).__name__}: {e}"[:2000],
-        )
+        row.update(status="refused", error=f"{type(e).__name__}: {e}"[:2000])
         return row
     want = jax.block_until_ready(jax.jit(case.ref)(*args))
     row["rel_l2"], ok = _compare(got, want, case.tol)
-    if case.refused_with:
-        row["status"] = "compiles now: drop its refused_with"
-    else:
-        row["status"] = "compiled" if ok and row["mosaic_calls"] else "wrong"
+    row["status"] = "compiled" if ok and row["mosaic_calls"] else "wrong"
     return row
 
 
@@ -415,7 +398,7 @@ def main() -> None:
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/chip_kernels.json", "w") as f:
         json.dump(rows, f, indent=1)
-    passing = ("compiled", "raises", "refused as recorded")
+    passing = ("compiled", "raises")
     bad = [r["kernel"] for r in rows if r["status"] not in passing]
     if bad:
         raise SystemExit(f"chip_kernels: {len(bad)} kernel(s) refused or wrong: {bad}")

@@ -63,7 +63,7 @@ def main() -> None:
                          "bucket's cross-pod phase hides under the backward")
     args = ap.parse_args()
 
-    from mpi_pytorch_tpu.models.registry import supports_remat_blocks
+    from mpi_pytorch_tpu.models.registry import check_build_flags
     from mpi_pytorch_tpu.train.step import (
         bucket_overlap_frac,
         grad_bucket_plan,
@@ -72,8 +72,10 @@ def main() -> None:
     )
     from mpi_pytorch_tpu.utils.hardware import peak_bf16_tflops, step_flops
 
-    if args.remat == "blocks" and not supports_remat_blocks(args.model):
-        ap.error(f"--remat blocks not implemented for {args.model}")
+    try:
+        check_build_flags(args.model, remat_blocks=args.remat == "blocks")
+    except ValueError as e:
+        ap.error(str(e))
     if (args.zero_opt_state or args.grad_sync_buckets) and not args.spmd:
         ap.error("--zero-opt-state / --grad-sync-buckets are spmd-step levers; add --spmd")
     if args.mesh_pods > 1 and not args.spmd:
