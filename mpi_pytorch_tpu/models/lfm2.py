@@ -31,9 +31,9 @@ counted (``moe_pairs_absent``). A sliced vocabulary is a smaller
 Device scopes: ``shortconv``, ``attention`` (q/k norms, RoPE and the
 attention call; the projections stay outside), ``moe`` (with ``moe/route``,
 ``moe/dispatch``, ``moe/experts``, ``moe/combine`` beneath), ``head``. The
-expert layers sow ``moe_pairs_held`` / ``moe_pairs_absent`` / ``moe_load_max``
-into the ``counters`` collection, which the train step sums (``_max``: takes
-the largest of) into its step metrics.
+expert layers sow ``moe_pairs_held`` / ``moe_pairs_absent`` / ``moe_load_max`` /
+``moe_rows_computed`` into the ``counters`` collection, which the train step
+sums (``_max``: takes the largest of) into its step metrics.
 """
 
 from __future__ import annotations

@@ -13,7 +13,7 @@ Record kinds (every record also carries ``ts``, the epoch-seconds stamp
 
 | kind      | required                                            | optional |
 |-----------|-----------------------------------------------------|----------|
-| epoch     | epoch, loss, time_s, images_per_sec                 | tflops, mfu_pct, tokens, tokens_per_sec, moe_pairs_held, moe_pairs_absent, moe_load_max |
+| epoch     | epoch, loss, time_s, images_per_sec                 | tflops, mfu_pct, tokens, tokens_per_sec, moe_pairs_held, moe_pairs_absent, moe_load_max, moe_rows_computed |
 | val       | epoch, accuracy, loss                               |          |
 | eval      | accuracy, loss, images, time_s                      |          |
 | step      | epoch, step, loss                                   | grad_norm, data_wait_ms, step_ms, recompiles, hbm_bytes, sync_ms, overlap_frac, dcn_overlap_frac, skipped, steps_skipped |
@@ -301,6 +301,7 @@ OPTIONAL: dict[str, dict[str, tuple]] = {
         # its expert layers' counters: the epoch's sums, the largest load.
         "tokens": _INT, "tokens_per_sec": _NUM,
         "moe_pairs_held": _INT, "moe_pairs_absent": _INT, "moe_load_max": _INT,
+        "moe_rows_computed": _INT,
     },
     "val": {},
     "eval": {},

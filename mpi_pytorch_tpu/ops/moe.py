@@ -34,6 +34,7 @@ share nothing yet; ROADMAP's Design queue says which cell would settle them.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -284,41 +285,145 @@ def grouped_matmul(rows, weights, group_sizes):
     return lax.ragged_dot(rows, weights, group_sizes, preferred_element_type=rows.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _pair_rows(x, perm, inv_perm, top_k: int):
-    """Row r of the result is the token of routed pair ``perm[r]``. Pairs are
+# A row bound is a whole number of these: the grouped matmul tiles its rows.
+ROW_TILE = 128
+
+
+def row_bound(pairs: int, held: int, routed: int) -> int:
+    """``C``, the rows of the expert layer's buffers: twice the share of the
+    ``pairs`` (tokens x choices) that ``held`` of ``routed`` experts expect,
+    in whole row tiles, and never more than all of them — which it is where
+    every routed expert is held. Twice, because a pass costs by its rows
+    whether filled or not: while the cell's router is sound every layer of
+    every step holds under 1.1 times the share, and four times the share
+    cost its layer 4.4 ms of 14.7 more (PERF.md section 6, PR 30)."""
+    expected = -(-2 * pairs * held // routed)
+    return min(pairs, -(-expected // ROW_TILE) * ROW_TILE)
+
+
+class _Sorted(NamedTuple):
+    """The routed pairs sorted by held expert, absent ones last. Pairs are
     numbered CHOICE-major — pair p is choice ``p // T`` of token ``p % T`` —
-    so that ``[k * T, D]`` pair rows split into ``[k, T, D]`` along the major
-    dimension alone (token-major pairs would put ``k`` beside ``D`` in the
-    tiled layout, a padded copy each way: 3 ms a layer at 65 536 pairs).
-    ``inv_perm`` is handed in so that the cotangent is a gather too
-    (``ct[inv_perm]``, summed over a token's choices): the transpose of a
-    gather is a scatter-add that cannot know its indices are a permutation."""
-    return jnp.take(x, perm % x.shape[0], axis=0)
+    so that ``[k * T]`` splits into ``[k, T]`` along the major dimension."""
+
+    order: jax.Array  # [chunks * C] int32: the pair at each sorted position (padded)
+    place: jax.Array  # [k, T] int32: the sorted position of each pair
+    ends: jax.Array  # [H] int32: where each held expert's run of positions ends
+    n_held: jax.Array  # int32: positions below it hold a pair of a held expert
 
 
-def _pair_rows_fwd(x, perm, inv_perm, top_k):
-    return _pair_rows(x, perm, inv_perm, top_k), inv_perm
+def _chunk(pairs: _Sorted, start, bound: int, tokens: int):
+    """Sorted positions ``start .. start + bound``: (their pairs, their
+    tokens, how many of them each held expert has, how many are held)."""
+    ids = lax.dynamic_slice_in_dim(pairs.order, start, bound)
+    sizes = jnp.diff(jnp.clip(pairs.ends - start, 0, bound), prepend=0)
+    return ids, ids % tokens, sizes, jnp.clip(pairs.n_held - start, 0, bound)
 
 
-def _pair_rows_bwd(top_k, inv_perm, ct):
-    by_choice = jnp.take(ct, inv_perm, axis=0).reshape(top_k, -1, ct.shape[-1])
-    return jnp.sum(by_choice.astype(jnp.float32), axis=0).astype(ct.dtype), None, None
+def _expert_ffn(rows, w1, w3, w2, sizes):
+    hidden = jax.nn.silu(grouped_matmul(rows, w1, sizes)) * grouped_matmul(rows, w3, sizes)
+    return grouped_matmul(hidden, w2, sizes)
 
 
-_pair_rows.defvjp(_pair_rows_fwd, _pair_rows_bwd)
+def _by_token(buffer, at, n_live):
+    """``buffer [C, ...]`` read once a pair: ``[k, T, ...]``, entry ``(j, t)``
+    the row at ``at[j, t]`` where that lies in ``0 .. n_live``, else 0. The
+    way from sorted rows back to tokens as a gather: its transpose, a
+    scatter-add, cannot know that the live positions are distinct (1.9 ms for
+    16 384 rows of 2 048 where this read and its sum take 1.0). Every pass
+    is read on its own: a gather costs by its operand, 0.41 ms from these
+    67 MB and 2.39 from one ``[T * k, D]`` buffer all passes would land in.
+    Rows past ``n_live`` hold whatever the buffer held, so they are masked,
+    never multiplied by zero."""
+    live = (at >= 0) & (at < n_live)
+    rows = jnp.take(buffer, at.reshape(-1), axis=0, mode="clip").reshape(at.shape + buffer.shape[1:])
+    return jnp.where(live.reshape(at.shape + (1,) * (buffer.ndim - 1)), rows, 0)
 
 
-@jax.custom_vjp
-def _unsort(rows, perm, inv_perm):
-    """``rows[inv_perm]``: sorted rows back in pair order; cotangent ``ct[perm]``."""
-    return jnp.take(rows, inv_perm, axis=0)
+def _chunk_forward(bound, start, x, w1, w3, w2, share, pairs):
+    """What the held pairs at sorted positions ``start .. start + bound`` add
+    to ``y [T, D]`` (float32)."""
+    _, token, sizes, n_live = _chunk(pairs, start, bound, x.shape[0])
+    with jax.named_scope("moe/dispatch"):
+        rows = jnp.take(x, token, axis=0, mode="clip")
+    with jax.named_scope("moe/experts"):
+        out = _expert_ffn(rows, w1, w3, w2, sizes)
+    with jax.named_scope("moe/combine"):
+        mine = _by_token(out, pairs.place - start, n_live).astype(jnp.float32)
+        return jnp.sum(mine * share[..., None], axis=0)
 
 
-_unsort.defvjp(
-    lambda rows, perm, inv_perm: (jnp.take(rows, inv_perm, axis=0), perm),
-    lambda perm, ct: (jnp.take(ct, perm, axis=0), None, None),
-)
+def _chunk_backward(bound, start, x, w1, w3, w2, share, pairs, ct_y):
+    """The same chunk recomputed and pulled back: its part of the cotangents
+    of ``x`` (float32), ``w1``, ``w3``, ``w2`` and ``share``."""
+    ids, token, sizes, n_live = _chunk(pairs, start, bound, x.shape[0])
+    at = pairs.place - start
+    live = (jnp.arange(bound) < n_live)[:, None]
+    with jax.named_scope("moe/dispatch"):
+        rows = jnp.take(x, token, axis=0, mode="clip")
+    with jax.named_scope("moe/experts"):
+        out, pull = jax.vjp(lambda *a: _expert_ffn(*a, sizes), rows, w1, w3, w2)
+    with jax.named_scope("moe/combine"):
+        weight = jnp.take(share.reshape(-1), ids, mode="clip")
+        ct_y_rows = jnp.take(ct_y, token, axis=0, mode="clip").astype(jnp.float32)
+        ct_out = jnp.where(live, ct_y_rows * weight[:, None], 0).astype(out.dtype)
+        ct_share = _by_token(jnp.sum(out.astype(jnp.float32) * ct_y_rows, axis=-1), at, n_live)
+    with jax.named_scope("moe/experts"):
+        ct_rows, ct_w1, ct_w3, ct_w2 = pull(ct_out)
+    with jax.named_scope("moe/dispatch"):
+        ct_x = jnp.sum(_by_token(ct_rows, at, n_live).astype(jnp.float32), axis=0)
+    return ct_x, ct_w1, ct_w3, ct_w2, ct_share
+
+
+def _live_chunks(bound: int, pairs: _Sorted, chunk):
+    """The sum of ``chunk(start)`` over the chunks of ``bound`` sorted
+    positions that hold a held pair — the first whatever it holds, the others
+    in a loop on the device that ends at ``n_held``: as many passes as the
+    routing demands, one where the held pairs fit ``bound``. Returns (the
+    rows the passes ran over, the sum)."""
+    # The first pass is not the loop's, though that compiles the body twice:
+    # a sum onto zeros in a loop's carry is two [T, D] float32 adds and three
+    # of the experts' weights a layer, 2.9 ms of the cell's 14.7.
+    first = (jnp.int32(bound), chunk(0))
+    if pairs.order.shape[0] == bound:
+        return first
+
+    def another(carry):
+        start, total = carry
+        return start + bound, jax.tree.map(jnp.add, total, chunk(start))
+
+    return lax.while_loop(lambda carry: carry[0] < pairs.n_held, another, first)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_ffn(bound: int, x, w1, w3, w2, share, pairs: _Sorted):
+    """``y[t] = sum_j share[j, t] * FFN_e(j, t)(x[t])`` over the held pairs,
+    in buffers of ``bound`` rows, and the buffer rows that took. Nothing is
+    kept for the backward pass but the arguments: it computes each chunk's
+    FFN again."""
+    rows_run, y = _live_chunks(
+        bound, pairs, lambda start: _chunk_forward(bound, start, x, w1, w3, w2, share, pairs)
+    )
+    return y.astype(x.dtype), rows_run
+
+
+def _held_ffn_fwd(bound, x, w1, w3, w2, share, pairs):
+    return _held_ffn(bound, x, w1, w3, w2, share, pairs), (x, w1, w3, w2, share, pairs)
+
+
+def _held_ffn_bwd(bound, saved, cotangents):
+    # What ``jax.checkpoint`` does for its recomputation: without the barrier
+    # XLA finds the first chunk's FFN in the forward pass and keeps it (2.0 GB
+    # of the cell's memory for 3 ms of its 257 ms step).
+    saved, ct_y = lax.optimization_barrier((saved, cotangents[0]))
+    x, *_, pairs = saved
+    _, (ct_x, *rest) = _live_chunks(
+        bound, pairs, lambda start: _chunk_backward(bound, start, *saved, ct_y)
+    )
+    return (ct_x.astype(x.dtype), *rest, None)
+
+
+_held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
 
 
 def dropless_moe(
@@ -338,60 +443,63 @@ def dropless_moe(
     matmuls whatever their split over the experts, so adversarial routing
     (every token to one expert) drops nothing.
 
-    The row buffers are sized for the worst case (``T * k`` rows); the grouped
-    matmuls visit only the rows the held pairs fill. The expert FFN is
-    recomputed in the backward pass (``jax.checkpoint``): its ``[T * k, F]``
-    intermediates would otherwise be kept for every layer at the worst-case
-    size, an eighth of them used.
+    The row buffers follow the pairs held. They have ``C = row_bound(T * k,
+    H, E)`` rows, twice what ``H`` of ``E`` experts expect, and the sorted
+    pairs go through them ``C`` at a time for as long as held pairs remain
+    (``_live_chunks``, from ``n_held`` on the device): one pass where the
+    routing is anywhere near even, ``T * k / C`` where every pair lands here.
+    A pass past the first costs about a fifth more than the same rows cost
+    in full-size buffers (each repeats the reads by token and the weights'
+    sums), so from three passes on this is the slower way: a router that has
+    collapsed onto the experts held here, not one that is learning.
+    A rank that holds every expert has ``C = T * k``, one pass and no loop.
+    The expert FFN is recomputed in the backward pass (``_held_ffn``): its
+    ``[C, F]`` intermediates would otherwise be kept for every layer.
 
     Returns ``(y [T, D], counters, selected [T, k])``: ``moe_pairs_held``
     (pairs computed here), ``moe_pairs_absent`` (pairs routed to experts not
-    held), ``moe_load_max`` (largest per-expert count), int32 scalars; and the
+    held), ``moe_load_max`` (largest per-expert count), ``moe_rows_computed``
+    (buffer rows the passes ran over: ``C`` a pass), int32 scalars; and the
     ids every token selected, for whoever compares routings.
     """
     from mpi_pytorch_tpu.obs import trace as obs_trace
 
     t, d = x.shape
-    held = w1.shape[0]
+    held, routed = w1.shape[0], gate.shape[1]
+    bound = row_bound(t * top_k, held, routed)
+    chunks = -(-t * top_k // bound)
     obs_trace.current().instant(
         "moe/dispatch",
-        {"experts": int(gate.shape[1]), "held": int(held), "top_k": top_k,
-         "tokens": int(t), "path": "ragged_dot"},
+        {"experts": int(routed), "held": int(held), "top_k": top_k,
+         "tokens": int(t), "path": "ragged_dot", "rows_bound": bound},
         once=True,
     )
     with jax.named_scope("moe/route"):
         sel, weight = sigmoid_topk_route(x, gate, expert_bias, top_k, scaling)
     with jax.named_scope("moe/dispatch"):
-        # [k*T] pair -> held index; pair p = choice p // T of token p % T
+        # [k*T] pair -> held index
         local = sel.T.reshape(-1) - expert_offset
         here = (local >= 0) & (local < held)
         key = jnp.where(here, local, held)  # absent pairs sort last
-        perm = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inv_perm = jnp.zeros_like(perm).at[perm].set(jnp.arange(t * top_k, dtype=jnp.int32))
-        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        # A second sort inverts the first in 0.05 ms at 65 536 pairs; a scatter
+        # takes 0.30, and a bincount's scatter-add 0.57 for a compare and sum's 0.01.
+        place = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0, dtype=jnp.int32)
         n_held = jnp.sum(group_sizes)
-        filled = (jnp.arange(t * top_k) < n_held)[:, None]  # rows a held pair fills
-
-    @jax.checkpoint
-    def experts(x, w1, w3, w2):
-        with jax.named_scope("moe/dispatch"):
-            rows = jnp.where(filled, _pair_rows(x, perm, inv_perm, top_k), 0)
-        with jax.named_scope("moe/experts"):
-            hidden = jax.nn.silu(grouped_matmul(rows, w1, group_sizes)) * grouped_matmul(
-                rows, w3, group_sizes
-            )
-            out = grouped_matmul(hidden, w2, group_sizes)
-        with jax.named_scope("moe/combine"):
-            # Rows no group covers hold whatever the buffer held.
-            return _unsort(jnp.where(filled, out, 0), perm, inv_perm)
-
-    out = experts(x, w1.astype(x.dtype), w3.astype(x.dtype), w2.astype(x.dtype))
+        pairs = _Sorted(
+            jnp.pad(order, (0, chunks * bound - t * top_k)), place.reshape(top_k, t),
+            jnp.cumsum(group_sizes), n_held,
+        )
     with jax.named_scope("moe/combine"):
         share = jnp.where(here.reshape(top_k, t), weight.T, 0.0).astype(jnp.float32)
-        y = jnp.sum(out.reshape(top_k, t, d) * share[..., None], axis=0)
+    y, rows_run = _held_ffn(
+        bound, x, w1.astype(x.dtype), w3.astype(x.dtype), w2.astype(x.dtype), share, pairs
+    )
     counters = {
-        "moe_pairs_held": n_held.astype(jnp.int32),
-        "moe_pairs_absent": (t * top_k - n_held).astype(jnp.int32),
-        "moe_load_max": jnp.max(group_sizes).astype(jnp.int32),
+        "moe_pairs_held": n_held,
+        "moe_pairs_absent": t * top_k - n_held,
+        "moe_load_max": jnp.max(group_sizes),
+        "moe_rows_computed": rows_run,
     }
-    return y.astype(x.dtype), counters, sel
+    return y, {name: value.astype(jnp.int32) for name, value in counters.items()}, sel

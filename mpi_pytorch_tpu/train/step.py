@@ -161,7 +161,7 @@ def _step_metrics(loss, logits, labels, grads, counters=None) -> dict:
     """The step's own numbers, in every compiler-partitioned step flavor.
     ``counters``: what the model counted in this apply (``_loss_and_updates``;
     the expert layers' ``moe_pairs_held`` / ``moe_pairs_absent`` /
-    ``moe_load_max``), under its own names; a token batch (labels ``[B, S]``)
+    ``moe_load_max`` / ``moe_rows_computed``), under its own names; a token batch (labels ``[B, S]``)
     also reports ``tokens``, its valid positions — ``count`` stays samples.
     grad_norm: the global (all-parameter) L2 norm — the training-health
     signal the obs layer records per step (obs/health.py). A scalar

@@ -12,7 +12,7 @@ import pytest
 
 from benchmark.reference import lfm2_moe as ref
 from mpi_pytorch_tpu.models.lfm2 import Block, Lfm2Config, ShortConv, lfm2_moe
-from mpi_pytorch_tpu.ops.moe import dropless_moe, sigmoid_topk_route
+from mpi_pytorch_tpu.ops.moe import dropless_moe, row_bound, sigmoid_topk_route
 
 TINY = {
     "hidden_size": 64, "intermediate_size": 160, "moe_intermediate_size": 48,
@@ -150,6 +150,62 @@ def test_dropless_under_adversarial_routing(top_k, favoured):
     assert _rel(y, ref.moe(x, p, top_k=top_k)) < 1e-5
 
 
+def _routing_with_held_pairs(n, tokens, top_k, held, routed, d=24):
+    """Tokens and a router under which exactly ``n`` of the ``tokens * top_k``
+    pairs land on the first ``held`` experts: the first ``n // top_k`` tokens
+    send every choice there, one more the remainder, the others none. The
+    first ``top_k + 1`` features say which kind a token is and the router's
+    rows for them set its choices 3 against -3; the other features and router
+    rows are noise around that."""
+    rng = np.random.default_rng(n)
+    to_held = np.zeros(tokens, int)
+    to_held[: n // top_k] = top_k
+    if n % top_k:
+        to_held[n // top_k] = n % top_k
+    x = rng.normal(size=(tokens, d)).astype(np.float32) * 0.5
+    x[:, : top_k + 1] = 0
+    x[np.arange(tokens), to_held] = 1.0
+    gate = rng.normal(size=(d, routed)).astype(np.float32) * 0.1
+    for kind in range(top_k + 1):
+        gate[kind] = -3.0 + rng.normal(size=routed) * 0.1
+        gate[kind, :kind] += 6.0
+        gate[kind, held : held + top_k - kind] += 6.0
+    return jnp.asarray(x), jnp.asarray(gate)
+
+
+@pytest.mark.parametrize("top_k,tokens", [(1, 512), (4, 128)])
+@pytest.mark.parametrize("fill", ["below", "full", "one_over", "every_pair", "none"])
+def test_row_buffers_follow_the_pairs_held(fill, top_k, tokens):
+    """4 of 32 experts held, so the row buffers have C = 128 of the 512
+    pairs' rows: whatever share of the pairs lands here — under C, C, C + 1,
+    all, none — the output and every gradient are the reference's, the pairs
+    are conserved, and the passes ran over as many rows as that share demands
+    (one pass of C at the least)."""
+    held, routed, pairs = 4, 32, tokens * top_k
+    bound = row_bound(pairs, held, routed)
+    assert bound == 128 < pairs
+    n = {"below": bound - 7, "full": bound, "one_over": bound + 1, "every_pair": pairs, "none": 0}[fill]
+    x, gate = _routing_with_held_pairs(n, tokens, top_k, held, routed)
+    p = dict(_share(_moe_params(10, d=x.shape[1], experts=routed), 0, held), gate=gate)
+    mix = jax.random.normal(jax.random.PRNGKey(11), x.shape, jnp.float32)
+
+    def mine(x, p):
+        y, counters, _ = dropless_moe(
+            x, p["gate"], p["expert_bias"], p["w1"], p["w3"], p["w2"], top_k=top_k
+        )
+        return jnp.sum(y * mix), (y, counters)
+
+    (_, (y, c)), got = jax.value_and_grad(mine, argnums=(0, 1), has_aux=True)(x, p)
+    want_y = ref.moe(x, p, top_k=top_k)
+    want = jax.grad(lambda x, p: jnp.sum(ref.moe(x, p, top_k=top_k) * mix), argnums=(0, 1))(x, p)
+    assert (int(c["moe_pairs_held"]), int(c["moe_pairs_absent"])) == (n, pairs - n)
+    assert int(c["moe_rows_computed"]) == bound * max(1, -(-n // bound))
+    pairs_of = {"y": (y, want_y), "x": (got[0], want[0])}
+    pairs_of.update({k: (got[1][k], want[1][k]) for k in ("gate", "w1", "w3", "w2")})
+    for name, (g, w) in pairs_of.items():
+        assert float(jnp.max(jnp.abs(g - w))) < 2e-5 * (float(jnp.max(jnp.abs(w))) + 1.0), name
+
+
 def test_pairs_routed_to_absent_experts_are_counted_and_left_out():
     d, tokens, top_k = 16, 32, 4
     p = _share(_moe_params(8, d=d, experts=16), 4, 8)
@@ -280,11 +336,16 @@ def test_trainer_main_trains_tokens_from_the_device_cache_in_scanned_epochs(tmp_
         assert round(rec["images_per_sec"] * rec["time_s"]) == 16  # samples are sequences
         assert rec["moe_pairs_held"] + rec["moe_pairs_absent"] == rec["tokens"] * 4 * moe_layers
         assert 0 < rec["moe_load_max"] <= 8 * 64
+        # 4 of 16 held: buffers of half the pairs' rows, one pass or two a layer a step
+        passes, rest = divmod(rec["moe_rows_computed"], 2 * 8 * 64)
+        assert rest == 0 and 2 * moe_layers <= passes <= 4 * moe_layers
+        assert rec["moe_rows_computed"] >= rec["moe_pairs_held"]
     with open(tmp_path / "spans.json") as f:
         instants = [e for e in json.load(f)["traceEvents"] if e["name"] == "moe/dispatch"]
     # One a distinct shape: init's dummy sequence, then the step's batch.
     assert [e["args"] for e in instants] == [
-        {"experts": 16, "held": 4, "top_k": 4, "tokens": tokens, "path": "ragged_dot"}
+        {"experts": 16, "held": 4, "top_k": 4, "tokens": tokens, "path": "ragged_dot",
+         "rows_bound": 2 * tokens}
         for tokens in (64, 8 * 64)
     ]
 

@@ -192,3 +192,32 @@ def test_flash_kernels_compile_for_v5e_at_the_benchmark_cells_shape(one_v5e):
 
     compiled = jax.jit(pair).lower(q, kv, kv, q).compile()
     assert mosaic_call_count(compiled) == 3
+
+
+def test_expert_layer_compiles_for_v5e_at_the_benchmark_cells_shape(one_v5e):
+    """``dropless_moe`` and its gradients at ``lfm2_train_hbm_8k``'s expert
+    layer (16 384 tokens of 2 048, 8 of 64 experts of 1 536 held, top-4,
+    bf16): row buffers of C = 16 384 rows, the first chunk of the sorted pairs
+    inline and a loop on the device over the others, forward and backward,
+    with the grouped matmuls inside both. No buffer of the FFN's width has
+    all 65 536 pairs' rows. Nothing runs; no time comes out of this."""
+    import jax.numpy as jnp
+
+    from mpi_pytorch_tpu.ops.moe import dropless_moe, row_bound
+
+    tokens, d, f, held, routed, top_k = 16_384, 2_048, 1_536, 8, 64, 4
+    assert row_bound(tokens * top_k, held, routed) == 16_384
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+
+    def loss(x, gate, bias, w1, w3, w2):
+        y, _, _ = dropless_moe(x, gate, bias, w1, w3, w2, top_k=top_k)
+        return jnp.sum(y.astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        shape(tokens, d, dtype=jnp.bfloat16), shape(d, routed), shape(routed),
+        shape(held, d, f), shape(held, d, f), shape(held, f, d),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 2  # the chunks past the first, forward and backward
+    assert f"[{tokens * top_k},{f}]" not in text
+    assert f"[{tokens},{f}]" in text
