@@ -55,6 +55,15 @@ FLASH_BLOCK = 512
 INIT_STD = 0.02  # the source's ``initializer_range``
 
 
+def model_config_json(text: str) -> dict:
+    """``--model-config`` as a dict: a JSON object, or the path of a file that
+    holds one (what every configured token model's ``parse`` starts from)."""
+    if not text.lstrip().startswith("{"):
+        with open(text) as f:
+            text = f.read()
+    return json.loads(text)
+
+
 @dataclasses.dataclass(frozen=True)
 class Lfm2Config:
     """The source's ``config.json`` keys this module reads (defaults:
@@ -89,10 +98,7 @@ class Lfm2Config:
         cannot honour is an error that names it."""
         if not text:
             return cls()
-        if not text.lstrip().startswith("{"):
-            with open(text) as f:
-                text = f.read()
-        raw = json.loads(text)
+        raw = model_config_json(text)
         for key, want in (("conv_bias", False), ("norm_topk_prob", True), ("use_expert_bias", True)):
             if raw.get(key, want) != want:
                 raise ValueError(f"model-config: {key}={raw[key]!r} is not implemented (only {want})")
@@ -179,6 +185,26 @@ def rope(x, theta: float):
     return x.astype(jnp.float32) * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
 
+def causal_attention(module: nn.Module, q, k, v, attn_impl: str, scale: float | None = None):
+    """Causal grouped-query attention of ``q [B, S, H, Dh]`` over ``k``, ``v``
+    ``[B, S, Hkv, Dh]`` by ``attn_impl`` (``flash``: the Pallas kernels;
+    ``full``: XLA's materialized scores); ``scale`` None is ``Dh ** -0.5``."""
+    from mpi_pytorch_tpu.ops.flash_attention import flash_attention
+    from mpi_pytorch_tpu.ops.ring_attention import full_attention
+
+    # init traces one short dummy sequence for the parameters' shapes:
+    # XLA's composition will do, whatever the backend.
+    impl = "full" if module.is_initializing() else attn_impl
+    if impl == "flash":
+        block = min(FLASH_BLOCK, q.shape[1])
+        return flash_attention(q, k, v, causal=True, block_q=block, block_k=block, scale=scale)
+    if impl == "full":
+        # XLA's materialized scores have one head layout: k and v repeat.
+        k, v = (jnp.repeat(t, q.shape[2] // k.shape[2], axis=2) for t in (k, v))
+        return full_attention(q, k, v, causal=True, scale=scale)
+    raise ValueError(f"attn_impl {attn_impl!r} is not implemented for a token model (full|flash)")
+
+
 class Attention(nn.Module):
     cfg: Lfm2Config
     attn_impl: str = "full"
@@ -187,9 +213,6 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from mpi_pytorch_tpu.ops.flash_attention import flash_attention
-        from mpi_pytorch_tpu.ops.ring_attention import full_attention
-
         cfg = self.cfg
         d, h, hkv = cfg.hidden_size, cfg.num_attention_heads, cfg.num_key_value_heads
         dh = d // h
@@ -204,18 +227,7 @@ class Attention(nn.Module):
         with jax.named_scope("attention"):
             q = rope(rms_norm(q, q_scale, cfg.norm_eps), cfg.rope_theta).astype(self.dtype)
             k = rope(rms_norm(k, k_scale, cfg.norm_eps), cfg.rope_theta).astype(self.dtype)
-            # init traces one short dummy sequence for the parameters' shapes:
-            # XLA's composition will do, whatever the backend.
-            impl = "full" if self.is_initializing() else self.attn_impl
-            if impl == "flash":
-                block = min(FLASH_BLOCK, x.shape[1])
-                out = flash_attention(q, k, v, causal=True, block_q=block, block_k=block)
-            elif impl == "full":
-                # XLA's materialized scores have one head layout: k and v repeat.
-                k, v = (jnp.repeat(t, h // hkv, axis=2) for t in (k, v))
-                out = full_attention(q, k, v, causal=True)
-            else:
-                raise ValueError(f"lfm2: attn_impl {self.attn_impl!r} is not implemented (full|flash)")
+            out = causal_attention(self, q, k, v, self.attn_impl)
         return jnp.einsum("bshk,hkd->bsd", out, wo.astype(self.dtype))
 
 

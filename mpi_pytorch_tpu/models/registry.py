@@ -27,6 +27,7 @@ from mpi_pytorch_tpu.models.alexnet import alexnet
 from mpi_pytorch_tpu.models.common import head_filter
 from mpi_pytorch_tpu.models.densenet import densenet121
 from mpi_pytorch_tpu.models.efficientnet import efficientnet_b0
+from mpi_pytorch_tpu.models.granite_hybrid import granite_vocab, granitemoehybrid
 from mpi_pytorch_tpu.models.inception import inception_v3
 from mpi_pytorch_tpu.models.lfm2 import lfm2_moe, lfm2_vocab
 from mpi_pytorch_tpu.models.mobilenet import mobilenet_v2
@@ -124,7 +125,7 @@ def _vit(*more: str) -> dict:
 
 
 # The vit_* family is beyond reference parity (the reference has no
-# attention); lfm2_moe is the token model.
+# attention); lfm2_moe and granitemoehybrid are the two token models.
 _REGISTRY: dict[str, ModelSpec] = {
     "resnet18": ModelSpec(resnet18, 224, **_RESNET),
     "resnet34": ModelSpec(resnet34, 128, **_RESNET),
@@ -144,6 +145,11 @@ _REGISTRY: dict[str, ModelSpec] = {
         lfm2_moe, 128, flags=frozenset({"remat_blocks", "model_config"}),
         # causal, grouped heads: the single-pass kernel has no such form
         attn_impls=("full", "flash"), sample="tokens", vocab=lfm2_vocab,
+        batchnorm=False,
+    ),
+    "granitemoehybrid": ModelSpec(
+        granitemoehybrid, 256, flags=frozenset({"remat_blocks", "model_config"}),
+        attn_impls=("full", "flash"), sample="tokens", vocab=granite_vocab,
         batchnorm=False,
     ),
 }

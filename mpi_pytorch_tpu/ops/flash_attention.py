@@ -42,6 +42,10 @@ Design notes:
   is skipped) nor fetched (its index clamps to a block the pipeline already
   holds: the last k block a q block needs, forward and dq; the first q block
   a k block reaches, dk/dv).
+- The softmax scale defaults to ``D ** -0.5`` (the ViT family and
+  ``lfm2_moe`` pass none); a model whose scale is its own constant passes
+  ``scale=`` (``granitemoehybrid``: ``attention_multiplier``), and the forward
+  kernel and both backward kernels take it.
 - Every dot runs in the operands' dtype with float32 accumulation (bf16
   operands take the MXU's bf16 rate; float32 operands stay float32): p and
   dS are cast to it for the MXU; exp, the mask, dS and every accumulator are
@@ -150,12 +154,12 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths)
 
 
-def _fwd_impl(q3, k3, v3, *, causal, block_q, block_k, interpret):
+def _fwd_impl(q3, k3, v3, *, causal, block_q, block_k, interpret, scale=None):
     """[BH, S, D] flash forward → (out [BH, S, D], lse [BH, S_pad]); ``k3``
     and ``v3`` are [BHkv, S, D], row ``b // (BH / BHkv)`` serving q row b."""
     bh, s, d = q3.shape
     group = bh // k3.shape[0]
-    scale = d**-0.5
+    scale = d**-0.5 if scale is None else scale
     qp = _pad_to(q3, 1, block_q)
     kp = _pad_to(k3, 1, block_k)
     vp = _pad_to(v3, 1, block_k)
@@ -329,7 +333,7 @@ def _bwd_blocks(s: int) -> tuple[int, int]:
     return min(BWD_BLOCKS[0], whole), min(BWD_BLOCKS[1], whole)
 
 
-def _bwd_impl(q3, k3, v3, out, lse, do, *, causal, interpret):
+def _bwd_impl(q3, k3, v3, out, lse, do, *, causal, interpret, scale=None):
     """dq, dk, dv from the forward's residuals: two Pallas kernels under one
     scope, probabilities recomputed from the saved logsumexp in VMEM, blocks
     wholly above the causal diagonal neither computed nor fetched. ``k3`` /
@@ -339,7 +343,7 @@ def _bwd_impl(q3, k3, v3, out, lse, do, *, causal, interpret):
     bh, s, d = q3.shape
     bkv = k3.shape[0]
     group = bh // bkv
-    scale = d**-0.5
+    scale = d**-0.5 if scale is None else scale
     # D_i = Σ_d dOut · Out — the softmax-jacobian diagonal term.
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     lse = lse[:, :s]
@@ -405,27 +409,27 @@ def _bwd_impl(q3, k3, v3, out, lse, do, *, causal, interpret):
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
 )
-def _flash3(q3, k3, v3, causal, block_q, block_k, interpret):
+def _flash3(q3, k3, v3, causal, block_q, block_k, interpret, scale):
     out, _ = _fwd_impl(
         q3, k3, v3, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, scale=scale,
     )
     return out
 
 
-def _flash3_fwd(q3, k3, v3, causal, block_q, block_k, interpret):
+def _flash3_fwd(q3, k3, v3, causal, block_q, block_k, interpret, scale):
     out, lse = _fwd_impl(
         q3, k3, v3, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, scale=scale,
     )
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash3_bwd(causal, block_q, block_k, interpret, residuals, do):
+def _flash3_bwd(causal, block_q, block_k, interpret, scale, residuals, do):
     q3, k3, v3, out, lse = residuals
-    return _bwd_impl(q3, k3, v3, out, lse, do, causal=causal, interpret=interpret)
+    return _bwd_impl(q3, k3, v3, out, lse, do, causal=causal, interpret=interpret, scale=scale)
 
 
 _flash3.defvjp(_flash3_fwd, _flash3_bwd)
@@ -434,12 +438,14 @@ _flash3.defvjp(_flash3_fwd, _flash3_bwd)
 def flash_attention(
     q, k, v, *, causal: bool = False,
     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool | None = None,
+    interpret: bool | None = None, scale: float | None = None,
 ) -> jnp.ndarray:
     """Flash attention over [B, S, H, D] inputs (the repo layout); ``k`` and
     ``v`` may carry fewer heads, [B, S, Hkv, D] with ``H % Hkv == 0``: query
     head h then reads key-value head ``h // (H / Hkv)`` (grouped-query
     attention), by index and without a repeated copy.
+
+    ``scale`` multiplies ``q kᵀ`` before the softmax; None is ``D ** -0.5``.
 
     ``interpret``: None = Pallas on TPU, ``full_attention`` fallback
     elsewhere (or the Pallas interpreter when ``MPT_FLASH_INTERPRET`` is
@@ -456,7 +462,7 @@ def flash_attention(
             group = q.shape[2] // k.shape[2]
             if group > 1:  # the XLA composition has one head layout
                 k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-            return full_attention(q, k, v, causal=causal)
+            return full_attention(q, k, v, causal=causal, scale=scale)
         else:
             interpret = False
 
@@ -472,5 +478,5 @@ def flash_attention(
     def to3(x):
         return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], d)
 
-    out3 = _flash3(to3(q), to3(k), to3(v), causal, bq, bk, interpret)
+    out3 = _flash3(to3(q), to3(k), to3(v), causal, bq, bk, interpret, scale)
     return out3.reshape(b, h, s, d).transpose(0, 2, 1, 3)

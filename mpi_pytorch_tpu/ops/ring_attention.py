@@ -30,9 +30,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 from mpi_pytorch_tpu.parallel.compat import shard_map
 
 
-def full_attention(q, k, v, *, causal: bool = False) -> jnp.ndarray:
-    """Single-device reference attention ([B,S,H,D], f32 accumulation)."""
-    scale = q.shape[-1] ** -0.5
+def full_attention(q, k, v, *, causal: bool = False, scale: float | None = None) -> jnp.ndarray:
+    """Single-device reference attention ([B,S,H,D], f32 accumulation);
+    ``scale`` multiplies the scores, None is ``D ** -0.5``."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale, k.astype(jnp.float32)
     )

@@ -32,23 +32,32 @@ TINY_LFM2 = json.dumps({
     "num_experts": 4, "num_experts_per_tok": 2, "vocab_size": 128,
 })
 
+TINY_GRANITE = json.dumps({
+    "hidden_size": 64, "shared_intermediate_size": 96, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "layer_types": ["mamba", "attention"], "mamba_n_heads": 8,
+    "mamba_d_head": 16, "mamba_d_state": 16, "mamba_chunk_size": 16, "vocab_size": 128,
+})
+# A configured model's own tiny ``--model-config`` (each reads its source's keys).
+TINY_CONFIGS = {"lfm2_moe": TINY_LFM2, "granitemoehybrid": TINY_GRANITE}
+
 # The (flag, value) -> models table of the parent commit's eleven name lists
 # (ATTN_IMPL_MODELS, SP_MODELS, MOE_MODELS, REMAT_BLOCKS_MODELS, S2D_MODELS,
 # FUSED_STEM_MODELS, CONFIGURED_MODELS, PP_MODELS and the token model's
 # fused-small refusal): what is accepted; every other pair is refused.
 _VITS = {"vit_s16", "vit_b16", "vit_moe_s16"}
 ACCEPTED = {
-    ("attn_impl", "flash"): _VITS | {"lfm2_moe"},
+    ("attn_impl", "flash"): _VITS | {"lfm2_moe", "granitemoehybrid"},
     ("attn_impl", "fused-small"): _VITS,
     ("sp_strategy", "ring"): _VITS,
     ("qkv_fused", True): _VITS,
     ("ep_mesh", "mesh"): {"vit_moe_s16"},
     ("remat_blocks", True): {
         "resnet18", "resnet34", "densenet121", "vit_s16", "vit_b16", "lfm2_moe",
+        "granitemoehybrid",
     },
     ("stem_s2d", True): {"resnet18", "resnet34"},
     ("fused_stem", True): {"resnet18", "resnet34", "densenet121"},
-    ("model_config", TINY_LFM2): {"lfm2_moe"},
+    ("model_config", TINY_LFM2): set(TINY_CONFIGS),
     ("pp_stages", 2): {"vit_s16", "vit_b16"},
 }
 
@@ -73,10 +82,12 @@ def _init_shapes(name, model):
 @pytest.mark.parametrize("name", available_models())
 def test_accepted_flags_are_what_the_module_takes_and_the_rest_is_refused(name):
     spec = model_spec(name)
-    base = {"model_config": TINY_LFM2} if "model_config" in spec.flags else {}
+    base = {"model_config": TINY_CONFIGS[name]} if "model_config" in spec.flags else {}
     for (flag, value), takers in ACCEPTED.items():
         if flag == "ep_mesh":
             value = _mesh("expert")
+        if flag == "model_config":
+            value = TINY_CONFIGS.get(name, value)
         assert spec.accepts(flag, value) == (name in takers), (name, flag)
         if name not in takers:
             with pytest.raises(ValueError, match=f"{flag}.* does not apply to model '{name}'"):

@@ -221,3 +221,31 @@ def test_expert_layer_compiles_for_v5e_at_the_benchmark_cells_shape(one_v5e):
     assert text.count(" while(") == 2  # the chunks past the first, forward and backward
     assert f"[{tokens * top_k},{f}]" not in text
     assert f"[{tokens},{f}]" in text
+
+
+def test_state_space_scan_compiles_for_v5e_at_the_benchmark_cells_shape(one_v5e):
+    """``ops/ssd.ssd`` and its six gradients at ``granite4h_train_hbm_8k``'s
+    state-space layer (one sequence of 8 192 positions, 64 heads of 64, one
+    group, state 128, chunks of 256, bf16 operands, float32 decays): the
+    chunked form fits a chip as XLA einsums — the ``[64, 32, 256, 256]``
+    float32 decay tensor is 537 MB — with the one loop over the 32 chunk
+    states, forward and backward, and no loop over positions. Nothing runs;
+    no time comes out of this."""
+    import jax.numpy as jnp
+
+    from mpi_pytorch_tpu.ops.ssd import ssd
+
+    s, h, p, g, n = 8_192, 64, 64, 1, 128
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+
+    def loss(x, dt, a_log, b, c, d):
+        return jnp.sum(ssd(x, dt, a_log, b, c, d, chunk=256).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))).lower(
+        shape(1, s, h, p, dtype=jnp.bfloat16), shape(1, s, h), shape(h),
+        shape(1, s, g, n, dtype=jnp.bfloat16), shape(1, s, g, n, dtype=jnp.bfloat16), shape(h),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 2  # the 32 chunk states, forward and backward
+    assert "f32[32,64,256,256]" in text  # the decays, float32, every chunk at once
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30  # 461 MB when written
