@@ -49,9 +49,6 @@ from jax import lax
 
 Dtype = Any
 
-# Flash blocks at long sequences: 512 x 512 keeps the grid at (S/512)^2 steps a
-# head (a step costs ~0.35 us whatever it holds) with a 1 MiB score tile.
-FLASH_BLOCK = 512
 INIT_STD = 0.02  # the source's ``initializer_range``
 
 
@@ -196,8 +193,8 @@ def causal_attention(module: nn.Module, q, k, v, attn_impl: str, scale: float | 
     # XLA's composition will do, whatever the backend.
     impl = "full" if module.is_initializing() else attn_impl
     if impl == "flash":
-        block = min(FLASH_BLOCK, q.shape[1])
-        return flash_attention(q, k, v, causal=True, block_q=block, block_k=block, scale=scale)
+        # The kernel sizes its own tiles from the shape it sees (ops/flash_attention.py).
+        return flash_attention(q, k, v, causal=True, scale=scale)
     if impl == "full":
         # XLA's materialized scores have one head layout: k and v repeat.
         k, v = (jnp.repeat(t, q.shape[2] // k.shape[2], axis=2) for t in (k, v))

@@ -15,10 +15,31 @@ the one ``ring_attention`` uses across shards, applied across k-blocks.
 Design notes:
 - Layout [B, S, H, D] (the repo's attention convention), internally
   [B·H, S, D]; f32 accumulation regardless of input dtype.
-- Forward is the Pallas kernel: grid (B·H, S/BQ, S/BK), k innermost; the
+- Forward is the Pallas kernel: grid (B·Hkv, S/BQ, S/BK), k innermost.
+  One step holds a [G·BQ, BK] tile: the BQ-row query blocks of the G query
+  heads that share a key-value head, stacked along the rows against the one
+  key block they all read (the tile is tall without the causal diagonal's
+  waste growing with it, and K and V are fetched once a group). The
   (m, l, acc) state lives in VMEM scratch and persists across the k
   iterations (TPU grids iterate sequentially); the last k block finalizes
   ``acc / l`` and also writes the logsumexp per row.
+- The online softmax's state is lane-dense: ``m`` [rows, 128] holds the
+  row's running max in every lane, ``l`` [rows, 128] holds in lane j the
+  running sum over the keys j mod 128. A step then costs a row ONE
+  cross-lane reduction (the max); subtracting the max, rescaling ``l`` and
+  ``acc`` are plain vreg-by-vreg operations with no column broadcast, and
+  the 128 partial sums meet once a query block, when it finalizes. With
+  [rows, 1] columns (two reductions and four broadcasts a row and step) the
+  kernel's time went as 1 / BK and the elementwise work hardly showed
+  (PERF.md section 6, PR 32).
+- Every computed tile carries the per-element mask. Leaving it out of the
+  tiles the diagonal and the padded end do not cross gives the same bits
+  and, at this tile, no time: the tile is MXU-bound at Dh = 64 and the mask
+  rides in spare VPU slots (PERF.md section 6, PR 32).
+- The forward's tile is this module's constant ``FWD_TILE``, from a sweep on
+  the chip, fitted to the head's width and clipped to the sequence
+  (``_fwd_blocks``); no caller chooses it. The trace file's
+  ``flash/dispatch`` instant says what a shape got.
 - Backward is two more Pallas kernels under one scope
   (``kernel/flash_attn_bwd``), from the forward's residuals (q, k, v, out,
   logsumexp) and ``delta = rowsum(dOut · out)``: each (q block, k block)
@@ -28,20 +49,22 @@ Design notes:
   query head of the key-value group, accumulating dk and dv in float32
   scratch and writing each once; its tiles are [BK, BQ], so the per-query
   ``lse`` and ``delta`` are [1, BQ] rows of narrow [B·H, 1, S] arrays.
-  ``flash_attn_bwd_dq`` runs on the forward's grid (key blocks innermost,
-  dq in float32 scratch) with [BQ, BK] tiles, the two rows turned into
-  columns once a query block. Each kernel recomputes the scores: seven
+  ``flash_attn_bwd_dq`` runs on a grid (B·H, S/BQ, S/BK), key blocks
+  innermost, dq in float32 scratch, with [BQ, BK] tiles, the two rows turned
+  into columns once a query block. Each kernel recomputes the scores: seven
   matmuls a tile pair for the algorithm's five. Their block sizes are this
-  module's constants, chosen on the chip whatever the forward's.
+  module's constant ``BWD_BLOCKS``, from a sweep of their own.
 - Grouped key-value heads (``k``/``v`` with fewer heads than ``q``): query
   head h reads key-value head ``h // (H / Hkv)`` through the kernel's index
   map — k and v are never repeated in HBM; the dk/dv kernel streams a
   group's query heads past one key block, so dk and dv come out summed over
   the group.
-- Causal: a tile wholly above the diagonal is neither computed (the body
-  is skipped) nor fetched (its index clamps to a block the pipeline already
-  holds: the last k block a q block needs, forward and dq; the first q block
-  a k block reaches, dk/dv).
+- Causal, all three kernels: a tile wholly above the diagonal is neither
+  computed (the body is skipped) nor fetched (its index clamps to a block
+  the pipeline already holds: the last k block a q block needs, forward and
+  dq; the first q block a k block reaches, dk/dv). Every tile they compute
+  carries the per-element mask (``_mask_as_forward``: padded keys and,
+  causal, keys after the query).
 - The softmax scale defaults to ``D ** -0.5`` (the ViT family and
   ``lfm2_moe`` pass none); a model whose scale is its own constant passes
   ``scale=`` (``granitemoehybrid``: ``attention_multiplier``), and the forward
@@ -66,6 +89,7 @@ because it is numerically the same function.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -75,8 +99,17 @@ from jax.experimental import pallas as pl
 from mpi_pytorch_tpu.ops.kernel_call import kernel_call
 
 _NEG = -1e30  # finite mask value: keeps the online-softmax recurrence NaN-free
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
+# The forward's tile, (query rows, keys): the rows are shared among the query
+# heads of a key-value group, whose blocks one grid step stacks. The fastest of
+# a sweep over 512..4096 rows (one head's, or a group of four's) by 256..2048
+# keys on a v5e at S=8192, Dh=64, bf16, causal (PERF.md section 6, PR 32): the
+# [2048, 512] float32 score tile and its probabilities fit the 16 MiB of VMEM
+# a kernel gets; 4096 rows read 1 % faster and do not.
+FWD_TILE = (2048, 512)
+# The bytes a row of q may have (Dh x itemsize) for FWD_TILE's rows to fit
+# those 16 MiB, by compiling for a v5e: Dh=128 in float32 and Dh=256 in bf16
+# fit at 2048 rows, Dh=256 in float32 does not and fits at 1024.
+_ROW_BYTES = 512
 # The backward kernels' (query, key) block sizes, whatever the forward's: the
 # fastest of a sweep over 128..2048 a side on a v5e at S=8192, Dh=64, for the
 # dk/dv kernel and for the dq kernel alike (PERF.md section 6, PR 28); three
@@ -85,12 +118,31 @@ DEFAULT_BLOCK_K = 128
 BWD_BLOCKS = (1024, 1024)
 
 
+def _mask_as_forward(scores, *, causal, seq_len, q0, k0, q_axis, block_q=None):
+    """``scores`` at -1e30 wherever the forward masks: padded keys and,
+    causal, keys after the query. ``q_axis`` is the tile's query axis (0 in a
+    [BQ, BK] tile, 1 in a [BK, BQ] one). ``block_q``: the query axis stacks
+    several heads' blocks of that many rows, each starting at ``q0``."""
+    k_pos = k0 + lax.broadcasted_iota(jnp.int32, scores.shape, 1 - q_axis)
+    valid = k_pos < seq_len
+    if causal:
+        row = lax.broadcasted_iota(jnp.int32, scores.shape, q_axis)
+        if block_q is not None and block_q != scores.shape[q_axis]:
+            row = row % block_q
+        valid = valid & (k_pos <= q0 + row)
+    return jnp.where(valid, scores, _NEG)
+
+
 def _attn_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     *, scale: float, causal: bool, seq_len: int, block_q: int, block_k: int,
     n_k: int,
 ):
+    """One [heads * BQ, BK] tile: the ``heads`` query heads of the step share
+    the key block, their query blocks stacked along the rows."""
     iq, ik = pl.program_id(1), pl.program_id(2)
+    heads, _, d = q_ref.shape[1:]
+    rows = heads * block_q
 
     @pl.when(ik == 0)
     def _init():
@@ -100,48 +152,59 @@ def _attn_fwd_kernel(
 
     def _block():
         scores = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            q_ref[0].reshape(rows, d), k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [BQ, BK]
+        ) * scale  # [rows, BK]
+        scores = _mask_as_forward(
+            scores, causal=causal, seq_len=seq_len,
+            q0=iq * block_q, k0=ik * block_k, q_axis=0, block_q=block_q,
+        )
 
-        k_pos = ik * block_k + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        valid = k_pos < seq_len  # padded keys contribute nothing
-        if causal:
-            q_pos = iq * block_q + lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-            valid = valid & (k_pos <= q_pos)
-        scores = jnp.where(valid, scores, _NEG)
-
-        m_prev = m_scr[:, :1]  # [BQ, 1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(scores, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        # Running max and sum ride ``w`` lanes: every lane of ``m`` holds the
+        # row's max, lane j of ``l`` the sum over the keys j mod w — so a step
+        # costs a row ONE cross-lane reduction (the max) and no broadcast of
+        # a column; the sum's lanes meet once a query block, in ``_finalize``.
+        w = m_scr.shape[1]
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)  # masked entries: exp(_NEG - m) == 0
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        p = jnp.exp(scores - _lanes(m_new, block_k))  # masked entries: exp(_NEG - m) == 0
+        l_scr[:] = alpha * l_scr[:] + sum(
+            p[:, c * w:(c + 1) * w] for c in range(block_k // w)
+        )
+        acc_scr[:] = acc_scr[:] * _lanes(alpha, d) + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[:] = m_new
 
     if causal:
-        # Blocks wholly above the diagonal hold no valid key for this q block.
+        # Key blocks wholly after the query block: none of their keys is seen.
         pl.when(ik * block_k <= iq * block_q + block_q - 1)(_block)
     else:
         _block()
 
     @pl.when(ik == n_k - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = jnp.sum(l_scr[:], axis=-1, keepdims=True)
         safe_l = jnp.where(l > 0, l, 1.0)  # fully-padded q rows (sliced later)
-        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype).reshape(o_ref.shape[1:])
         # lse rides a 128-wide lane dim (TPU block shapes need the minor-most
         # two dims (8, 128)-tileable or full; a [BQ] vector is neither) —
         # broadcast across lanes here, lane 0 is read back after the call.
         lse_ref[0] = jnp.broadcast_to(
-            m_scr[:, :1] + jnp.log(safe_l), lse_ref[0].shape
-        )
+            m_scr[:, :1] + jnp.log(safe_l), (rows, 128)
+        ).reshape(lse_ref.shape[1:])
+
+
+def _lanes(x, n: int):
+    """``x [rows, w]`` whose lanes are equal in every row, as ``[rows, n]``."""
+    w = x.shape[1]
+    if n <= w:
+        return x[:, :n]
+    if n % w == 0:
+        return jnp.tile(x, (1, n // w))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
 def _pad_to(x, axis, mult):
@@ -158,13 +221,16 @@ def _fwd_impl(q3, k3, v3, *, causal, block_q, block_k, interpret, scale=None):
     """[BH, S, D] flash forward → (out [BH, S, D], lse [BH, S_pad]); ``k3``
     and ``v3`` are [BHkv, S, D], row ``b // (BH / BHkv)`` serving q row b."""
     bh, s, d = q3.shape
-    group = bh // k3.shape[0]
+    bkv = k3.shape[0]
+    group = bh // bkv
     scale = d**-0.5 if scale is None else scale
     qp = _pad_to(q3, 1, block_q)
     kp = _pad_to(k3, 1, block_k)
     vp = _pad_to(v3, 1, block_k)
     sq, sk = qp.shape[1], kp.shape[1]
     n_q, n_k = sq // block_q, sk // block_k
+    qp = qp.reshape(bkv, group, sq, d)
+    lanes = math.gcd(block_k, 128)  # of the running max and sum: whole vregs at real sizes
 
     kernel = functools.partial(
         _attn_fwd_kernel, scale=scale, causal=causal, seq_len=s,
@@ -176,51 +242,42 @@ def _fwd_impl(q3, k3, v3, *, causal, block_q, block_k, interpret, scale=None):
         # The last k block a q block needs; later grid steps name it again,
         # so nothing is fetched for the blocks the body skips.
         def kv_block(b, iq, ik):
-            return (b // group, jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k), 0)
+            return (b, jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k), 0)
     else:
         def kv_block(b, iq, ik):
-            return (b // group, ik, 0)
+            return (b, ik, 0)
+
+    def q_block(b, iq, ik):
+        return (b, 0, iq, 0)
 
     out, lse = kernel_call(
         "flash_attn_fwd",
         kernel,
-        grid=(bh, n_q, n_k),
+        grid=(bkv, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, group, block_q, d), q_block),
             pl.BlockSpec((1, block_k, d), kv_block),
             pl.BlockSpec((1, block_k, d), kv_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, group, block_q, d), q_block),
+            pl.BlockSpec((1, group, block_q, 128), q_block),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 128), jnp.float32),
+            jax.ShapeDtypeStruct((bkv, group, sq, d), q3.dtype),
+            jax.ShapeDtypeStruct((bkv, group, sq, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running max m
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running sum l
-            pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((group * block_q, lanes), jnp.float32),  # running max m
+            pltpu.VMEM((group * block_q, lanes), jnp.float32),  # running sum l
+            pltpu.VMEM((group * block_q, d), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
     )(qp, kp, vp)
     # Every lane holds the row's value: a max over them reads the kernel's
     # output as it lies (``lse[:, :, 0]`` made XLA copy all of it into
     # another layout first, 0.8 ms at [64, 8192, 128]).
-    return out[:, :s], jnp.max(lse, axis=-1)
-
-
-def _mask_as_forward(scores, *, causal, seq_len, q0, k0, q_axis):
-    """``scores`` at -1e30 wherever the forward masks: padded keys and,
-    causal, keys after the query. ``q_axis`` is the tile's query axis (0 in a
-    [BQ, BK] tile, 1 in a [BK, BQ] one)."""
-    k_pos = k0 + lax.broadcasted_iota(jnp.int32, scores.shape, 1 - q_axis)
-    valid = k_pos < seq_len
-    if causal:
-        q_pos = q0 + lax.broadcasted_iota(jnp.int32, scores.shape, q_axis)
-        valid = valid & (k_pos <= q_pos)
-    return jnp.where(valid, scores, _NEG)
+    return out.reshape(bh, sq, d)[:, :s], jnp.max(lse, axis=-1).reshape(bh, sq)
 
 
 def _attn_bwd_dkv_kernel(
@@ -281,8 +338,8 @@ def _attn_bwd_dq_kernel(
     *, scale: float, causal: bool, seq_len: int, block_q: int, block_k: int,
     n_k: int,
 ):
-    """dq of one query block on the forward's grid (key blocks innermost).
-    Tiles are [BQ, BK] as in the forward, so ``dq += ds @ k`` is plain; the
+    """dq of one query block of one query head, key blocks innermost. Tiles
+    are [BQ, BK] as in the forward, so ``dq += ds @ k`` is plain; the
     [1, BQ] rows of ``lse`` and ``delta`` are turned into columns once a
     query block."""
     iq, ik = pl.program_id(1), pl.program_id(2)
@@ -435,9 +492,32 @@ def _flash3_bwd(causal, block_q, block_k, interpret, scale, residuals, do):
 _flash3.defvjp(_flash3_fwd, _flash3_bwd)
 
 
+def _fwd_blocks(s: int, group: int, row_bytes: int) -> tuple[int, int]:
+    """The forward's (query rows a head, keys) a tile for a sequence of ``s``
+    whose key-value heads each serve ``group`` query heads of ``row_bytes``
+    (Dh x itemsize) a row: the module's constant, its rows fewer as a row of q
+    and out outgrows ``_ROW_BYTES``, shared among the group in whole 16-row
+    sublane tiles (bf16 packs 16 rows a vreg), and clipped to the sequence —
+    in whole 128-lane tiles, or for a sequence inside one, in whole sublane
+    tiles (so the ViTs' S <= 196 is one block a side)."""
+    rows = FWD_TILE[0] * _ROW_BYTES // max(_ROW_BYTES, row_bytes)
+    whole = -(-s // 128) * 128 if s > 128 else -(-s // 16) * 16
+    return min(max(rows // group // 16 * 16, 16), whole), min(FWD_TILE[1], whole)
+
+
+def _tile_counts(s: int, *, causal: bool, block_q: int, block_k: int) -> dict:
+    """How many of a head's tiles the forward skips and computes."""
+    n_q, n_k = -(-s // block_q), -(-s // block_k)
+    computed = sum(
+        min(n_k, (iq * block_q + block_q - 1) // block_k + 1) if causal else n_k
+        for iq in range(n_q)
+    )
+    return {"tiles_skipped": n_q * n_k - computed, "tiles_computed": computed}
+
+
 def flash_attention(
     q, k, v, *, causal: bool = False,
-    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+    block_q: int | None = None, block_k: int | None = None,
     interpret: bool | None = None, scale: float | None = None,
 ) -> jnp.ndarray:
     """Flash attention over [B, S, H, D] inputs (the repo layout); ``k`` and
@@ -447,19 +527,24 @@ def flash_attention(
 
     ``scale`` multiplies ``q kᵀ`` before the softmax; None is ``D ** -0.5``.
 
+    ``block_q`` / ``block_k``: the forward's tile, for a test that wants
+    several blocks a side of a short sequence; None is what the shape chooses
+    (``_fwd_blocks``). The trace file's ``flash/dispatch`` instant says which.
+
     ``interpret``: None = Pallas on TPU, ``full_attention`` fallback
     elsewhere (or the Pallas interpreter when ``MPT_FLASH_INTERPRET`` is
     set — how tests drive the real kernel path through a whole model on
     CPU); True forces the interpreter; False forces the compiled kernel."""
+    from mpi_pytorch_tpu.obs import trace as obs_trace
     from mpi_pytorch_tpu.ops.ring_attention import full_attention
     from mpi_pytorch_tpu.utils.env import env_flag
     from mpi_pytorch_tpu.utils.hardware import tpu_backend
 
+    group = q.shape[2] // k.shape[2]
     if interpret is None:
         if env_flag("MPT_FLASH_INTERPRET"):
             interpret = True
         elif not tpu_backend():
-            group = q.shape[2] // k.shape[2]
             if group > 1:  # the XLA composition has one head layout
                 k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
             return full_attention(q, k, v, causal=causal, scale=scale)
@@ -472,8 +557,16 @@ def flash_attention(
             f"flash_attention: {h} query heads over k {k.shape} / v {v.shape}: "
             "the key-value heads must divide the query heads"
         )
-    bq = min(block_q, max(8, s))
-    bk = min(block_k, max(8, s))
+    bq, bk = _fwd_blocks(s, group, d * jnp.dtype(q.dtype).itemsize)
+    bq = bq if block_q is None else min(block_q, max(8, s))
+    bk = bk if block_k is None else min(block_k, max(8, s))
+    obs_trace.current().instant(
+        "flash/dispatch",
+        {"S": s, "Dh": d, "heads": h, "kv_heads": k.shape[2], "causal": causal,
+         "block_q": bq, "block_k": bk,
+         **_tile_counts(s, causal=causal, block_q=bq, block_k=bk)},
+        once=True,
+    )
 
     def to3(x):
         return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], d)

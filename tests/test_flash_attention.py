@@ -180,6 +180,148 @@ def test_flash_backward_kernels_match_grad_of_full_attention(
         assert rel < tol, (name, rel)
 
 
+def _fwd_case(name, *, causal, group, s, dtype, blocks):
+    return pytest.param(causal, group, s, dtype, blocks, id=name)
+
+
+# (query, key) blocks of the forward kernel, never square in the product; None:
+# what the shape chooses.
+FWD_CASES = [
+    _fwd_case(
+        f"{'causal' if causal else 'dense'}-g{group}-s{s}-{np.dtype(dtype).name}-{bq}x{bk}",
+        causal=causal, group=group, s=s, dtype=dtype, blocks=(bq, bk),
+    )
+    for causal in (False, True)
+    for group in (1, 4)
+    for s in (64, 50)  # divides the blocks; padded
+    for dtype in (jnp.float32, jnp.bfloat16)
+    for bq, bk in ((16, 32), (32, 16))
+] + [
+    # S inside one block of the shape's choice, not filling it: padded keys
+    # and the diagonal in the same tile.
+    _fwd_case("one-block-a-side", causal=True, group=4, s=200, dtype=jnp.float32, blocks=None),
+    # Square blocks: the first query block sees exactly one key block and the
+    # last key block is seen by exactly one query block (the clamp's edges).
+    _fwd_case("clamp-edge", causal=True, group=4, s=64, dtype=jnp.float32, blocks=(16, 16)),
+    # 48 x 16: the diagonal crosses a tile in some of its rows only (the tile
+    # of queries 48..95 and keys 64..79 is all-true below row 80), and other
+    # tiles of the same query block lie wholly below it.
+    _fwd_case("diagonal-in-part-of-the-rows", causal=True, group=4, s=96, dtype=jnp.float32, blocks=(48, 16)),
+    # 16 x 48, padded: the last key block holds the diagonal AND padded keys.
+    _fwd_case("wide-keys-padded", causal=True, group=1, s=88, dtype=jnp.float32, blocks=(16, 48)),
+    # A group of 3 in 48-row blocks: queries padded to 144 over keys padded to
+    # 128, so the last query block's clamp points past the last key block.
+    _fwd_case("group3-rows-past-the-keys", causal=True, group=3, s=100, dtype=jnp.bfloat16, blocks=(48, 32)),
+]
+
+
+def _fwd_operands(causal, group, s, dtype):
+    b, hkv, d = 2, 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(60 + s + group), 3)
+    q = jax.random.normal(ks[0], (b * hkv * group, s, d), jnp.float32).astype(dtype)
+    k, v = (jax.random.normal(key, (b * hkv, s, d), jnp.float32).astype(dtype) for key in ks[1:])
+    return q, k, v
+
+
+@pytest.mark.parametrize("causal,group,s,dtype,blocks", FWD_CASES)
+def test_flash_forward_kernel_matches_materialized_scores(causal, group, s, dtype, blocks):
+    """The forward kernel's output AND the logsumexp it leaves for the
+    backward (interpreted), against the materialized scores' softmax and
+    ``logsumexp`` in float32 at the same operands."""
+    from mpi_pytorch_tpu.ops import flash_attention as module
+
+    q, k, v = _fwd_operands(causal, group, s, dtype)
+    bq, bk = blocks or module._fwd_blocks(s, group, q.shape[-1] * q.dtype.itemsize)
+    if blocks is None:
+        assert (-(-s // bq), -(-s // bk)) == (1, 1) and s % bk
+    out, lse = module._fwd_impl(q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True)
+
+    qf, kf, vf = (x.astype(jnp.float32) for x in (q, jnp.repeat(k, group, 0), jnp.repeat(v, group, 0)))
+    scores = jnp.einsum("hqd,hkd->hqk", qf, kf) * q.shape[-1] ** -0.5
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    want = jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(scores, axis=-1), vf)
+    want_lse = jax.nn.logsumexp(scores, axis=-1)
+
+    assert out.shape == q.shape and out.dtype == dtype and lse.dtype == jnp.float32
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want), rtol=tol, atol=tol)
+    # The scores are float32 whatever the operands: only p's rounding into
+    # the MXU differs, and the logsumexp does not pass through it.
+    np.testing.assert_allclose(np.asarray(lse[:, :s]), np.asarray(want_lse), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("s,causal,blocks,want", [
+    (8192, True, (512, 512), (120, 136)),  # the parent's blocks at the token cells' sequence
+    (8192, True, None, None),  # one head's own tile, 2 048 x 512: counted element by element below
+    (8192, False, (512, 512), (0, 256)),  # no diagonal: every tile
+    (200, True, (128, 128), (1, 3)),  # padded: the tiles are the padded sequence's
+    (96, True, (48, 16), (3, 9)),  # tall tiles: a query block reaches the key blocks under its LAST row
+    (88, True, (16, 48), (3, 9)),  # wide tiles: a query block inside a key block computes it
+])
+def test_tile_counts_skipped_and_computed(s, causal, blocks, want):
+    from mpi_pytorch_tpu.ops import flash_attention as module
+
+    bq, bk = blocks or module._fwd_blocks(s, 1, 128)
+    counts = module._tile_counts(s, causal=causal, block_q=bq, block_k=bk)
+    if want is None:  # a tile is computed where some key of it is at or before some query
+        q_pos, k_pos = np.arange(s)[:, None], np.arange(s)[None, :]
+        seen = (k_pos <= q_pos).reshape(s // bq, bq, s // bk, bk).any(axis=(1, 3))
+        want = ((~seen).sum(), seen.sum())
+    assert (counts["tiles_skipped"], counts["tiles_computed"]) == want
+
+
+@pytest.mark.parametrize("s,group,d,dtype,want", [
+    (8192, 4, 64, jnp.bfloat16, (512, 512)),  # both token cells: FWD_TILE's 2 048 rows over four heads
+    (8192, 1, 64, jnp.bfloat16, (2048, 512)),
+    (8192, 3, 64, jnp.bfloat16, (672, 512)),  # 2 048 / 3 = 682: down to whole sublane tiles
+    (8192, 5, 64, jnp.bfloat16, (400, 512)),
+    (8192, 7, 64, jnp.float32, (288, 512)),
+    (8192, 256, 64, jnp.bfloat16, (16, 512)),  # never under one sublane tile
+    (8192, 4, 128, jnp.float32, (512, 512)),  # 512 bytes a row of q: the widest head at every row
+    (8192, 4, 256, jnp.float32, (256, 512)),  # twice that: half the rows
+    (8192, 1, 192, jnp.float32, (1360, 512)),
+    (1024, 1, 64, jnp.bfloat16, (1024, 512)),  # clipped to the sequence
+    (196, 1, 64, jnp.bfloat16, (256, 256)),  # ViT-B/16: one padded block a side
+    (65, 1, 64, jnp.float32, (80, 80)),  # inside one lane tile: whole sublane tiles
+])
+def test_forward_blocks_follow_the_shape(s, group, d, dtype, want):
+    """``_fwd_blocks``: rows a head in whole 16-row sublane tiles whatever the
+    group (Mosaic refuses a block whose second-minor dimension is neither a
+    multiple of 8 nor the array's), fewer rows for a wider head."""
+    from mpi_pytorch_tpu.ops import flash_attention as module
+
+    bq, bk = module._fwd_blocks(s, group, d * np.dtype(dtype).itemsize)
+    assert (bq, bk) == want and bq % 16 == 0 and bk % 16 == 0
+
+
+def test_flash_dispatch_instant_once_a_distinct_shape():
+    """``flash/dispatch`` says, once per distinct shape at trace time, which
+    tiles the forward chose and how many of a head's it skips and computes
+    (nothing runs here: the token cells' shape is only traced)."""
+    from mpi_pytorch_tpu.obs import trace as obs_trace
+
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.bfloat16)
+    small = jax.ShapeDtypeStruct((2, 64, 2, 64), jnp.float32)
+    causal = lambda *a, **kw: flash_attention(*a, causal=True, interpret=True, **kw)
+    tracer = obs_trace.Tracer("unwritten.json")
+    with obs_trace.use(tracer):
+        for _ in range(2):  # twice: one instant a shape
+            jax.eval_shape(causal, q, kv, kv)
+            jax.eval_shape(lambda *a: flash_attention(*a, interpret=True), small, small, small)
+        jax.eval_shape(lambda *a: causal(*a, block_q=1024, block_k=1024), q, kv, kv)
+    shape = {"S": 8192, "Dh": 64, "heads": 32, "kv_heads": 8, "causal": True}
+    assert [e["args"] for e in tracer._events if e["name"] == "flash/dispatch"] == [
+        {**shape, "block_q": 512, "block_k": 512,
+         "tiles_skipped": 120, "tiles_computed": 136},
+        {"S": 64, "Dh": 64, "heads": 2, "kv_heads": 2, "causal": False, "block_q": 64, "block_k": 64,
+         "tiles_skipped": 0, "tiles_computed": 1},
+        {**shape, "block_q": 1024, "block_k": 1024,
+         "tiles_skipped": 28, "tiles_computed": 36},
+    ]
+
+
 def test_flash_cpu_fallback_is_full_attention():
     """interpret=None off-TPU must route to full_attention (identical
     output, no Pallas involved) — the production CPU/GPU gating."""

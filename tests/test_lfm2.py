@@ -61,11 +61,10 @@ def test_every_gradient_leaf_through_many_blocks_of_the_flash_kernels(monkeypatc
     """The model's 64 tokens in 16-wide forward and 32 x 16 backward blocks:
     forward, dk/dv and dq kernels each walk several blocks a side, skip those
     above the diagonal and sum dk and dv over the 2 query heads of a group."""
-    from mpi_pytorch_tpu.models import lfm2
     from mpi_pytorch_tpu.ops import flash_attention
 
     monkeypatch.setenv("MPT_FLASH_INTERPRET", "1")
-    monkeypatch.setattr(lfm2, "FLASH_BLOCK", 16)
+    monkeypatch.setattr(flash_attention, "FWD_TILE", (32, 16))  # 16 rows a head of the group
     monkeypatch.setattr(flash_attention, "BWD_BLOCKS", (32, 16))
     model = lfm2_moe(0, model_config=json.dumps(TINY), attn_impl="flash")
     x, y = _tokens(2)

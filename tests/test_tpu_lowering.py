@@ -171,9 +171,10 @@ def test_attention_kernels_compile_for_v5e(one_v5e, shape):
 def test_flash_kernels_compile_for_v5e_at_the_benchmark_cells_shape(one_v5e):
     """Mosaic ACCEPTS the flash forward and both backward kernels at
     ``lfm2_train_hbm_8k``'s attention layer (2 sequences of 8 192 tokens, 32
-    query / 8 key-value heads of 64, causal, the model's 512-wide blocks):
-    the [1, BQ] rows of lse and delta, their turn into columns, VMEM for
-    three float32 score tiles. Nothing runs; no time comes out of this."""
+    query / 8 key-value heads of 64, causal, the tiles the shape chooses):
+    the forward's stacked query heads and the VMEM it asks for, the [1, BQ]
+    rows of lse and delta, their turn into columns, VMEM for three float32
+    score tiles. Nothing runs; no time comes out of this."""
     import jax.numpy as jnp
 
     from mpi_pytorch_tpu.ops.flash_attention import flash_attention
@@ -184,14 +185,40 @@ def test_flash_kernels_compile_for_v5e_at_the_benchmark_cells_shape(one_v5e):
 
     def pair(q, k, v, do):
         out, vjp = jax.vjp(
-            lambda *a: flash_attention(
-                *a, causal=True, block_q=512, block_k=512, interpret=False
-            ), q, k, v,
+            lambda *a: flash_attention(*a, causal=True, interpret=False), q, k, v,
         )
         return out, vjp(do)
 
     compiled = jax.jit(pair).lower(q, kv, kv, q).compile()
     assert mosaic_call_count(compiled) == 3
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,dtype", [
+    (6, 2, 64, "bfloat16"),  # a group of 3: 2 048 rows do not divide by it
+    (5, 1, 64, "bfloat16"),
+    (7, 1, 64, "float32"),
+    (8, 2, 128, "float32"),  # the widest head that keeps every row of the tile
+    (4, 1, 256, "float32"),  # twice its bytes a row: half the rows
+], ids=["group3", "group5", "group7-f32", "dh128-f32", "dh256-f32"])
+def test_flash_forward_compiles_for_v5e_whatever_the_group_and_the_head(
+    one_v5e, heads, kv_heads, d, dtype
+):
+    """Mosaic ACCEPTS the forward's tile at S = 8 192 for key-value groups
+    that do not divide ``FWD_TILE``'s rows (the rows a head are whole sublane
+    tiles) and for heads wider than the cells' 64 x bf16 (fewer rows: the tile
+    stays inside the 16 MiB of VMEM a kernel gets). The forward alone: the
+    backward's blocks are not the shape's to choose. Nothing runs."""
+    import jax.numpy as jnp
+
+    from mpi_pytorch_tpu.ops.flash_attention import flash_attention
+    from mpi_pytorch_tpu.utils.hardware import mosaic_call_count
+
+    q = jax.ShapeDtypeStruct((1, 8192, heads, d), jnp.dtype(dtype), sharding=one_v5e)
+    kv = jax.ShapeDtypeStruct((1, 8192, kv_heads, d), jnp.dtype(dtype), sharding=one_v5e)
+    compiled = jax.jit(
+        lambda *a: flash_attention(*a, causal=True, interpret=False)
+    ).lower(q, kv, kv).compile()
+    assert mosaic_call_count(compiled) == 1
 
 
 def test_expert_layer_compiles_for_v5e_at_the_benchmark_cells_shape(one_v5e):

@@ -113,8 +113,8 @@ def _gqa_args(b, s, h, hkv):
 
 
 def _gqa_flash_case(b, s, h, hkv) -> Case:
-    """Causal flash attention with grouped heads at the model's 512-wide
-    blocks, forward and gradients; the reference repeats k and v."""
+    """Causal flash attention with grouped heads at the tiles the shape
+    chooses, forward and gradients; the reference repeats k and v."""
     from mpi_pytorch_tpu.ops.flash_attention import flash_attention
     from mpi_pytorch_tpu.ops.ring_attention import full_attention
 
@@ -122,9 +122,7 @@ def _gqa_flash_case(b, s, h, hkv) -> Case:
     return Case(
         f"flash_attention[S={s},H={h}/{hkv},causal]",
         _with_grads(
-            lambda q, k, v: flash_attention(
-                q, k, v, causal=True, block_q=512, block_k=512, interpret=False
-            ), 3,
+            lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False), 3,
         ),
         _with_grads(
             lambda q, k, v: full_attention(
@@ -198,11 +196,14 @@ def _cases() -> list[Case]:
             tol=5e-2,
         ),
         # LFM2's attention layer: 8 key-value heads for 32 query heads, causal,
-        # the model's 512-wide blocks (models/lfm2.py FLASH_BLOCK); then the
+        # the tiles the kernel chooses for the shape; then the
         # benchmark cell's sequence (lfm2_train_hbm_8k) for one key-value group
         # of one sequence: the float32 reference's scores are 1 GB.
         _gqa_flash_case(1, 2048, 32, 8),
         _gqa_flash_case(1, 8192, 4, 1),
+        # A group of 3: the forward's 2 048 rows do not divide by it, and the
+        # sequence is no whole number of the 672-row blocks a head then gets.
+        _gqa_flash_case(1, 8192, 3, 1),
         Case(
             "fused_attention_small",
             _with_grads(lambda q, k, v: fused_attention_small(q, k, v, interpret=False), 3),
