@@ -17,13 +17,18 @@ The layer equations are the source's ``modeling_granitemoehybrid``:
   taps); ``x, B, C = split(xBC)``; ``dt = softplus(dt + dt_bias)``; the
   selective state-space recurrence (``ops/ssd.py``, chunks of
   ``mamba_chunk_size``); ``y = RMSNorm(y * silu(z)) * w`` (the gate before the
-  norm, one group over all channels); ``out = y W_out``;
+  norm; the mean square over each of ``mamba_n_groups`` groups of channels
+  apart — this model has one group, all channels); ``out = y W_out``;
 - ``mlp``: SwiGLU of ``shared_intermediate_size`` (the source fuses gate and
   up into one ``input_linear``; here they are the two matrices ``w1``, ``w3``).
 
 The architecture arrives one way, ``--model-config`` (a JSON object, inline or
 a file's path) in the source's own key names; ``GraniteHybridConfig`` reads it.
 A sliced vocabulary is a smaller ``vocab_size``.
+
+``Mamba2`` and ``Attention`` take SIZES (``Mamba2Sizes``; heads, head size,
+scale), not this family's configuration: ``models/nemotron_h.py`` builds its
+mixers from the same two definitions.
 
 Device scopes: ``mamba`` around the whole state-space mixer, inside it
 ``mamba/conv`` (convolution, bias, SiLU), ``mamba/scan`` (softplus, decays,
@@ -78,6 +83,13 @@ class GraniteHybridConfig:
     @property
     def mamba_inner(self) -> int:
         return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba(self) -> "Mamba2Sizes":
+        return Mamba2Sizes(
+            self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state, self.mamba_n_groups,
+            self.mamba_d_conv, self.mamba_chunk_size, self.rms_norm_eps,
+        )
 
     @classmethod
     def parse(cls, text: str) -> "GraniteHybridConfig":
@@ -134,8 +146,37 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+@dataclasses.dataclass(frozen=True)
+class Mamba2Sizes:
+    """What a Mamba-2 mixer is, whoever's configuration says it: ``heads`` of
+    ``head_dim`` channels, ``groups`` of B and C with ``state`` entries each
+    (a group serves ``heads / groups`` heads and is one group of the gated
+    norm), ``conv`` taps, chunks of ``chunk`` positions."""
+
+    heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv: int
+    chunk: int
+    eps: float
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+
+def grouped_rms_norm(x, scale, eps, groups: int):
+    """``rms_norm`` with the mean square taken over each of ``groups`` equal
+    runs of the last axis apart; one group is ``rms_norm``."""
+    if groups == 1:
+        return rms_norm(x, scale, eps)
+    split = x.reshape(x.shape[:-1] + (groups, x.shape[-1] // groups))
+    return rms_norm(split, 1.0, eps).reshape(x.shape) * scale
+
+
 class Mamba2(nn.Module):
-    cfg: GraniteHybridConfig
+    sizes: Mamba2Sizes
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
@@ -143,12 +184,11 @@ class Mamba2(nn.Module):
     def __call__(self, x):
         from mpi_pytorch_tpu.ops.ssd import ssd
 
-        cfg = self.cfg
-        d, inner, heads = cfg.hidden_size, cfg.mamba_inner, cfg.mamba_n_heads
-        bc = cfg.mamba_n_groups * cfg.mamba_d_state
-        taps = cfg.mamba_d_conv
+        m = self.sizes
+        d, inner, heads = x.shape[-1], m.inner, m.heads
+        bc = m.groups * m.state
         w_in = self.param("in_proj", _init(), (d, 2 * inner + 2 * bc + heads), self.param_dtype)
-        conv_w = self.param("conv_w", _init(taps**-0.5), (taps, inner + 2 * bc), self.param_dtype)
+        conv_w = self.param("conv_w", _init(m.conv**-0.5), (m.conv, inner + 2 * bc), self.param_dtype)
         conv_b = self.param("conv_b", nn.initializers.zeros, (inner + 2 * bc,), self.param_dtype)
         dt_bias = self.param("dt_bias", _dt_bias_init, (heads,), jnp.float32)
         a_log = self.param("A_log", _a_log_init, (heads,), jnp.float32)
@@ -165,27 +205,32 @@ class Mamba2(nn.Module):
             with jax.named_scope("mamba/scan"):
                 step = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
             y = ssd(
-                u.reshape(u.shape[:2] + (heads, cfg.mamba_d_head)), step, a_log,
-                b.reshape(b.shape[:2] + (cfg.mamba_n_groups, cfg.mamba_d_state)),
-                c.reshape(c.shape[:2] + (cfg.mamba_n_groups, cfg.mamba_d_state)),
-                skip, chunk=cfg.mamba_chunk_size,
+                u.reshape(u.shape[:2] + (heads, m.head_dim)), step, a_log,
+                b.reshape(b.shape[:2] + (m.groups, m.state)),
+                c.reshape(c.shape[:2] + (m.groups, m.state)),
+                skip, chunk=m.chunk,
             ).reshape(u.shape)
             with jax.named_scope("mamba/gate_norm"):
-                y = rms_norm(y * jax.nn.silu(z), norm_w, cfg.rms_norm_eps).astype(self.dtype)
+                y = grouped_rms_norm(y * jax.nn.silu(z), norm_w, m.eps, m.groups).astype(self.dtype)
             return y @ w_out.astype(self.dtype)
 
 
 class Attention(nn.Module):
-    cfg: GraniteHybridConfig
+    """Causal grouped-query attention WITHOUT a positional embedding:
+    ``heads`` query heads of ``head_dim`` read ``kv_heads`` key-value heads;
+    softmax of ``q k^T * scale`` (None: ``head_dim ** -0.5``)."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    scale: float | None = None
     attn_impl: str = "full"
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.cfg
-        d, h, hkv = cfg.hidden_size, cfg.num_attention_heads, cfg.num_key_value_heads
-        dh = d // h
+        d, h, hkv, dh = x.shape[-1], self.heads, self.kv_heads, self.head_dim
         proj = lambda name, heads: self.param(name, _init(), (d, heads, dh), self.param_dtype)
         wq, wk, wv = proj("q", h), proj("k", hkv), proj("v", hkv)
         wo = self.param("out", _init(), (h, dh, d), self.param_dtype)
@@ -193,7 +238,7 @@ class Attention(nn.Module):
         k = jnp.einsum("bsd,dhk->bshk", x, wk.astype(self.dtype))
         v = jnp.einsum("bsd,dhk->bshk", x, wv.astype(self.dtype))
         with jax.named_scope("attention"):
-            out = causal_attention(self, q, k, v, self.attn_impl, scale=cfg.attention_multiplier)
+            out = causal_attention(self, q, k, v, self.attn_impl, scale=self.scale)
         return jnp.einsum("bshk,hkd->bsd", out, wo.astype(self.dtype))
 
 
@@ -210,9 +255,13 @@ class Block(nn.Module):
         scale = jnp.asarray(cfg.residual_multiplier, self.dtype)
         h = RMSNorm(cfg.rms_norm_eps, name="mixer_norm", **kw)(x)
         if cfg.layer_types[self.index] == "attention":
-            x = x + scale * Attention(cfg, self.attn_impl, name="attn", **kw)(h)
+            heads = cfg.num_attention_heads
+            x = x + scale * Attention(
+                heads, cfg.num_key_value_heads, cfg.hidden_size // heads, cfg.attention_multiplier,
+                self.attn_impl, name="attn", **kw,
+            )(h)
         else:
-            x = x + scale * Mamba2(cfg, name="mamba", **kw)(h)
+            x = x + scale * Mamba2(cfg.mamba, name="mamba", **kw)(h)
         h = RMSNorm(cfg.rms_norm_eps, name="mlp_norm", **kw)(x)
         with jax.named_scope("mlp"):
             return x + scale * SwiGLU(cfg.shared_intermediate_size, name="mlp", **kw)(h)

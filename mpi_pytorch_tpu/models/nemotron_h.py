@@ -1,0 +1,284 @@
+"""Nemotron-H decoder with latent sparse experts (NVIDIA ``model_type:
+nemotron_h``, e.g. NVIDIA-Nemotron-3-Super-120B-A12B): a token model —
+``int32 [B, S]`` ids in, ``[B, S, V]`` next-token logits out — whose layers are
+ONE branch each: a Mamba-2 mixer, an attention layer, or an expert
+feed-forward, by the letters of ``hybrid_override_pattern``.
+
+The layer equations (the source's ``config.json``; what it does not fix is
+stated as assumed in ``benchmark/configs/nemotron-3-super-120b-a12b-tp8ep64.json``):
+
+- ``h = embed[tokens]``; layer ``i``: ``h = h + mixer_i(RMSNorm(h))``;
+  ``logits = RMSNorm(h) W_head`` (the head is NOT tied to the embedding);
+- ``M``: Mamba-2 (``models/granite_hybrid.Mamba2``, the same definition):
+  ``mamba_num_heads`` heads of ``mamba_head_dim``, ``n_groups`` groups of B
+  and C with ``ssm_state_size`` entries, a causal depthwise convolution of
+  ``conv_kernel`` taps with bias, chunks of ``chunk_size``, the gated RMSNorm
+  over each group's channels apart. ``d_inner`` is heads x head size
+  (``expand`` is not read: a share holds fewer heads of the same size);
+- ``*``: attention (``models/granite_hybrid.Attention``): ``num_attention_heads``
+  query heads of ``head_dim`` over ``num_key_value_heads``, no bias, causal
+  softmax of ``q k^T / sqrt(head_dim)``, NO positional embedding;
+- ``E``: the latent expert layer. Scores ``sigmoid(x W_r)`` in float32 over all
+  routed experts, the ``num_experts_per_tok`` largest of score +
+  ``e_score_correction_bias`` selected, weights ``s / (sum s + 1e-20) *
+  routed_scaling_factor`` (``ops/moe.sigmoid_topk_route``); ``u = x W_down``
+  into ``moe_latent_size``; the selected experts ``relu(u W1)^2 W2`` of
+  ``moe_intermediate_size`` on ``u`` (``ops/moe.held_experts``); their weighted
+  sum ``W_up`` back to the hidden width; plus the shared expert ``relu(x
+  V1)^2 V2`` of ``moe_shared_expert_intermediate_size`` on the FULL hidden state.
+
+The architecture arrives one way, ``--model-config`` (a JSON object, inline or
+a file's path) in the source's own key names; ``NemotronHConfig`` reads it. Two
+keys are this system's, for one chip's share of the experts as ``lfm2_moe`` is
+told it: ``n_routed_experts`` counts the experts HELD here,
+``n_routed_experts_published`` is the router's width (absent: every expert is
+held), ``expert_offset`` the first held id. What the absent experts would add
+is left out; the pairs routed to them are counted. A share of the heads or of
+the vocabulary is just a smaller count (``mamba_num_heads`` and ``n_groups``,
+``num_attention_heads`` and ``num_key_value_heads``, ``vocab_size``).
+
+Device scopes: ``mamba`` (``mamba/conv``, ``mamba/scan``, ``mamba/gate_norm``),
+``attention``, ``moe`` around the whole ``E`` branch with ``moe/route``,
+``moe/dispatch``, ``moe/experts``, ``moe/combine`` (``ops/moe.py``) and two of
+this module's: ``moe/latent`` (both latent projections) and ``moe/shared`` (the
+shared expert); ``head``. An ``E`` layer sows ``moe_pairs_held`` /
+``moe_pairs_absent`` / ``moe_load_max`` / ``moe_rows_computed`` into the
+``counters`` collection as ``lfm2_moe``'s do.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from mpi_pytorch_tpu.models.granite_hybrid import Attention, Mamba2, Mamba2Sizes
+from mpi_pytorch_tpu.models.lfm2 import RMSNorm, _init, model_config_json
+
+Dtype = Any
+
+# The published trunk: 88 layers, every run of 11 holding 5 M, 5 E and 1 *.
+_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
+)
+ROUTE_EPS = 1e-20  # the source's normaliser of the selected scores
+# The expert layers' row buffers hold FOUR times the share 8 of 512 experts
+# expect (``ops/moe.row_bound``'s default is twice). At top-22 a pass costs by
+# its three reads of ``k * T`` routed pairs back to tokens (8 ms each at the
+# benchmark's shape), hardly by its ``C`` rows, and at a share of 1.6 % a few
+# frequent token ids move a layer's held pairs far: one seed in fifteen sent a
+# layer over twice its share, whose second pass cost 19 ms of a 625 ms step
+# (PERF.md section 6, PR 33).
+ROW_SLACK = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    """The source's ``config.json`` keys this module reads (defaults:
+    Nemotron-3-Super-120B-A12B's published values), plus the share keys above."""
+
+    hidden_size: int = 4096
+    hybrid_override_pattern: str = _PATTERN
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    n_groups: int = 8
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    n_routed_experts: int = 512
+    n_routed_experts_published: int | None = None
+    expert_offset: int = 0
+    num_experts_per_tok: int = 22
+    routed_scaling_factor: float = 5.0
+    moe_latent_size: int = 1024
+    moe_intermediate_size: int = 2688
+    moe_shared_expert_intermediate_size: int = 5376
+    layer_norm_epsilon: float = 1e-5
+    vocab_size: int = 131072
+
+    @property
+    def routed(self) -> int:
+        return self.n_routed_experts_published or self.n_routed_experts
+
+    @property
+    def mamba(self) -> Mamba2Sizes:
+        return Mamba2Sizes(
+            self.mamba_num_heads, self.mamba_head_dim, self.ssm_state_size, self.n_groups,
+            self.conv_kernel, self.chunk_size, self.layer_norm_epsilon,
+        )
+
+    @classmethod
+    def parse(cls, text: str) -> "NemotronHConfig":
+        """From ``--model-config``: a JSON object, or the path of a file that
+        holds one. Keys this module does not read (``model_type``, ``expand``,
+        ``rope_theta``, ...) pass; a key whose value this module cannot honour
+        is an error that names it."""
+        if not text:
+            return cls()
+        raw = model_config_json(text)
+        only = (
+            ("n_group", 1), ("topk_group", 1), ("mlp_hidden_act", "relu2"),
+            ("mamba_hidden_act", "silu"), ("num_nextn_predict_layers", 0),
+            ("tie_word_embeddings", False), ("norm_topk_prob", True), ("n_shared_experts", 1),
+            ("attention_bias", False), ("mlp_bias", False), ("mamba_proj_bias", False),
+            ("use_bias", False), ("use_conv_bias", True), ("sliding_window", None),
+        )
+        for key, want in only:
+            if raw.get(key, want) != want:
+                raise ValueError(f"model-config: {key}={raw[key]!r} is not implemented (only {want!r})")
+        known = {f.name for f in dataclasses.fields(cls)}
+        cfg = cls(**{k: v for k, v in raw.items() if k in known})
+        depth = raw.get("num_hidden_layers", len(cfg.hybrid_override_pattern))
+        if depth != len(cfg.hybrid_override_pattern):
+            raise ValueError(
+                f"model-config: num_hidden_layers {depth} but hybrid_override_pattern "
+                f"has {len(cfg.hybrid_override_pattern)} letters"
+            )
+        unknown = sorted(set(cfg.hybrid_override_pattern) - set("ME*"))
+        if unknown:
+            raise ValueError(
+                f"model-config: hybrid_override_pattern letter {unknown[0]!r} is not implemented (M, E, *)"
+            )
+        if cfg.num_attention_heads % cfg.num_key_value_heads:
+            raise ValueError("model-config: num_key_value_heads must divide num_attention_heads")
+        if cfg.mamba_num_heads % cfg.n_groups:
+            raise ValueError(
+                f"model-config: n_groups {cfg.n_groups} must divide mamba_num_heads {cfg.mamba_num_heads}"
+            )
+        if not 0 <= cfg.expert_offset <= cfg.routed - cfg.n_routed_experts:
+            raise ValueError(
+                f"model-config: experts {cfg.expert_offset}..{cfg.expert_offset + cfg.n_routed_experts} "
+                f"(expert_offset, n_routed_experts) are not among the {cfg.routed} routed"
+            )
+        if cfg.num_experts_per_tok > cfg.routed:
+            raise ValueError(
+                f"model-config: num_experts_per_tok {cfg.num_experts_per_tok} of {cfg.routed} routed experts"
+            )
+        return cfg
+
+
+class Relu2Mlp(nn.Module):
+    """``relu(x W1)^2 W2``: the family's feed-forward, no gate, no bias."""
+
+    width: int
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        w1 = self.param("w1", _init(), (d, self.width), self.param_dtype)
+        w2 = self.param("w2", _init(), (self.width, d), self.param_dtype)
+        return jnp.square(jax.nn.relu(x @ w1.astype(self.dtype))) @ w2.astype(self.dtype)
+
+
+class LatentMoE(nn.Module):
+    """The ``E`` branch, told which experts it holds (``n_routed_experts`` of
+    ``n_routed_experts_published``, from ``expert_offset``): the router and the
+    shared expert read the hidden state, the routed experts a latent
+    projection of it."""
+
+    cfg: NemotronHConfig
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from mpi_pytorch_tpu.ops.moe import held_experts, relu2_expert, sigmoid_topk_route
+
+        cfg = self.cfg
+        d, latent, f, held = cfg.hidden_size, cfg.moe_latent_size, cfg.moe_intermediate_size, cfg.n_routed_experts
+        gate = self.param("gate", _init(), (d, cfg.routed), self.param_dtype)
+        # A buffer in the source, whose update rule the config does not give:
+        # drawn once, it steers the selection and ``sigmoid_topk_route`` stops
+        # its gradient, so the optimizer's update of it is exactly zero.
+        bias = self.param("e_score_correction_bias", _init(0.01), (cfg.routed,), jnp.float32)
+        down = self.param("down", _init(), (d, latent), self.param_dtype)
+        up = self.param("up", _init(), (latent, d), self.param_dtype)
+        w1 = self.param("w1", _init(), (held, latent, f), self.param_dtype)
+        w2 = self.param("w2", _init(), (held, f, latent), self.param_dtype)
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        flat = x.reshape(-1, d).astype(self.dtype)
+        with jax.named_scope("moe"):
+            with jax.named_scope("moe/route"):
+                selected, weight = sigmoid_topk_route(
+                    flat, gate, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor, ROUTE_EPS
+                )
+            with jax.named_scope("moe/latent"):
+                u = flat @ down.astype(self.dtype)
+            routed, counters = held_experts(
+                u, selected, weight, relu2_expert, (w1, w2),
+                routed=cfg.routed, expert_offset=cfg.expert_offset, slack=ROW_SLACK,
+            )
+            with jax.named_scope("moe/latent"):
+                routed = routed @ up.astype(self.dtype)
+            with jax.named_scope("moe/shared"):
+                shared = Relu2Mlp(cfg.moe_shared_expert_intermediate_size, name="shared", **kw)(flat)
+        for name, value in counters.items():
+            self.sow("counters", name, value)
+        # For a check of the routing (apply with mutable=["intermediates"]).
+        self.sow("intermediates", "selected_experts", selected)
+        return (routed + shared).reshape(x.shape)
+
+
+class Block(nn.Module):
+    cfg: NemotronHConfig
+    index: int
+    attn_impl: str = "full"
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, kw = self.cfg, dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        h = RMSNorm(cfg.layer_norm_epsilon, name="norm", **kw)(x)
+        kind = cfg.hybrid_override_pattern[self.index]
+        if kind == "M":
+            return x + Mamba2(cfg.mamba, name="mamba", **kw)(h)
+        if kind == "*":
+            return x + Attention(
+                cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
+                attn_impl=self.attn_impl, name="attn", **kw,
+            )(h)
+        return x + LatentMoE(cfg, name="moe", **kw)(h)
+
+
+class NemotronH(nn.Module):
+    cfg: NemotronHConfig
+    attn_impl: str = "full"
+    remat_blocks: bool = False
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False):
+        cfg, kw = self.cfg, dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        x = nn.Embed(
+            cfg.vocab_size, cfg.hidden_size, embedding_init=_init(), name="embed", **kw
+        )(tokens)
+        block = nn.remat(Block) if self.remat_blocks else Block
+        for i in range(len(cfg.hybrid_override_pattern)):
+            x = block(cfg, i, self.attn_impl, name=f"layer{i}", **kw)(x)
+        x = RMSNorm(cfg.layer_norm_epsilon, name="norm", **kw)(x)
+        with jax.named_scope("head"):
+            return nn.Dense(
+                cfg.vocab_size, use_bias=False, kernel_init=_init(), name="head", **kw
+            )(x)
+
+
+def nemotron_h(num_classes: int, *, model_config: str = "", **kw: Any) -> NemotronH:
+    """``num_classes`` is the image models' head size and is not read: the
+    vocabulary is the configuration's."""
+    del num_classes
+    return NemotronH(cfg=NemotronHConfig.parse(model_config), **kw)
+
+
+def nemotron_vocab(model_config: str) -> int:
+    """The vocabulary ``nemotron_h`` would be built with (``ModelSpec.vocab``)."""
+    return NemotronHConfig.parse(model_config).vocab_size

@@ -31,6 +31,7 @@ from mpi_pytorch_tpu.models.granite_hybrid import granite_vocab, granitemoehybri
 from mpi_pytorch_tpu.models.inception import inception_v3
 from mpi_pytorch_tpu.models.lfm2 import lfm2_moe, lfm2_vocab
 from mpi_pytorch_tpu.models.mobilenet import mobilenet_v2
+from mpi_pytorch_tpu.models.nemotron_h import nemotron_h, nemotron_vocab
 from mpi_pytorch_tpu.models.resnet import resnet18, resnet34
 from mpi_pytorch_tpu.models.squeezenet import squeezenet1_0
 from mpi_pytorch_tpu.models.vgg import vgg11_bn
@@ -125,7 +126,7 @@ def _vit(*more: str) -> dict:
 
 
 # The vit_* family is beyond reference parity (the reference has no
-# attention); lfm2_moe and granitemoehybrid are the two token models.
+# attention); lfm2_moe, granitemoehybrid and nemotron_h are the token models.
 _REGISTRY: dict[str, ModelSpec] = {
     "resnet18": ModelSpec(resnet18, 224, **_RESNET),
     "resnet34": ModelSpec(resnet34, 128, **_RESNET),
@@ -150,6 +151,11 @@ _REGISTRY: dict[str, ModelSpec] = {
     "granitemoehybrid": ModelSpec(
         granitemoehybrid, 256, flags=frozenset({"remat_blocks", "model_config"}),
         attn_impls=("full", "flash"), sample="tokens", vocab=granite_vocab,
+        batchnorm=False,
+    ),
+    "nemotron_h": ModelSpec(
+        nemotron_h, 128, flags=frozenset({"remat_blocks", "model_config"}),
+        attn_impls=("full", "flash"), sample="tokens", vocab=nemotron_vocab,
         batchnorm=False,
     ),
 }

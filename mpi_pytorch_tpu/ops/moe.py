@@ -24,11 +24,17 @@ coefficient to keep routing uniform.
 tests/test_moe.py asserts the 8-shard EP result equals a dense single-device
 evaluation of the same routing, values and gradients.
 
-A second routing lives below the first (``dropless_moe``, for
-``models/lfm2.py``): sigmoid scores, a selection bias, top-k over ALL routed
-experts with NO capacity and NO dropped token, computed for the experts this
-chip HOLDS by grouped matmuls over the routed pairs sorted by expert. The two
-share nothing yet; ROADMAP's Design queue says which cell would settle them.
+A second routing lives below the first, for the token models: sigmoid scores,
+a selection bias, top-k over ALL routed experts with NO capacity and NO
+dropped token (``sigmoid_topk_route``), and — apart from it — the pass over
+the experts this chip HOLDS (``held_experts``): grouped matmuls over the routed
+pairs sorted by expert, for whatever rows the caller dispatches and whatever
+expert it hands in as a function of (rows, group sizes, its weights).
+``models/lfm2.py`` routes and dispatches the same hidden state through SwiGLU
+experts (``dropless_moe``, the two composed); ``models/nemotron_h.py`` routes
+on the hidden state and dispatches rows of a narrower latent space through
+squared-ReLU experts. The two routings share nothing yet; ROADMAP's Design
+queue says which cell would settle them.
 """
 
 from __future__ import annotations
@@ -262,11 +268,12 @@ def moe_forward(
 # ---------------------------------------------------------------------------
 
 
-def sigmoid_topk_route(x, gate, expert_bias, top_k: int, scaling: float = 1.0):
+def sigmoid_topk_route(x, gate, expert_bias, top_k: int, scaling: float = 1.0, eps: float = 1e-6):
     """Scores ``s = sigmoid(x W_g)`` in float32 over ALL routed experts; the
     top-k of ``s + b`` are SELECTED (``b`` steers the choice and nothing
-    else); the weights are the selected ``s`` over their sum (+ 1e-6), times
-    ``scaling``. Returns (ids ``[T, k]`` int32, weights ``[T, k]`` float32).
+    else); the weights are the selected ``s`` over their sum (+ ``eps``: the
+    source's own constant), times ``scaling``. Returns (ids ``[T, k]`` int32,
+    weights ``[T, k]`` float32).
     The scores' matmul asks for HIGHEST precision: a TPU's default would round
     its float32 operands to bf16, and top-k over 64 close scores flips on less."""
     s = jax.nn.sigmoid(
@@ -274,7 +281,7 @@ def sigmoid_topk_route(x, gate, expert_bias, top_k: int, scaling: float = 1.0):
     )
     _, sel = lax.top_k(s + lax.stop_gradient(expert_bias.astype(jnp.float32)), top_k)
     w = jnp.take_along_axis(s, sel, axis=-1)
-    return sel, w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6) * scaling
+    return sel, w / (jnp.sum(w, axis=-1, keepdims=True) + eps) * scaling
 
 
 def grouped_matmul(rows, weights, group_sizes):
@@ -289,15 +296,17 @@ def grouped_matmul(rows, weights, group_sizes):
 ROW_TILE = 128
 
 
-def row_bound(pairs: int, held: int, routed: int) -> int:
-    """``C``, the rows of the expert layer's buffers: twice the share of the
-    ``pairs`` (tokens x choices) that ``held`` of ``routed`` experts expect,
-    in whole row tiles, and never more than all of them — which it is where
-    every routed expert is held. Twice, because a pass costs by its rows
-    whether filled or not: while the cell's router is sound every layer of
-    every step holds under 1.1 times the share, and four times the share
-    cost its layer 4.4 ms of 14.7 more (PERF.md section 6, PR 30)."""
-    expected = -(-2 * pairs * held // routed)
+def row_bound(pairs: int, held: int, routed: int, slack: int = 2) -> int:
+    """``C``, the rows of the expert layer's buffers: ``slack`` times the
+    share of the ``pairs`` (tokens x choices) that ``held`` of ``routed``
+    experts expect, in whole row tiles, and never more than all of them —
+    which it is where every routed expert is held. Twice by default, because
+    a pass costs by its rows whether filled or not: while lfm2's router is
+    sound every layer of every step holds under 1.1 times the share, and four
+    times the share cost its layer 4.4 ms of 14.7 more (PERF.md section 6,
+    PR 30). A caller whose passes cost by something else says so
+    (``models/nemotron_h.py``: PERF.md section 6, PR 33)."""
+    expected = -(-slack * pairs * held // routed)
     return min(pairs, -(-expected // ROW_TILE) * ROW_TILE)
 
 
@@ -320,9 +329,15 @@ def _chunk(pairs: _Sorted, start, bound: int, tokens: int):
     return ids, ids % tokens, sizes, jnp.clip(pairs.n_held - start, 0, bound)
 
 
-def _expert_ffn(rows, w1, w3, w2, sizes):
+def swiglu_expert(rows, sizes, w1, w3, w2):
+    """``W2 (silu(W1 r) * W3 r)``: ``w1``, ``w3 [H, D, F]``, ``w2 [H, F, D]``."""
     hidden = jax.nn.silu(grouped_matmul(rows, w1, sizes)) * grouped_matmul(rows, w3, sizes)
     return grouped_matmul(hidden, w2, sizes)
+
+
+def relu2_expert(rows, sizes, w1, w2):
+    """``W2 relu(W1 r)^2``, no gate: ``w1 [H, D, F]``, ``w2 [H, F, D]``."""
+    return grouped_matmul(jnp.square(jax.nn.relu(grouped_matmul(rows, w1, sizes))), w2, sizes)
 
 
 def _by_token(buffer, at, n_live):
@@ -340,39 +355,39 @@ def _by_token(buffer, at, n_live):
     return jnp.where(live.reshape(at.shape + (1,) * (buffer.ndim - 1)), rows, 0)
 
 
-def _chunk_forward(bound, start, x, w1, w3, w2, share, pairs):
+def _chunk_forward(bound, expert, start, x, weights, share, pairs):
     """What the held pairs at sorted positions ``start .. start + bound`` add
     to ``y [T, D]`` (float32)."""
     _, token, sizes, n_live = _chunk(pairs, start, bound, x.shape[0])
     with jax.named_scope("moe/dispatch"):
         rows = jnp.take(x, token, axis=0, mode="clip")
     with jax.named_scope("moe/experts"):
-        out = _expert_ffn(rows, w1, w3, w2, sizes)
+        out = expert(rows, sizes, *weights)
     with jax.named_scope("moe/combine"):
         mine = _by_token(out, pairs.place - start, n_live).astype(jnp.float32)
         return jnp.sum(mine * share[..., None], axis=0)
 
 
-def _chunk_backward(bound, start, x, w1, w3, w2, share, pairs, ct_y):
+def _chunk_backward(bound, expert, start, x, weights, share, pairs, ct_y):
     """The same chunk recomputed and pulled back: its part of the cotangents
-    of ``x`` (float32), ``w1``, ``w3``, ``w2`` and ``share``."""
+    of ``x`` (float32), the expert's ``weights`` and ``share``."""
     ids, token, sizes, n_live = _chunk(pairs, start, bound, x.shape[0])
     at = pairs.place - start
     live = (jnp.arange(bound) < n_live)[:, None]
     with jax.named_scope("moe/dispatch"):
         rows = jnp.take(x, token, axis=0, mode="clip")
     with jax.named_scope("moe/experts"):
-        out, pull = jax.vjp(lambda *a: _expert_ffn(*a, sizes), rows, w1, w3, w2)
+        out, pull = jax.vjp(lambda rows, *w: expert(rows, sizes, *w), rows, *weights)
     with jax.named_scope("moe/combine"):
         weight = jnp.take(share.reshape(-1), ids, mode="clip")
         ct_y_rows = jnp.take(ct_y, token, axis=0, mode="clip").astype(jnp.float32)
         ct_out = jnp.where(live, ct_y_rows * weight[:, None], 0).astype(out.dtype)
         ct_share = _by_token(jnp.sum(out.astype(jnp.float32) * ct_y_rows, axis=-1), at, n_live)
     with jax.named_scope("moe/experts"):
-        ct_rows, ct_w1, ct_w3, ct_w2 = pull(ct_out)
+        ct_rows, *ct_weights = pull(ct_out)
     with jax.named_scope("moe/dispatch"):
         ct_x = jnp.sum(_by_token(ct_rows, at, n_live).astype(jnp.float32), axis=0)
-    return ct_x, ct_w1, ct_w3, ct_w2, ct_share
+    return ct_x, tuple(ct_weights), ct_share
 
 
 def _live_chunks(bound: int, pairs: _Sorted, chunk):
@@ -395,56 +410,59 @@ def _live_chunks(bound: int, pairs: _Sorted, chunk):
     return lax.while_loop(lambda carry: carry[0] < pairs.n_held, another, first)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_ffn(bound: int, x, w1, w3, w2, share, pairs: _Sorted):
-    """``y[t] = sum_j share[j, t] * FFN_e(j, t)(x[t])`` over the held pairs,
-    in buffers of ``bound`` rows, and the buffer rows that took. Nothing is
-    kept for the backward pass but the arguments: it computes each chunk's
-    FFN again."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_ffn(bound: int, expert, x, weights, share, pairs: _Sorted):
+    """``y[t] = sum_j share[j, t] * expert_e(j, t)(x[t])`` over the held
+    pairs, in buffers of ``bound`` rows, and the buffer rows that took.
+    ``expert(rows, sizes, *weights)`` is the held experts' function of rows
+    sorted by expert. Nothing is kept for the backward pass but the
+    arguments: it computes each chunk's experts again."""
     rows_run, y = _live_chunks(
-        bound, pairs, lambda start: _chunk_forward(bound, start, x, w1, w3, w2, share, pairs)
+        bound, pairs, lambda start: _chunk_forward(bound, expert, start, x, weights, share, pairs)
     )
     return y.astype(x.dtype), rows_run
 
 
-def _held_ffn_fwd(bound, x, w1, w3, w2, share, pairs):
-    return _held_ffn(bound, x, w1, w3, w2, share, pairs), (x, w1, w3, w2, share, pairs)
+def _held_ffn_fwd(bound, expert, x, weights, share, pairs):
+    return _held_ffn(bound, expert, x, weights, share, pairs), (x, weights, share, pairs)
 
 
-def _held_ffn_bwd(bound, saved, cotangents):
+def _held_ffn_bwd(bound, expert, saved, cotangents):
     # What ``jax.checkpoint`` does for its recomputation: without the barrier
     # XLA finds the first chunk's FFN in the forward pass and keeps it (2.0 GB
     # of the cell's memory for 3 ms of its 257 ms step).
     saved, ct_y = lax.optimization_barrier((saved, cotangents[0]))
     x, *_, pairs = saved
-    _, (ct_x, *rest) = _live_chunks(
-        bound, pairs, lambda start: _chunk_backward(bound, start, *saved, ct_y)
+    _, (ct_x, ct_weights, ct_share) = _live_chunks(
+        bound, pairs, lambda start: _chunk_backward(bound, expert, start, *saved, ct_y)
     )
-    return (ct_x.astype(x.dtype), *rest, None)
+    return ct_x.astype(x.dtype), ct_weights, ct_share, None
 
 
 _held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
 
 
-def dropless_moe(
-    x, gate, expert_bias, w1, w3, w2, *, top_k: int, expert_offset: int = 0,
-    scaling: float = 1.0,
+def held_experts(
+    x, sel, weight, expert, weights, *, routed: int, expert_offset: int = 0, slack: int = 2
 ):
-    """The expert layer of one expert-parallel rank, without its exchange.
+    """The pass over the experts held here, for one expert-parallel rank
+    without its exchange.
 
-    ``x [T, D]`` tokens; ``gate [D, E]`` and ``expert_bias [E]`` span ALL
-    ``E`` routed experts; ``w1``/``w3 [H, D, F]`` and ``w2 [H, F, D]`` are the
-    ``H`` experts held here, ids ``expert_offset .. expert_offset + H``. Every
-    token routes over all ``E`` (``sigmoid_topk_route``); the result is the
-    weighted sum over its selected experts HELD HERE of ``W2 (silu(W1 h) *
-    W3 h)``; what absent experts would add is left out (their pairs are
-    counted, below). No capacity: the ``T * k`` routed pairs are sorted by
-    expert — absent ones last — and the held ones run through three grouped
-    matmuls whatever their split over the experts, so adversarial routing
-    (every token to one expert) drops nothing.
+    ``x [T, D]`` the rows to dispatch (the hidden state, or a latent
+    projection of it); ``sel``, ``weight [T, k]`` every token's selection
+    over ALL ``routed`` experts and its weights (``sigmoid_topk_route``);
+    ``weights`` a tuple of arrays with the ``H`` held experts leading, ids
+    ``expert_offset .. expert_offset + H``, and ``expert(rows, sizes,
+    *weights)`` their function of rows sorted by expert (``swiglu_expert``,
+    ``relu2_expert``). The result is, for every token, the weighted sum of
+    its selected experts HELD HERE; what absent experts would add is left out
+    (their pairs are counted, below). No capacity: the ``T * k`` routed pairs
+    are sorted by expert — absent ones last — and the held ones run through
+    the grouped matmuls whatever their split over the experts, so adversarial
+    routing (every token to one expert) drops nothing.
 
     The row buffers follow the pairs held. They have ``C = row_bound(T * k,
-    H, E)`` rows, twice what ``H`` of ``E`` experts expect, and the sorted
+    H, E, slack)`` rows, ``slack`` times what ``H`` of ``E`` experts expect, and the sorted
     pairs go through them ``C`` at a time for as long as held pairs remain
     (``_live_chunks``, from ``n_held`` on the device): one pass where the
     routing is anywhere near even, ``T * k / C`` where every pair lands here.
@@ -453,29 +471,26 @@ def dropless_moe(
     sums), so from three passes on this is the slower way: a router that has
     collapsed onto the experts held here, not one that is learning.
     A rank that holds every expert has ``C = T * k``, one pass and no loop.
-    The expert FFN is recomputed in the backward pass (``_held_ffn``): its
+    The experts are recomputed in the backward pass (``_held_ffn``): their
     ``[C, F]`` intermediates would otherwise be kept for every layer.
 
-    Returns ``(y [T, D], counters, selected [T, k])``: ``moe_pairs_held``
-    (pairs computed here), ``moe_pairs_absent`` (pairs routed to experts not
-    held), ``moe_load_max`` (largest per-expert count), ``moe_rows_computed``
-    (buffer rows the passes ran over: ``C`` a pass), int32 scalars; and the
-    ids every token selected, for whoever compares routings.
+    Returns ``(y [T, D], counters)``: ``moe_pairs_held`` (pairs computed
+    here), ``moe_pairs_absent`` (pairs routed to experts not held),
+    ``moe_load_max`` (largest per-expert count), ``moe_rows_computed``
+    (buffer rows the passes ran over: ``C`` a pass), int32 scalars.
     """
     from mpi_pytorch_tpu.obs import trace as obs_trace
 
     t, d = x.shape
-    held, routed = w1.shape[0], gate.shape[1]
-    bound = row_bound(t * top_k, held, routed)
+    top_k, held = sel.shape[1], weights[0].shape[0]
+    bound = row_bound(t * top_k, held, routed, slack)
     chunks = -(-t * top_k // bound)
     obs_trace.current().instant(
         "moe/dispatch",
-        {"experts": int(routed), "held": int(held), "top_k": top_k,
-         "tokens": int(t), "path": "ragged_dot", "rows_bound": bound},
+        {"experts": int(routed), "held": int(held), "top_k": top_k, "tokens": int(t),
+         "latent": int(d), "path": "ragged_dot", "rows_bound": bound},
         once=True,
     )
-    with jax.named_scope("moe/route"):
-        sel, weight = sigmoid_topk_route(x, gate, expert_bias, top_k, scaling)
     with jax.named_scope("moe/dispatch"):
         # [k*T] pair -> held index
         local = sel.T.reshape(-1) - expert_offset
@@ -494,7 +509,7 @@ def dropless_moe(
     with jax.named_scope("moe/combine"):
         share = jnp.where(here.reshape(top_k, t), weight.T, 0.0).astype(jnp.float32)
     y, rows_run = _held_ffn(
-        bound, x, w1.astype(x.dtype), w3.astype(x.dtype), w2.astype(x.dtype), share, pairs
+        bound, expert, x, tuple(w.astype(x.dtype) for w in weights), share, pairs
     )
     counters = {
         "moe_pairs_held": n_held,
@@ -502,4 +517,23 @@ def dropless_moe(
         "moe_load_max": jnp.max(group_sizes),
         "moe_rows_computed": rows_run,
     }
-    return y, {name: value.astype(jnp.int32) for name, value in counters.items()}, sel
+    return y, {name: value.astype(jnp.int32) for name, value in counters.items()}
+
+
+def dropless_moe(
+    x, gate, expert_bias, w1, w3, w2, *, top_k: int, expert_offset: int = 0,
+    scaling: float = 1.0,
+):
+    """``models/lfm2.py``'s expert layer: every token of ``x [T, D]`` routes
+    over all ``E`` experts of ``gate [D, E]`` (``sigmoid_topk_route``) and the
+    SAME rows go through the ``H`` SwiGLU experts held here (``held_experts``
+    with ``w1``/``w3 [H, D, F]``, ``w2 [H, F, D]``). Returns ``(y, counters,
+    selected [T, k])``: the ids every token selected, for whoever compares
+    routings."""
+    with jax.named_scope("moe/route"):
+        sel, weight = sigmoid_topk_route(x, gate, expert_bias, top_k, scaling)
+    y, counters = held_experts(
+        x, sel, weight, swiglu_expert, (w1, w3, w2),
+        routed=gate.shape[1], expert_offset=expert_offset,
+    )
+    return y, counters, sel

@@ -112,7 +112,7 @@ def test_a_sequence_that_is_no_whole_number_of_chunks_is_refused_with_the_number
 def test_the_state_space_mixer_is_causal():
     """A change at position t moves nothing before t, across chunk borders."""
     cfg = GraniteHybridConfig.parse(json.dumps(TINY))
-    mixer = Mamba2(cfg)
+    mixer = Mamba2(cfg.mamba)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 64, 64), jnp.float32)
     params = mixer.init(jax.random.PRNGKey(1), x)
     base = mixer.apply(params, x)

@@ -343,8 +343,8 @@ def test_trainer_main_trains_tokens_from_the_device_cache_in_scanned_epochs(tmp_
         instants = [e for e in json.load(f)["traceEvents"] if e["name"] == "moe/dispatch"]
     # One a distinct shape: init's dummy sequence, then the step's batch.
     assert [e["args"] for e in instants] == [
-        {"experts": 16, "held": 4, "top_k": 4, "tokens": tokens, "path": "ragged_dot",
-         "rows_bound": 2 * tokens}
+        {"experts": 16, "held": 4, "top_k": 4, "tokens": tokens, "latent": 64,
+         "path": "ragged_dot", "rows_bound": 2 * tokens}
         for tokens in (64, 8 * 64)
     ]
 

@@ -37,8 +37,15 @@ TINY_GRANITE = json.dumps({
     "num_key_value_heads": 2, "layer_types": ["mamba", "attention"], "mamba_n_heads": 8,
     "mamba_d_head": 16, "mamba_d_state": 16, "mamba_chunk_size": 16, "vocab_size": 128,
 })
+TINY_NEMOTRON = json.dumps({
+    "hidden_size": 64, "hybrid_override_pattern": "ME*", "mamba_num_heads": 8, "mamba_head_dim": 8,
+    "ssm_state_size": 16, "n_groups": 2, "chunk_size": 16, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "n_routed_experts": 4, "num_experts_per_tok": 2,
+    "moe_latent_size": 32, "moe_intermediate_size": 48, "moe_shared_expert_intermediate_size": 80,
+    "num_nextn_predict_layers": 0, "vocab_size": 128,
+})
 # A configured model's own tiny ``--model-config`` (each reads its source's keys).
-TINY_CONFIGS = {"lfm2_moe": TINY_LFM2, "granitemoehybrid": TINY_GRANITE}
+TINY_CONFIGS = {"lfm2_moe": TINY_LFM2, "granitemoehybrid": TINY_GRANITE, "nemotron_h": TINY_NEMOTRON}
 
 # The (flag, value) -> models table of the parent commit's eleven name lists
 # (ATTN_IMPL_MODELS, SP_MODELS, MOE_MODELS, REMAT_BLOCKS_MODELS, S2D_MODELS,
@@ -46,14 +53,14 @@ TINY_CONFIGS = {"lfm2_moe": TINY_LFM2, "granitemoehybrid": TINY_GRANITE}
 # fused-small refusal): what is accepted; every other pair is refused.
 _VITS = {"vit_s16", "vit_b16", "vit_moe_s16"}
 ACCEPTED = {
-    ("attn_impl", "flash"): _VITS | {"lfm2_moe", "granitemoehybrid"},
+    ("attn_impl", "flash"): _VITS | {"lfm2_moe", "granitemoehybrid", "nemotron_h"},
     ("attn_impl", "fused-small"): _VITS,
     ("sp_strategy", "ring"): _VITS,
     ("qkv_fused", True): _VITS,
     ("ep_mesh", "mesh"): {"vit_moe_s16"},
     ("remat_blocks", True): {
         "resnet18", "resnet34", "densenet121", "vit_s16", "vit_b16", "lfm2_moe",
-        "granitemoehybrid",
+        "granitemoehybrid", "nemotron_h",
     },
     ("stem_s2d", True): {"resnet18", "resnet34"},
     ("fused_stem", True): {"resnet18", "resnet34", "densenet121"},

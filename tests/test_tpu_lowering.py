@@ -276,3 +276,49 @@ def test_state_space_scan_compiles_for_v5e_at_the_benchmark_cells_shape(one_v5e)
     assert text.count(" while(") == 2  # the 32 chunk states, forward and backward
     assert "f32[32,64,256,256]" in text  # the decays, float32, every chunk at once
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30  # 461 MB when written
+
+
+def test_nemotron_h_step_lowers_for_a_tpu_at_the_benchmark_cells_shapes(monkeypatch):
+    """Loss and gradients of ``nemotron3s_train_hbm_8k``'s model — the
+    configuration file's cut, 16 384 tokens, bf16, flash attention, per-block
+    recompute — lowered for ``tpu`` from shapes alone: flash attention at head
+    size 128 with four query heads on one key-value head (forward, the
+    recompute's forward, dk/dv, dq), the expert layers' grouped matmuls over
+    row buffers of C = 22 528 rows of the 1 024-wide LATENT (never the 360 448
+    routed pairs' rows), the by-token read of 22 choices. Nothing is
+    allocated; whether Mosaic compiles it is ``rehearse/compile_v5e.py``'s."""
+    import json
+
+    import jax.numpy as jnp
+
+    from mpi_pytorch_tpu.models.nemotron_h import nemotron_h
+    from mpi_pytorch_tpu.ops.losses import cross_entropy
+    from mpi_pytorch_tpu.ops.moe import row_bound
+    from mpi_pytorch_tpu.utils import hardware
+
+    monkeypatch.setattr(hardware, "tpu_backend", lambda: True)  # the kernels, not their XLA compositions
+    with open(os.path.join(REPO, "benchmark/configs/nemotron-3-super-120b-a12b-tp8ep64.json")) as f:
+        stated = json.load(f)
+    tokens = stated["batch_per_chip"] * stated["model"]["seq_len"]
+    assert row_bound(tokens * 22, 8, 512, 4) == 22_528  # the model's ROW_SLACK; 11 264 at the default
+    model = nemotron_h(
+        0, model_config=json.dumps(stated["model"]), attn_impl="flash", remat_blocks=True,
+        dtype=jnp.bfloat16,
+    )
+    params = jax.eval_shape(
+        lambda key, x: model.init(key, x), jax.ShapeDtypeStruct((2,), jnp.uint32),
+        jax.ShapeDtypeStruct((1, 128), jnp.int32),
+    )["params"]
+    ids = jax.ShapeDtypeStruct((stated["batch_per_chip"], stated["model"]["seq_len"]), jnp.int32)
+
+    def loss(params, x, y):
+        logits, _ = model.apply({"params": params}, x, mutable=["counters"])
+        return cross_entropy(logits, y)
+
+    lowered = jax.jit(jax.value_and_grad(loss)).trace(params, ids, ids).lower(lowering_platforms=("tpu",))
+    assert hardware.mosaic_call_count(lowered) == 4
+    text = lowered.as_text()
+    assert "ragged_dot" in text and "22528x2688xbf16" in text and "22528x1024xbf16" in text
+    assert f"22x{tokens}x1024xbf16" in text  # the per-pair read back to tokens
+    assert f"{tokens * 22}x2688" not in text  # no buffer of the experts' width has every pair's row
+    assert f"{tokens}x5376xbf16" in text  # the shared expert sees every token at the hidden width
