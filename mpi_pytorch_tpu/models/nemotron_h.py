@@ -66,12 +66,13 @@ _PATTERN = (
 )
 ROUTE_EPS = 1e-20  # the source's normaliser of the selected scores
 # The expert layers' row buffers hold FOUR times the share 8 of 512 experts
-# expect (``ops/moe.row_bound``'s default is twice). At top-22 a pass costs by
-# its three reads of ``k * T`` routed pairs back to tokens (8 ms each at the
-# benchmark's shape), hardly by its ``C`` rows, and at a share of 1.6 % a few
+# expect (``ops/moe.row_bound``'s default is twice). At a share of 1.6 % a few
 # frequent token ids move a layer's held pairs far: one seed in fifteen sent a
-# layer over twice its share, whose second pass cost 19 ms of a 625 ms step
-# (PERF.md section 6, PR 33).
+# layer over twice its share, and its second pass moved the benchmark's six-run
+# spread past half the bound (PERF.md section 6, PR 33). What a pass costs has
+# changed since (it returns to tokens by its ``C`` rows, no longer by three
+# reads of ``k * T`` routed pairs: PR 34), and what twice the share reads now is
+# an open question there (section 7), not a reason this value rests on.
 ROW_SLACK = 4
 
 

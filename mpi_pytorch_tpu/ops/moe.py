@@ -40,6 +40,7 @@ queue says which cell would settle them.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -304,8 +305,9 @@ def row_bound(pairs: int, held: int, routed: int, slack: int = 2) -> int:
     a pass costs by its rows whether filled or not: while lfm2's router is
     sound every layer of every step holds under 1.1 times the share, and four
     times the share cost its layer 4.4 ms of 14.7 more (PERF.md section 6,
-    PR 30). A caller whose passes cost by something else says so
-    (``models/nemotron_h.py``: PERF.md section 6, PR 33)."""
+    PR 30). A caller whose routing swings further than that asks for more
+    (``models/nemotron_h.py``: PERF.md section 6, PR 33). The bound also sets
+    how a pass returns to tokens (``BY_ROW_FROM``)."""
     expected = -(-slack * pairs * held // routed)
     return min(pairs, -(-expected // ROW_TILE) * ROW_TILE)
 
@@ -340,30 +342,67 @@ def relu2_expert(rows, sizes, w1, w2):
     return grouped_matmul(jnp.square(jax.nn.relu(grouped_matmul(rows, w1, sizes))), w2, sizes)
 
 
+# Routed pairs a buffer row (``k * T / C``) from which a pass returns to tokens
+# BY ROW. The per-pair read costs by ``k * T`` rows and the sum by row by ``C``,
+# so the two cross in that ratio. One expert layer, forward + backward, on a
+# v5e, ms per pair / by row: 2 048-wide rows 13.6 / 16.7 at 4 pairs a row,
+# 15.4 / 16.5 at 8, 17.8 / 16.7 at 12, 20.0 / 17.0 at 16; 1 024-wide rows
+# 14.8 / 15.7 at 6, 13.2 / 12.9 at 8, 24.6 / 13.1 at 16 (PERF.md section 6,
+# PR 34).
+BY_ROW_FROM = 10
+
+
+def _returns_by_row(pairs: int, bound: int) -> bool:
+    """Whether a pass of ``bound`` buffer rows over ``pairs`` routed pairs goes
+    back to tokens by row (``_sum_by_token``) or once a pair (``_by_token``):
+    from the two SHAPES alone."""
+    return pairs >= BY_ROW_FROM * bound
+
+
 def _by_token(buffer, at, n_live):
     """``buffer [C, ...]`` read once a pair: ``[k, T, ...]``, entry ``(j, t)``
     the row at ``at[j, t]`` where that lies in ``0 .. n_live``, else 0. The
-    way from sorted rows back to tokens as a gather: its transpose, a
-    scatter-add, cannot know that the live positions are distinct (1.9 ms for
-    16 384 rows of 2 048 where this read and its sum take 1.0). Every pass
-    is read on its own: a gather costs by its operand, 0.41 ms from these
-    67 MB and 2.39 from one ``[T * k, D]`` buffer all passes would land in.
-    Rows past ``n_live`` hold whatever the buffer held, so they are masked,
-    never multiplied by zero."""
+    way from sorted rows back to tokens as a gather of ``k * T`` rows, which
+    the caller sums over ``k``: the cheaper way while a buffer row stands for
+    few routed pairs (at lfm2's 4 this read and its sum take 1.0 ms for 16 384
+    rows of 2 048 where ``_sum_by_token`` takes 1.9), the dearer from
+    ``BY_ROW_FROM`` on. Every pass is read on its own: a gather costs by its
+    operand, 0.41 ms from lfm2's 67 MB and 2.39 from one ``[T * k, D]`` buffer
+    all passes would land in. Rows past ``n_live`` hold whatever the buffer
+    held, so they are masked, never multiplied by zero."""
     live = (at >= 0) & (at < n_live)
     rows = jnp.take(buffer, at.reshape(-1), axis=0, mode="clip").reshape(at.shape + buffer.shape[1:])
     return jnp.where(live.reshape(at.shape + (1,) * (buffer.ndim - 1)), rows, 0)
 
 
+def _sum_by_token(rows, token, n_live, tokens: int):
+    """``rows [C, D]`` float32 summed into ``[tokens, D]`` by ``token [C]``:
+    the transpose of ``_by_token`` and its sum, a scatter-add of ``C`` rows,
+    through a ``[C, D / 128, 128]`` view in which a row is whole lane tiles.
+    The plain two-dimensional scatter is 1.3 ms a layer faster on a v5e and
+    ~1.5 MB of generated code an instance, forty instances a step of
+    ``nemotron_h``: its program then no longer fits the compile cache beside
+    the others of its run and every start compiles it again (PERF.md section
+    6, PR 34). The TPU compiler sorts the indices itself (sorting the rows by
+    token first bought nothing). Rows past ``n_live`` are masked, never
+    multiplied by zero."""
+    live = (jnp.arange(rows.shape[0]) < n_live)[:, None]
+    tiles = jnp.where(live, rows, 0).reshape(rows.shape[0], -1, math.gcd(rows.shape[1], 128))
+    return jax.ops.segment_sum(tiles, token, num_segments=tokens).reshape(tokens, -1)
+
+
 def _chunk_forward(bound, expert, start, x, weights, share, pairs):
     """What the held pairs at sorted positions ``start .. start + bound`` add
     to ``y [T, D]`` (float32)."""
-    _, token, sizes, n_live = _chunk(pairs, start, bound, x.shape[0])
+    ids, token, sizes, n_live = _chunk(pairs, start, bound, x.shape[0])
     with jax.named_scope("moe/dispatch"):
         rows = jnp.take(x, token, axis=0, mode="clip")
     with jax.named_scope("moe/experts"):
         out = expert(rows, sizes, *weights)
     with jax.named_scope("moe/combine"):
+        if _returns_by_row(share.size, bound):
+            weight = jnp.take(share.reshape(-1), ids, mode="clip")
+            return _sum_by_token(out.astype(jnp.float32) * weight[:, None], token, n_live, x.shape[0])
         mine = _by_token(out, pairs.place - start, n_live).astype(jnp.float32)
         return jnp.sum(mine * share[..., None], axis=0)
 
@@ -372,7 +411,8 @@ def _chunk_backward(bound, expert, start, x, weights, share, pairs, ct_y):
     """The same chunk recomputed and pulled back: its part of the cotangents
     of ``x`` (float32), the expert's ``weights`` and ``share``."""
     ids, token, sizes, n_live = _chunk(pairs, start, bound, x.shape[0])
-    at = pairs.place - start
+    by_row = _returns_by_row(share.size, bound)
+    at = None if by_row else pairs.place - start
     live = (jnp.arange(bound) < n_live)[:, None]
     with jax.named_scope("moe/dispatch"):
         rows = jnp.take(x, token, axis=0, mode="clip")
@@ -382,11 +422,20 @@ def _chunk_backward(bound, expert, start, x, weights, share, pairs, ct_y):
         weight = jnp.take(share.reshape(-1), ids, mode="clip")
         ct_y_rows = jnp.take(ct_y, token, axis=0, mode="clip").astype(jnp.float32)
         ct_out = jnp.where(live, ct_y_rows * weight[:, None], 0).astype(out.dtype)
-        ct_share = _by_token(jnp.sum(out.astype(jnp.float32) * ct_y_rows, axis=-1), at, n_live)
+        ct_weight = jnp.sum(out.astype(jnp.float32) * ct_y_rows, axis=-1)
+        if by_row:
+            # ``C`` scalars to their pairs: the live rows' ids are distinct.
+            ct_share = jnp.zeros(share.size, jnp.float32).at[ids].add(jnp.where(live[:, 0], ct_weight, 0))
+            ct_share = ct_share.reshape(share.shape)
+        else:
+            ct_share = _by_token(ct_weight, at, n_live)
     with jax.named_scope("moe/experts"):
         ct_rows, *ct_weights = pull(ct_out)
     with jax.named_scope("moe/dispatch"):
-        ct_x = jnp.sum(_by_token(ct_rows, at, n_live).astype(jnp.float32), axis=0)
+        if by_row:
+            ct_x = _sum_by_token(ct_rows.astype(jnp.float32), token, n_live, x.shape[0])
+        else:
+            ct_x = jnp.sum(_by_token(ct_rows, at, n_live).astype(jnp.float32), axis=0)
     return ct_x, tuple(ct_weights), ct_share
 
 
@@ -466,8 +515,14 @@ def held_experts(
     pairs go through them ``C`` at a time for as long as held pairs remain
     (``_live_chunks``, from ``n_held`` on the device): one pass where the
     routing is anywhere near even, ``T * k / C`` where every pair lands here.
+    A pass goes back from its ``C`` rows to the ``T`` tokens in one of two
+    forms, chosen from ``T * k / C`` alone (``BY_ROW_FROM``): under it by a
+    read of the buffer once a routed pair (``_by_token``: the pass costs by
+    its ``T * k`` reads besides its ``C`` rows), from it on by a sum of the
+    ``C`` rows by token (``_sum_by_token``: the pass costs by ``C`` alone);
+    the same float32 products and sums either way, in another order.
     A pass past the first costs about a fifth more than the same rows cost
-    in full-size buffers (each repeats the reads by token and the weights'
+    in full-size buffers (each repeats the return to tokens and the weights'
     sums), so from three passes on this is the slower way: a router that has
     collapsed onto the experts held here, not one that is learning.
     A rank that holds every expert has ``C = T * k``, one pass and no loop.
@@ -488,7 +543,9 @@ def held_experts(
     obs_trace.current().instant(
         "moe/dispatch",
         {"experts": int(routed), "held": int(held), "top_k": top_k, "tokens": int(t),
-         "latent": int(d), "path": "ragged_dot", "rows_bound": bound},
+         "latent": int(d), "path": "ragged_dot", "rows_bound": bound,
+         "combine": "by_row" if _returns_by_row(t * top_k, bound) else "per_pair",
+         "pairs_per_row": round(t * top_k / bound, 2)},
         once=True,
     )
     with jax.named_scope("moe/dispatch"):

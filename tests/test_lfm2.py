@@ -344,7 +344,7 @@ def test_trainer_main_trains_tokens_from_the_device_cache_in_scanned_epochs(tmp_
     # One a distinct shape: init's dummy sequence, then the step's batch.
     assert [e["args"] for e in instants] == [
         {"experts": 16, "held": 4, "top_k": 4, "tokens": tokens, "latent": 64,
-         "path": "ragged_dot", "rows_bound": 2 * tokens}
+         "path": "ragged_dot", "rows_bound": 2 * tokens, "combine": "per_pair", "pairs_per_row": 2.0}
         for tokens in (64, 8 * 64)
     ]
 

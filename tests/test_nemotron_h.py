@@ -129,10 +129,11 @@ def test_trainer_main_trains_the_model_from_the_device_cache_in_scanned_epochs(t
     with open(tmp_path / "spans.json") as f:
         events = json.load(f)["traceEvents"]
     # One a distinct shape: init's dummy sequence, then the step's batch. The
-    # dispatched rows are the LATENT ones (32 wide, not the hidden 64).
+    # dispatched rows are the LATENT ones (32 wide, not the hidden 64). Buffers
+    # of every pair's rows (one pair a row) go back to tokens once a pair.
     assert [e["args"] for e in events if e["name"] == "moe/dispatch"] == [
         {"experts": 16, "held": 4, "top_k": 6, "tokens": tokens, "latent": 32,
-         "path": "ragged_dot", "rows_bound": tokens * 6}
+         "path": "ragged_dot", "rows_bound": tokens * 6, "combine": "per_pair", "pairs_per_row": 1.0}
         for tokens in (64, 8 * 64)
     ]
     assert [e["args"]["groups"] for e in events if e["name"] == "ssm/dispatch"] == [2, 2]

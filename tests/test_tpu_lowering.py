@@ -10,6 +10,7 @@ whether the Mosaic COMPILER accepts the module — that is
 
 import importlib.util
 import os
+import re
 from unittest import mock
 
 import jax
@@ -240,14 +241,20 @@ def test_expert_layer_compiles_for_v5e_at_the_benchmark_cells_shape(one_v5e):
         y, _, _ = dropless_moe(x, gate, bias, w1, w3, w2, top_k=top_k)
         return jnp.sum(y.astype(jnp.float32))
 
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+    grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5)))
+    args = (
         shape(tokens, d, dtype=jnp.bfloat16), shape(d, routed), shape(routed),
         shape(held, d, f), shape(held, d, f), shape(held, f, d),
-    ).compile()
-    text = compiled.as_text()
+    )
+    text = grad.lower(*args).compile().as_text()
     assert text.count(" while(") == 2  # the chunks past the first, forward and backward
     assert f"[{tokens * top_k},{f}]" not in text
     assert f"[{tokens},{f}]" in text
+    # Back to tokens ONCE A PAIR (4 routed pairs a buffer row): the [4, 16 384, 2 048] read, and no sum of
+    # 2 048-wide rows by token (the router's scatters move scalars).
+    lowered = grad.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert f"tensor<{top_k}x{tokens}x{d}xbf16>" in lowered
+    assert not re.search(rf"xi32>, tensor<\d+x{d // 128}x128xf32>\) -> tensor<{tokens}x{d // 128}x128xf32>", lowered)
 
 
 def test_state_space_scan_compiles_for_v5e_at_the_benchmark_cells_shape(one_v5e):
@@ -285,7 +292,7 @@ def test_nemotron_h_step_lowers_for_a_tpu_at_the_benchmark_cells_shapes(monkeypa
     size 128 with four query heads on one key-value head (forward, the
     recompute's forward, dk/dv, dq), the expert layers' grouped matmuls over
     row buffers of C = 22 528 rows of the 1 024-wide LATENT (never the 360 448
-    routed pairs' rows), the by-token read of 22 choices. Nothing is
+    routed pairs' rows), the sum of those rows by token. Nothing is
     allocated; whether Mosaic compiles it is ``rehearse/compile_v5e.py``'s."""
     import json
 
@@ -319,6 +326,9 @@ def test_nemotron_h_step_lowers_for_a_tpu_at_the_benchmark_cells_shapes(monkeypa
     assert hardware.mosaic_call_count(lowered) == 4
     text = lowered.as_text()
     assert "ragged_dot" in text and "22528x2688xbf16" in text and "22528x1024xbf16" in text
-    assert f"22x{tokens}x1024xbf16" in text  # the per-pair read back to tokens
+    # Back to tokens BY ROW (16 routed pairs a buffer row): a sum of the 22 528 rows by token, forward and
+    # backward, and nothing of a row's width once a routed pair.
+    assert f"xi32>, tensor<22528x8x128xf32>) -> tensor<{tokens}x8x128xf32>" in text
+    assert f"22x{tokens}x1024" not in text and f"{tokens * 22}x1024" not in text
     assert f"{tokens * 22}x2688" not in text  # no buffer of the experts' width has every pair's row
     assert f"{tokens}x5376xbf16" in text  # the shared expert sees every token at the hidden width
