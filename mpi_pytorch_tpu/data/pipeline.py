@@ -18,10 +18,14 @@ is missing (SURVEY §3 quirks).
 Where a streaming epoch's time goes is written on the run's tracer
 (``obs.trace.current()``, inert unless a driver installed one): on the
 producer thread ``loader/epoch`` (its whole life; wall time outside it is
-time with no producer alive), ``loader/decode`` per batch (args ``images``,
+time with no producer alive; args ``cpu_s``, the CPU seconds of the whole
+process over that life, and ``host_cpus``, the cores it may run on),
+``loader/decode`` per batch (args ``images``,
 ``source``, ``threads``, ``fallbacks``, ``quarantined``, ``thread_busy_s``
-— the seconds the decode workers were inside a decode, from counters kept
-where the decode happens and read only while a tracer records),
+— the seconds the decode workers were inside a decode — and, for the native
+source, ``stage_s``: those seconds by stage of the C decoder, with
+``jpeg_scan_s``, the scanline loop's part of its ``jpeg`` stage; all from
+counters kept where the decode happens and read only while a tracer records),
 ``loader/cast`` (the batch's dtype conversion, on
 that one thread) and ``loader/put`` (blocked on a full queue: the consumer is
 the slower side); on the consumer's thread ``loader/get`` (blocked on an
@@ -339,19 +343,23 @@ class DataLoader:
         self._quarantine(i, err)
         return None
 
-    def _decode_counters(self) -> tuple[int, int]:
-        """``(images the C decoder refused, nanoseconds inside a decode)`` so
-        far: the native library's process-wide counters (another loader
-        decoding at the same time shows in them) plus this loader's Python
-        decodes. A batch's share is the difference around its
-        ``_load_batch``."""
+    def _decode_counters(self) -> tuple[int, int, dict[str, int]]:
+        """``(images the C decoder refused, nanoseconds inside a decode, the
+        C decoder's nanoseconds by stage)`` so far: the native library's
+        process-wide counters (another loader decoding at the same time shows
+        in them) plus this loader's Python decodes, which have no stages (an
+        empty dict where the pixels do not come from the C decoder). A
+        batch's share is the difference around its ``_load_batch``."""
         refused = busy_ns = 0
+        stage_ns: dict[str, int] = {}
         if self.native_decode:
             from mpi_pytorch_tpu import native
 
-            refused, busy_ns = native.counters()
+            refused, busy_ns, stage_ns = native.counters()
+            if self._pack is not None:
+                stage_ns = {}  # a pack's rows are read, not decoded
         with self._bad_lock:
-            return refused, busy_ns + self._py_busy_ns
+            return refused, busy_ns + self._py_busy_ns, stage_ns
 
     @property
     def decode_source(self) -> str:
@@ -611,12 +619,12 @@ class DataLoader:
                 "threads": self.num_workers,
             }
             with tracer.span("loader/decode", args=args):
-                refused, busy_ns = self._decode_counters()
+                refused, busy_ns, stage_ns = self._decode_counters()
                 quarantined = self.bad_samples
                 t0 = time.perf_counter()
                 stacked = self._load_batch(idx, pool)
                 call_s = time.perf_counter() - t0
-                refused_now, busy_ns_now = self._decode_counters()
+                refused_now, busy_ns_now, stage_ns_now = self._decode_counters()
                 args["fallbacks"] = refused_now - refused
                 args["quarantined"] = self.bad_samples - quarantined
                 # A pack is read by this one thread: its busy time is the
@@ -624,6 +632,13 @@ class DataLoader:
                 args["thread_busy_s"] = (
                     call_s if self._pack is not None else (busy_ns_now - busy_ns) / 1e9
                 )
+                if stage_ns_now:
+                    # Which stage of the C decoder the workers' seconds went
+                    # to (a per-item PIL fallback is busy time outside them),
+                    # and how much of ``jpeg`` was its scanline loop.
+                    took = {k: (ns - stage_ns[k]) / 1e9 for k, ns in stage_ns_now.items()}
+                    args["jpeg_scan_s"] = took.pop("jpeg_scan")
+                    args["stage_s"] = took
             return stacked
 
         def decode_one_batch(idx, pool):
@@ -639,10 +654,17 @@ class DataLoader:
         def producer() -> None:
             # The producer's whole life, thread start to sentinel: the wall
             # time of a run not under a ``loader/epoch`` had no producer alive.
-            with tracer.span(
-                "loader/epoch", args={"epoch": epoch, "batches": nb - start_batch}
-            ):
+            # Traced, the span also says what the WHOLE process burnt over
+            # that life — decode workers, the cast, the step loop and the
+            # runtime's transfer threads alike — beside the cores it had: a
+            # host out of cores reads cpu_s / (host_cpus x seconds) near 1.
+            args = {"epoch": epoch, "batches": nb - start_batch}
+            with tracer.span("loader/epoch", args=args):
+                cpu0 = time.process_time() if tracer.enabled else None
                 produce()
+                if cpu0 is not None:
+                    args["cpu_s"] = time.process_time() - cpu0
+                    args["host_cpus"] = len(os.sched_getaffinity(0))
 
         def produce() -> None:
             error = None

@@ -20,6 +20,7 @@ decode (corrupt, non-JPEG, CMYK), only that item falls back to PIL.
 
 from __future__ import annotations
 
+import _ctypes
 import ctypes
 import glob
 import hashlib
@@ -33,6 +34,14 @@ import numpy as np
 
 _SRC = os.path.join(os.path.dirname(__file__), "decode.cpp")
 _LIB_PREFIX = "_mptnative_"
+
+# What decode.cpp's ``mpt_abi_version`` must answer, and the names of the
+# nanoseconds ``mpt_decode_counters`` writes after its first two values: the
+# four stages of an image, which are its busy time, then ``jpeg_scan``, the
+# scanline loop inside ``jpeg``.
+_ABI_VERSION = 4
+STAGES = ("file", "jpeg", "resize", "normalize")
+_COUNTER_NAMES = STAGES + ("jpeg_scan",)
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -113,9 +122,12 @@ def _try_load() -> ctypes.CDLL | None:
                 last_err = f"{type(e).__name__}: {e} {out}"
                 lib = None
                 break  # build/load failure: move to the next candidate dir
-            if _abi_version(lib) == 3:
+            if _abi_version(lib) == _ABI_VERSION:
                 break
             last_err = f"stale native library (wrong ABI) at {path}"
+            # Unmap it: dlopen answers a path it has already loaded with that
+            # mapping, so the rebuilt file would never be looked at.
+            _ctypes.dlclose(lib._handle)
             lib = None
             try:
                 os.unlink(path)  # next attempt rebuilds from source
@@ -176,18 +188,22 @@ def build_error() -> str | None:
     return _build_error
 
 
-def counters() -> tuple[int, int]:
+def counters() -> tuple[int, int, dict[str, int]]:
     """``(images refused, nanoseconds the worker threads spent inside a
-    decode)`` over every ``decode_batch`` call of this process so far — kept
-    where the decode happens (two atomics in decode.cpp). A caller reads it
-    before and after a call and takes the difference; zeros when the library
-    is unavailable."""
+    decode, those nanoseconds by name)`` over every ``decode_batch`` call of
+    this process so far — seven values kept where the decode happens (atomics
+    in decode.cpp). The names are ``STAGES`` (read the file, libjpeg, the
+    resize, the normalize pass), which partition the busy time — a refused or
+    failed image leaves what it spent on the stage it stopped in — and then
+    ``jpeg_scan``, the scanline loop's share of ``jpeg``. A caller reads
+    before and after a call and takes the differences; zeros when the
+    library is unavailable."""
     lib = load()
     if lib is None:
-        return 0, 0
-    out = (ctypes.c_longlong * 2)()
+        return 0, 0, dict.fromkeys(_COUNTER_NAMES, 0)
+    out = (ctypes.c_longlong * (2 + len(_COUNTER_NAMES)))()
     lib.mpt_decode_counters(out)
-    return int(out[0]), int(out[1])
+    return int(out[0]), int(out[1]), dict(zip(_COUNTER_NAMES, map(int, out[2:])))
 
 
 def decode_batch(

@@ -54,6 +54,43 @@ void on_error_exit(j_common_ptr cinfo) {
 void on_output_message(j_common_ptr) {}  // swallow warnings
 
 // ---------------------------------------------------------------------------
+// Where one image's time goes: the laps of decode_file in the order they
+// run, timed on the worker thread by ONE clock read at each boundary. The
+// laps partition the worker's busy time (first read to last: the busy time
+// IS their sum); a refused or failed image leaves what it spent on the lap
+// it stopped in. Reported as four stages — file, jpeg (= head +
+// scan), resize, normalize — and jpeg's scanline loop beside them, the one
+// stage that is over half of an image.
+// ---------------------------------------------------------------------------
+using Clock = std::chrono::steady_clock;
+
+enum Lap {
+  LAP_FILE = 0,   // fopen .. fclose: the encoded bytes into memory
+  LAP_JPEG_HEAD,  // jpeg_create_decompress, the header, jpeg_start_decompress
+  LAP_JPEG_SCAN,  // the scanline loop (entropy decode, scaled IDCT, colour
+                  // conversion) .. jpeg_destroy_decompress
+  LAP_RESIZE,     // resize_rgb with its two make_kernel calls (or the
+                  // equal-size uint8 -> float copy)
+  LAP_NORMALIZE,  // the scale-and-shift pass
+  N_LAPS,
+};
+
+struct LapClock {
+  Clock::time_point last = Clock::now();
+  int lap = LAP_FILE;
+  long long ns[N_LAPS] = {};
+
+  // The lap in progress ends here; `next` begins.
+  void enter(int next) {
+    const Clock::time_point now = Clock::now();
+    ns[lap] +=
+        std::chrono::duration_cast<std::chrono::nanoseconds>(now - last).count();
+    last = now;
+    lap = next;
+  }
+};
+
+// ---------------------------------------------------------------------------
 // Separable antialiased triangle-filter resize (Pillow's BILINEAR).
 // ---------------------------------------------------------------------------
 struct ResampleKernel {
@@ -149,7 +186,8 @@ enum Status {
 int decode_buffer(const uint8_t* buf, size_t len, int out_h, int out_w,
                   const float* mean, const float* stdv, float* out,
                   int prescale_margin, std::vector<uint8_t>& pixels,
-                  std::vector<float>& rscratch) {
+                  std::vector<float>& rscratch, LapClock& clock) {
+  clock.enter(LAP_JPEG_HEAD);
   jpeg_decompress_struct cinfo;
   ErrMgr err;
   cinfo.err = jpeg_std_error(&err.pub);
@@ -202,6 +240,7 @@ int decode_buffer(const uint8_t* buf, size_t len, int out_h, int out_w,
     return ERR_FORMAT;
   }
   pixels.resize(static_cast<size_t>(w) * h * 3);
+  clock.enter(LAP_JPEG_SCAN);
   while (cinfo.output_scanline < cinfo.output_height) {
     JSAMPROW row = pixels.data() + static_cast<size_t>(cinfo.output_scanline) * w * 3;
     jpeg_read_scanlines(&cinfo, &row, 1);
@@ -209,6 +248,7 @@ int decode_buffer(const uint8_t* buf, size_t len, int out_h, int out_w,
   jpeg_finish_decompress(&cinfo);
   jpeg_destroy_decompress(&cinfo);
 
+  clock.enter(LAP_RESIZE);
   if (w == out_w && h == out_h) {
     for (size_t i = 0; i < static_cast<size_t>(out_h) * out_w * 3; ++i) {
       out[i] = static_cast<float>(pixels[i]);
@@ -217,6 +257,7 @@ int decode_buffer(const uint8_t* buf, size_t len, int out_h, int out_w,
     resize_rgb(pixels.data(), h, w, out, out_h, out_w, rscratch);
   }
   // [0,255] -> ([0,1] - mean) / std, fused here so Python never touches pixels.
+  clock.enter(LAP_NORMALIZE);
   const float inv255 = 1.0f / 255.0f;
   float scale[3], shift[3];
   for (int c = 0; c < 3; ++c) {
@@ -235,7 +276,7 @@ int decode_buffer(const uint8_t* buf, size_t len, int out_h, int out_w,
 int decode_file(const char* path, int out_h, int out_w, const float* mean,
                 const float* stdv, float* out, int prescale_margin,
                 std::vector<uint8_t>& filebuf, std::vector<uint8_t>& pixels,
-                std::vector<float>& rscratch) {
+                std::vector<float>& rscratch, LapClock& clock) {
   FILE* f = std::fopen(path, "rb");
   if (!f) return ERR_OPEN;
   std::fseek(f, 0, SEEK_END);
@@ -250,17 +291,21 @@ int decode_file(const char* path, int out_h, int out_w, const float* mean,
   std::fclose(f);
   if (got != filebuf.size()) return ERR_OPEN;
   return decode_buffer(filebuf.data(), filebuf.size(), out_h, out_w, mean, stdv,
-                       out, prescale_margin, pixels, rscratch);
+                       out, prescale_margin, pixels, rscratch, clock);
 }
 
 // Process-wide counters of what the batch entry point's worker threads did,
 // read by the loader before and after a call (the difference is that call's
-// share): images refused (left to the caller's fallback) and nanoseconds the
-// workers spent inside a decode. busy / (threads x wall) says whether N
-// workers were N cores' worth of work. Two clock reads an image (~50 ns)
+// share) and written on its `loader/decode` span (data/pipeline.py): images
+// refused (left to the caller's fallback) -> `fallbacks`; nanoseconds the
+// workers spent inside a decode -> `thread_busy_s` (busy / (threads x wall)
+// says whether N workers were N cores' worth of work); and those nanoseconds
+// by lap -> `stage_s` (which of file, jpeg, resize, normalize an image's
+// milliseconds go to) and `jpeg_scan_s` (how much of jpeg is its scanline
+// loop). Always on: six clock reads and five relaxed adds an image (~0.15 us)
 // against milliseconds of decode.
 std::atomic<long long> g_refused(0);
-std::atomic<long long> g_busy_ns(0);
+std::atomic<long long> g_lap_ns[N_LAPS];  // zero-initialized (static storage)
 
 }  // namespace
 
@@ -273,8 +318,9 @@ int mpt_decode_one(const uint8_t* buf, size_t len, int out_h, int out_w,
   try {
     std::vector<uint8_t> pixels;
     std::vector<float> rs;
+    LapClock clock;  // not counted: the counters are the batch entry point's
     return decode_buffer(buf, len, out_h, out_w, mean, stdv, out,
-                         prescale_margin, pixels, rs);
+                         prescale_margin, pixels, rs, clock);
   } catch (...) {
     return ERR_DECODE;  // allocation failure: per-item error, never a throw
   }
@@ -299,20 +345,20 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
       const int i = next.fetch_add(1);
       if (i >= n) return;
       int st;
-      const auto t0 = std::chrono::steady_clock::now();
+      LapClock clock;
       try {
         st = decode_file(paths[i], out_h, out_w, mean, stdv, out + stride * i,
-                         prescale_margin, filebuf, pixels, rs);
+                         prescale_margin, filebuf, pixels, rs, clock);
       } catch (...) {
         // e.g. std::bad_alloc from a header declaring absurd dimensions
         // (libjpeg permits up to 65500x65500). The contract is per-item
         // failure, never thread/process death.
         st = ERR_DECODE;
       }
-      g_busy_ns.fetch_add(std::chrono::duration_cast<std::chrono::nanoseconds>(
-                              std::chrono::steady_clock::now() - t0)
-                              .count(),
-                          std::memory_order_relaxed);
+      clock.enter(N_LAPS);  // closes the lap the image ended (or stopped) in
+      for (int s = 0; s < N_LAPS; ++s) {
+        g_lap_ns[s].fetch_add(clock.ns[s], std::memory_order_relaxed);
+      }
       statuses[i] = st;
       if (st != OK) {
         // A failed decode may have partially written its slot; zero it so
@@ -335,13 +381,25 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
   return failures.load();
 }
 
-// out2 = {images refused, nanoseconds inside a decode}, both since the
-// library was loaded, over every mpt_decode_batch call.
-void mpt_decode_counters(long long* out2) {
-  out2[0] = g_refused.load(std::memory_order_relaxed);
-  out2[1] = g_busy_ns.load(std::memory_order_relaxed);
+// out7 = {images refused, nanoseconds inside a decode, then those nanoseconds
+// by stage: file, jpeg, resize, normalize (they sum to the second value),
+// then the part of jpeg inside its scanline loop}, all since the library was
+// loaded, over every mpt_decode_batch call.
+void mpt_decode_counters(long long* out7) {
+  long long lap[N_LAPS], busy = 0;
+  for (int s = 0; s < N_LAPS; ++s) {
+    lap[s] = g_lap_ns[s].load(std::memory_order_relaxed);
+    busy += lap[s];
+  }
+  out7[0] = g_refused.load(std::memory_order_relaxed);
+  out7[1] = busy;
+  out7[2] = lap[LAP_FILE];
+  out7[3] = lap[LAP_JPEG_HEAD] + lap[LAP_JPEG_SCAN];
+  out7[4] = lap[LAP_RESIZE];
+  out7[5] = lap[LAP_NORMALIZE];
+  out7[6] = lap[LAP_JPEG_SCAN];
 }
 
-int mpt_abi_version() { return 3; }
+int mpt_abi_version() { return 4; }
 
 }  // extern "C"

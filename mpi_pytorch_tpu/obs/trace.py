@@ -66,13 +66,15 @@ def trace_path(path: str, process: int, process_count: int) -> str:
 
 
 class Timed:
-    """What ``Tracer.span`` yields: ``seconds`` is how long the block took,
-    set when it closes."""
+    """What ``Tracer.span`` yields: ``seconds`` is how long the block took and
+    ``ended_us`` when it closed (the tracer's clock, as an event's ``ts``),
+    both set when it closes."""
 
-    __slots__ = ("seconds",)
+    __slots__ = ("seconds", "ended_us")
 
     def __init__(self):
         self.seconds = 0.0
+        self.ended_us = 0.0
 
 
 class Tracer:
@@ -159,6 +161,12 @@ class Tracer:
             yield timed
         finally:
             timed.seconds = self.end(token, args)
+            timed.ended_us = token[2] + timed.seconds * 1e6
+
+    def ms_since(self, timed: Timed) -> float:
+        """Milliseconds from the close of the span that yielded ``timed`` to
+        now: what a finished piece of work then waited for."""
+        return (self._now_us() - timed.ended_us) / 1e3
 
     def instant(
         self, name: str, args: Mapping[str, Any] | None = None, *, once: bool = False

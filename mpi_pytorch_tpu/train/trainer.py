@@ -516,21 +516,39 @@ def device_prefetch(
     tracer (args ``bytes``, ``epoch``, ``batch`` — the last two only name
     the batch, which is what lets a device trace be joined to it): the
     host's share of the transfer. A finished batch then waits in ``buf``
-    until ``depth`` more are behind it."""
+    until ``depth`` more are behind it; on a traced run the instant
+    ``prefetch/yield`` marks the moment it is handed to the step loop (args
+    the ``h2d`` span's ``epoch`` and ``batch``, ``held_ms`` since that span
+    closed, ``behind``: the batches still in ``buf``). With two batches an
+    epoch and ``depth`` 2 the first is held for the whole decode of the
+    second."""
     from collections import deque
 
     tracer = obs_trace.current()
-    buf = deque()
+    buf = deque()  # (batch index, its h2d span's Timed, the sharded batch)
+
+    def hand_over():
+        batch_i, h2d, batch = buf.popleft()
+        if tracer.enabled:
+            tracer.instant(
+                "prefetch/yield",
+                args={
+                    "epoch": epoch, "batch": batch_i,
+                    "held_ms": tracer.ms_since(h2d), "behind": len(buf),
+                },
+            )
+        return batch
+
     for batch_i, (images, labels) in enumerate(batches, start=start_step):
         args = {"epoch": epoch, "batch": batch_i}
-        with tracer.span("h2d", args=args):
+        with tracer.span("h2d", args=args) as h2d:
             images, labels = pad_batch(images, labels, host_batch)
             args["bytes"] = int(images.nbytes + labels.nbytes)
-            buf.append(shard_batch((images, labels), mesh))
+            buf.append((batch_i, h2d, shard_batch((images, labels), mesh)))
         if len(buf) > depth:
-            yield buf.popleft()
+            yield hand_over()
     while buf:
-        yield buf.popleft()
+        yield hand_over()
 
 
 def build_device_cache(cfg: Config, manifest, loader, mesh):

@@ -242,9 +242,89 @@ def test_native_counter_counts_refusals_and_busy_time(tmp_path):
         fallback=lambda p: np.zeros((64, 64, 3), np.float32),
     )
     wall_ns = (time.perf_counter() - t0) * 1e9
-    refused, busy_ns = (a - b for a, b in zip(native.counters(), before))
+    refused, busy_ns = (a - b for a, b in zip(native.counters()[:2], before[:2]))
     assert refused == 1
     assert 0 < busy_ns <= 4 * wall_ns
+
+
+def _counters_around(call):
+    """``(refused, busy_ns, {stage: ns})`` that ``call()`` added."""
+    refused, busy_ns, stage_ns = native.counters()
+    call()
+    refused_now, busy_ns_now, stage_ns_now = native.counters()
+    assert native.STAGES == ("file", "jpeg", "resize", "normalize")
+    assert list(stage_ns_now) == [*native.STAGES, "jpeg_scan"]
+    took = {k: stage_ns_now[k] - stage_ns[k] for k in stage_ns_now}
+    # The scanline loop is a part of ``jpeg``, not a fifth stage.
+    scan_ns = took.pop("jpeg_scan")
+    assert 0 <= scan_ns <= took["jpeg"]
+    return refused_now - refused, busy_ns_now - busy_ns, took, scan_ns
+
+
+def test_native_stage_counters_split_the_busy_time(tmp_path):
+    """Beside the busy time, its four stages (ISSUE 35): every one grows for
+    a JPEG that needs a resize, none ever falls, and together they are the
+    busy time — the loop's own bookkeeping is all that may be left over
+    (1 % and a few microseconds an image)."""
+    m = _jpeg_manifest(tmp_path, n=8)  # 200 x 150 sources
+    paths = [os.path.join(m.img_dir, f) for f in m.filenames]
+    decode = lambda: native.decode_batch(paths, (64, 64), MEAN, STD, threads=3)
+    _, busy_ns, stage_ns, scan_ns = _counters_around(decode)
+    assert all(ns > 0 for ns in stage_ns.values()), stage_ns
+    assert abs(sum(stage_ns.values()) - busy_ns) <= 0.01 * busy_ns + 5_000 * len(paths)
+    # The loop decodes every pixel; the header and the start are what is left.
+    assert 0.5 * stage_ns["jpeg"] < scan_ns < stage_ns["jpeg"]
+    # Monotone: a second batch adds to each, and a batch of nothing adds nothing.
+    _, _, again, scan_again = _counters_around(decode)
+    assert all(ns > 0 for ns in again.values()) and scan_again > 0
+    assert _counters_around(lambda: None) == (0, 0, dict.fromkeys(native.STAGES, 0), 0)
+
+
+def test_refused_image_leaves_its_time_on_the_stage_it_stopped_in(tmp_path):
+    """A CMYK JPEG is read whole, refused after libjpeg's header and handed
+    to the fallback: ``file`` and ``jpeg`` grow, the stages after the refusal
+    do not, and ``refused`` counts it."""
+    cmyk = tmp_path / "cmyk.jpg"
+    Image.new("CMYK", (80, 60), (10, 200, 30, 40)).save(cmyk, quality=90)
+    refused, busy_ns, stage_ns, scan_ns = _counters_around(
+        lambda: native.decode_batch(
+            [str(cmyk)], (32, 32), MEAN, STD, threads=1,
+            fallback=lambda p: np.zeros((32, 32, 3), np.float32),
+        )
+    )
+    assert refused == 1
+    assert stage_ns["file"] > 0 and stage_ns["jpeg"] > 0
+    assert stage_ns["resize"] == 0 and stage_ns["normalize"] == 0 and scan_ns == 0
+    assert sum(stage_ns.values()) == busy_ns
+
+
+def test_library_of_the_old_abi_is_rebuilt_once(tmp_path, monkeypatch):
+    """A cached library that loads but answers another ABI version (3: two
+    counters where this source writes seven) is unmapped, deleted and rebuilt
+    from the source — never skipped, and never answered by the stale mapping
+    dlopen keeps for a path it has already loaded."""
+    import subprocess
+
+    with open(native._SRC) as f:
+        src = f.read()
+    current = f"int mpt_abi_version() {{ return {native._ABI_VERSION}; }}"
+    assert current in src
+    old_src = tmp_path / "old.cpp"
+    old_src.write_text(src.replace(current, "int mpt_abi_version() { return 3; }"))
+    cached = str(tmp_path / native._lib_name())
+    subprocess.run(
+        ["g++", "-O0", "-shared", "-fPIC", "-std=c++17", str(old_src), "-o", cached,
+         "-ljpeg", "-pthread"],
+        check=True, capture_output=True, timeout=120,
+    )
+    monkeypatch.setattr(native, "_candidate_paths", lambda name: [cached])
+    monkeypatch.setattr(native, "_build_error", None)
+    lib = native._try_load()
+    assert lib is not None, native._build_error
+    assert native._abi_version(lib) == native._ABI_VERSION
+    out = (native.ctypes.c_longlong * 7)()
+    lib.mpt_decode_counters(out)  # seven values, not two: no write past the end
+    assert list(out) == [0] * 7
 
 
 def _traced_epoch(spans_of, loader):
@@ -272,7 +352,13 @@ def test_loader_spans_per_batch_with_their_args(tmp_path, spans_of, source, kw):
     batches, spans = _traced_epoch(spans_of, loader)
     assert len(batches) == 3
     (life,) = spans["loader/epoch"]
-    assert life["args"] == {"epoch": 0, "batches": 3}
+    # Beside what it produced: the whole process's CPU seconds over the
+    # producer's life (every thread's, so it may pass the span's own length)
+    # and the cores the process may run on (ISSUE 35).
+    assert {k: life["args"][k] for k in ("epoch", "batches")} == {"epoch": 0, "batches": 3}
+    assert set(life["args"]) == {"epoch", "batches", "cpu_s", "host_cpus"}
+    assert life["args"]["cpu_s"] > 0
+    assert 1 <= life["args"]["host_cpus"] == len(os.sched_getaffinity(0))
     decodes = spans["loader/decode"]
     assert len(decodes) == 3
     for e in decodes:
@@ -281,6 +367,17 @@ def test_loader_spans_per_batch_with_their_args(tmp_path, spans_of, source, kw):
         assert args["fallbacks"] == 0 and args["quarantined"] == 0
         assert 0 < args["thread_busy_s"] <= 3 * e["dur"] / 1e6 * 1.05
         assert life["ts"] <= e["ts"] and e["ts"] + e["dur"] <= life["ts"] + life["dur"]
+        # The C decoder's seconds by stage, which are its busy seconds; the
+        # Python paths have no stages and say nothing.
+        if source == "native":
+            assert list(args["stage_s"]) == ["file", "jpeg", "resize", "normalize"]
+            assert all(v > 0 for v in args["stage_s"].values())
+            assert sum(args["stage_s"].values()) == pytest.approx(
+                args["thread_busy_s"], rel=0.01, abs=5e-6 * args["images"]
+            )
+            assert 0 < args["jpeg_scan_s"] < args["stage_s"]["jpeg"]
+        else:
+            assert "stage_s" not in args and "jpeg_scan_s" not in args
     # The Python paths time themselves as decode.cpp does; the C path does
     # not touch the Python counter.
     assert (loader._py_busy_ns > 0) == (source == "pil")
