@@ -22,12 +22,16 @@ time with no producer alive; args ``cpu_s``, the CPU seconds of the whole
 process over that life, and ``host_cpus``, the cores it may run on),
 ``loader/decode`` per batch (args ``images``,
 ``source``, ``threads``, ``fallbacks``, ``quarantined``, ``thread_busy_s``
-— the seconds the decode workers were inside a decode — and, for the native
+— the seconds the decode workers were inside a decode —, ``wrote``, the
+dtype the source produced, and, for the native
 source, ``stage_s``: those seconds by stage of the C decoder, with
 ``jpeg_scan_s``, the scanline loop's part of its ``jpeg`` stage; all from
 counters kept where the decode happens and read only while a tracer records),
-``loader/cast`` (the batch's dtype conversion, on
-that one thread) and ``loader/put`` (blocked on a full queue: the consumer is
+``loader/cast`` (in a loader whose batch dtype is not float32: what the
+producer thread converts itself — the PIL and pack sources' float32 batches,
+arg ``converted`` the rows it converted; ~0 and ``converted`` 0 on the native
+path, whose workers store the batch dtype) and ``loader/put`` (blocked on a
+full queue: the consumer is
 the slower side); on the consumer's thread ``loader/get`` (blocked on an
 empty queue: the producer is). docs/OBSERVABILITY.md "Trace spans".
 """
@@ -213,14 +217,15 @@ class DataLoader:
         # Native C++ batched ingest (mpi_pytorch_tpu/native): one GIL-released
         # call decodes the whole batch on C threads. Auto-falls back to the
         # PIL thread pool when the toolchain/libjpeg is unavailable. (Its
-        # fused output is normalized f32, so raw-uint8 mode uses PIL.)
+        # fused output is normalized floats, so raw-uint8 mode uses PIL.)
         self.native_decode = False
         if native_decode and not synthetic and self._pack is None and not self.raw_uint8:
             from mpi_pytorch_tpu import native as _native
 
             self.native_decode = _native.available()
         # bfloat16 batches halve host→device transfer (the step computes in
-        # bf16 anyway); decode/normalize still run in float32 on the host.
+        # bf16 anyway); decode/normalize still run in float32 on the host,
+        # and the native decoder's workers store the batch dtype themselves.
         if image_dtype == "bfloat16":
             import ml_dtypes
 
@@ -433,11 +438,12 @@ class DataLoader:
         return normalize_image(decode_image(path, self.image_size))
 
     def _load_batch(self, idx: np.ndarray, pool: ThreadPoolExecutor) -> np.ndarray:
-        """Load a batch of images [B,H,W,3]: normalized f32, or RAW uint8
-        pixels in ``raw_uint8`` mode (normalization then happens on device,
-        train/step.py ``ingest_images``). Sources in order: packed mmap rows
-        when a pack is resolved, else one GIL-released native call when
-        available, else the PIL thread pool."""
+        """Load a batch of images [B,H,W,3]: normalized f32 (the native
+        source: normalized ``image_dtype``, stored by its workers), or RAW
+        uint8 pixels in ``raw_uint8`` mode (normalization then happens on
+        device, train/step.py ``ingest_images``). Sources in order: packed
+        mmap rows when a pack is resolved, else one GIL-released native call
+        when available, else the PIL thread pool."""
         if self._pack is not None:
             if self.raw_uint8:
                 # The whole host pipeline collapses to an mmap row gather;
@@ -481,6 +487,7 @@ class DataLoader:
                 threads=self.num_workers,
                 prescale_margin=self.decode_prescale,
                 fallback=robust_fallback,
+                dtype=self.image_dtype,
             )
         rows = list(pool.map(self._decode_with_retries, idx))
         bad = [k for k, r in enumerate(rows) if r is None]
@@ -624,6 +631,7 @@ class DataLoader:
                 t0 = time.perf_counter()
                 stacked = self._load_batch(idx, pool)
                 call_s = time.perf_counter() - t0
+                args["wrote"] = str(stacked.dtype)
                 refused_now, busy_ns_now, stage_ns_now = self._decode_counters()
                 args["fallbacks"] = refused_now - refused
                 args["quarantined"] = self.bad_samples - quarantined
@@ -641,11 +649,20 @@ class DataLoader:
                     args["stage_s"] = took
             return stacked
 
+        # ``loader/cast`` in every batch of a loader whose floats are not
+        # float32, around what this thread converts itself: a float32 batch
+        # of the PIL pool or a pack. The native source's workers stored
+        # ``image_dtype``, and the span closes in microseconds.
+        may_convert = self.image_dtype != np.float32 and not self.raw_uint8
+
         def decode_one_batch(idx, pool):
             stacked = load_batch(idx, pool)
-            if stacked.dtype != self.image_dtype:
-                with tracer.span("loader/cast"):
-                    stacked = stacked.astype(self.image_dtype)
+            if may_convert:
+                args = {"converted": 0}
+                with tracer.span("loader/cast", args=args):
+                    if stacked.dtype != self.image_dtype:
+                        args["converted"] = len(stacked)
+                        stacked = stacked.astype(self.image_dtype)
             if fill_cache:
                 self._cache_images[idx] = stacked
                 self._cache_filled[idx] = True

@@ -1,4 +1,5 @@
-"""Native (C++) host-ingest library: batched JPEG decode→resize→normalize.
+"""Native (C++) host-ingest library: batched JPEG decode→resize→normalize,
+stored in the dtype the caller asks for (float32 or bfloat16).
 
 The reference's ingest parallelism is native code wearing Python clothes —
 torch DataLoader worker processes (``data_loader.py:29-39``) and three
@@ -39,9 +40,20 @@ _LIB_PREFIX = "_mptnative_"
 # nanoseconds ``mpt_decode_counters`` writes after its first two values: the
 # four stages of an image, which are its busy time, then ``jpeg_scan``, the
 # scanline loop inside ``jpeg``.
-_ABI_VERSION = 4
+_ABI_VERSION = 5
 STAGES = ("file", "jpeg", "resize", "normalize")
 _COUNTER_NAMES = STAGES + ("jpeg_scan",)
+
+
+def _elem(dtype) -> int:
+    """decode.cpp's ``Elem`` for an output dtype: 0 float32, 1 bfloat16."""
+    dtype = np.dtype(dtype)
+    if dtype == np.float32:
+        return 0
+    if dtype.name == "bfloat16":
+        return 1
+    raise ValueError(f"native decode writes float32 or bfloat16, not {dtype}")
+
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -144,15 +156,26 @@ def _try_load() -> ctypes.CDLL | None:
             ctypes.c_int,
             ctypes.POINTER(ctypes.c_float),
             ctypes.POINTER(ctypes.c_float),
-            ctypes.POINTER(ctypes.c_float),
+            ctypes.c_void_p,
+            ctypes.c_int,
             ctypes.c_int,
             ctypes.c_int,
             ctypes.POINTER(ctypes.c_int),
         ]
+        lib.mpt_normalize_store.restype = ctypes.c_int
+        lib.mpt_normalize_store.argtypes = [
+            ctypes.POINTER(ctypes.c_float),
+            ctypes.c_int,
+            ctypes.POINTER(ctypes.c_float),
+            ctypes.POINTER(ctypes.c_float),
+            ctypes.c_void_p,
+            ctypes.c_int,
+        ]
         lib.mpt_decode_counters.restype = None
         lib.mpt_decode_counters.argtypes = [ctypes.POINTER(ctypes.c_longlong)]
         # (decode.cpp also exports mpt_decode_one for ad-hoc C consumers and
-        # microbenchmarks; the framework only uses the batch entry point.)
+        # microbenchmarks; the framework only uses the batch entry point, and
+        # the tests mpt_normalize_store for the store's rounding.)
         return lib
     _build_error = last_err
     return None
@@ -215,13 +238,18 @@ def decode_batch(
     threads: int = 8,
     prescale_margin: int = 2,
     fallback=None,
+    dtype=np.float32,
 ) -> np.ndarray:
-    """Decode+resize+normalize a batch of JPEG files → f32 [N,H,W,3].
+    """Decode+resize+normalize a batch of JPEG files → ``dtype`` [N,H,W,3].
 
-    One C call on ``threads`` native threads with the GIL released. Items the
-    native path refuses (corrupt file, CMYK, ...) are retried through
-    ``fallback(path) -> normalized HWC f32`` (e.g. the PIL path) so odd files
-    degrade one at a time instead of failing the batch.
+    One C call on ``threads`` native threads with the GIL released. ``dtype``
+    is float32 or ``ml_dtypes.bfloat16``: the worker threads store it in
+    their normalize pass (bfloat16 rounded to nearest, ties to even — the
+    bits ``astype`` would give), so the caller converts nothing afterwards.
+    Items the native path refuses (corrupt file, CMYK, ...) are retried
+    through ``fallback(path) -> normalized HWC f32`` (e.g. the PIL path;
+    numpy converts that one row) so odd files degrade one at a time instead
+    of failing the batch.
 
     ``prescale_margin`` controls libjpeg DCT prescaling for large sources:
     0 = full-resolution decode (PIL bit-parity, slowest), 1 = decode just past
@@ -233,7 +261,8 @@ def decode_batch(
         raise RuntimeError(f"native decode unavailable: {_build_error}")
     n = len(paths)
     h, w = image_size
-    out = np.empty((n, h, w, 3), dtype=np.float32)
+    elem = _elem(dtype)
+    out = np.empty((n, h, w, 3), dtype=dtype)
     statuses = np.zeros(n, dtype=np.int32)
     mean32 = np.ascontiguousarray(mean, dtype=np.float32)
     std32 = np.ascontiguousarray(std, dtype=np.float32)
@@ -246,7 +275,8 @@ def decode_batch(
         w,
         mean32.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
         std32.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+        out.ctypes.data_as(ctypes.c_void_p),
+        elem,
         threads,
         prescale_margin,
         statuses.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),

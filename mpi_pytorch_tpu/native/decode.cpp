@@ -1,5 +1,8 @@
 // Native host-side image ingest: JPEG decode -> RGB -> antialiased bilinear
-// resize -> ImageNet normalize, batched over an internal thread pool.
+// resize -> ImageNet normalize, batched over an internal thread pool. The
+// output's element type is the caller's (float32 or bfloat16): the normalize
+// pass, the last one over an image's pixels, stores it, so no thread of the
+// caller converts a batch afterwards.
 //
 // Why this exists (capability parity, done TPU-host-native): the reference
 // hides Python-side decode cost behind torch DataLoader worker *processes*
@@ -70,8 +73,9 @@ enum Lap {
   LAP_JPEG_SCAN,  // the scanline loop (entropy decode, scaled IDCT, colour
                   // conversion) .. jpeg_destroy_decompress
   LAP_RESIZE,     // resize_rgb with its two make_kernel calls (or the
-                  // equal-size uint8 -> float copy)
-  LAP_NORMALIZE,  // the scale-and-shift pass
+                  // equal-size uint8 -> float copy), into the worker's scratch
+  LAP_NORMALIZE,  // the scale-and-shift pass, scratch -> the batch row in the
+                  // output's element type
   N_LAPS,
 };
 
@@ -175,6 +179,58 @@ void resize_rgb(const uint8_t* src, int in_h, int in_w, float* dst, int out_h,
   }
 }
 
+// ---------------------------------------------------------------------------
+// The output's element type (mirrored in native/__init__.py) and its store.
+// ---------------------------------------------------------------------------
+enum Elem {
+  ELEM_F32 = 0,
+  ELEM_BF16 = 1,  // the upper half of a float32, as ml_dtypes.bfloat16
+};
+
+// Bytes of one element; 0 for a type the decoder does not write.
+size_t elem_size(int elem) {
+  return elem == ELEM_F32 ? 4 : elem == ELEM_BF16 ? 2 : 0;
+}
+
+inline void store(float v, float* dst) { *dst = v; }
+
+// float32 -> bfloat16, rounded to nearest with ties to even: the rounding
+// ml_dtypes' astype does, so a batch stored here has the bits of a float32
+// batch converted afterwards. Normalized pixels are finite and small (about
+// -2.2 .. 2.7), so neither the NaN case nor an overflow of the add arises.
+inline void store(float v, uint16_t* dst) {
+  uint32_t bits;
+  std::memcpy(&bits, &v, sizeof bits);
+  bits += 0x7FFFu + ((bits >> 16) & 1u);
+  *dst = static_cast<uint16_t>(bits >> 16);
+}
+
+// [0,255] -> ([0,1] - mean) / std over n_pixels RGB pixels, stored as T.
+template <typename T>
+void normalize_store(const float* src, int n_pixels, const float* mean,
+                     const float* stdv, T* dst) {
+  const float inv255 = 1.0f / 255.0f;
+  float scale[3], shift[3];
+  for (int c = 0; c < 3; ++c) {
+    scale[c] = inv255 / stdv[c];
+    shift[c] = -mean[c] / stdv[c];
+  }
+  for (int i = 0; i < n_pixels; ++i, src += 3, dst += 3) {
+    store(src[0] * scale[0] + shift[0], dst + 0);
+    store(src[1] * scale[1] + shift[1], dst + 1);
+    store(src[2] * scale[2] + shift[2], dst + 2);
+  }
+}
+
+void normalize_store(const float* src, int n_pixels, const float* mean,
+                     const float* stdv, void* dst, int elem) {
+  if (elem == ELEM_BF16) {
+    normalize_store(src, n_pixels, mean, stdv, static_cast<uint16_t*>(dst));
+  } else {
+    normalize_store(src, n_pixels, mean, stdv, static_cast<float*>(dst));
+  }
+}
+
 // Status codes returned per item (mirrored in native/__init__.py).
 enum Status {
   OK = 0,
@@ -183,10 +239,19 @@ enum Status {
   ERR_FORMAT = 3,  // colorspace we refuse (e.g. CMYK) -> caller falls back
 };
 
+// Per-worker buffers, reused from image to image.
+struct Scratch {
+  std::vector<uint8_t> filebuf;  // the encoded file
+  std::vector<uint8_t> pixels;   // libjpeg's RGB output
+  std::vector<float> hpass;      // the resize's horizontal pass
+  std::vector<float> resized;    // [out_h, out_w, 3] in [0,255]: 196 KB at
+                                 // 128 px, so the normalize pass reads cache
+};
+
 int decode_buffer(const uint8_t* buf, size_t len, int out_h, int out_w,
-                  const float* mean, const float* stdv, float* out,
-                  int prescale_margin, std::vector<uint8_t>& pixels,
-                  std::vector<float>& rscratch, LapClock& clock) {
+                  const float* mean, const float* stdv, void* out, int elem,
+                  int prescale_margin, Scratch& scratch, LapClock& clock) {
+  std::vector<uint8_t>& pixels = scratch.pixels;
   clock.enter(LAP_JPEG_HEAD);
   jpeg_decompress_struct cinfo;
   ErrMgr err;
@@ -249,34 +314,27 @@ int decode_buffer(const uint8_t* buf, size_t len, int out_h, int out_w,
   jpeg_destroy_decompress(&cinfo);
 
   clock.enter(LAP_RESIZE);
+  const size_t n_values = static_cast<size_t>(out_h) * out_w * 3;
+  scratch.resized.resize(n_values);
+  float* resized = scratch.resized.data();
   if (w == out_w && h == out_h) {
-    for (size_t i = 0; i < static_cast<size_t>(out_h) * out_w * 3; ++i) {
-      out[i] = static_cast<float>(pixels[i]);
+    for (size_t i = 0; i < n_values; ++i) {
+      resized[i] = static_cast<float>(pixels[i]);
     }
   } else {
-    resize_rgb(pixels.data(), h, w, out, out_h, out_w, rscratch);
+    resize_rgb(pixels.data(), h, w, resized, out_h, out_w, scratch.hpass);
   }
-  // [0,255] -> ([0,1] - mean) / std, fused here so Python never touches pixels.
+  // Normalize and store the caller's element type, fused here so Python never
+  // touches pixels.
   clock.enter(LAP_NORMALIZE);
-  const float inv255 = 1.0f / 255.0f;
-  float scale[3], shift[3];
-  for (int c = 0; c < 3; ++c) {
-    scale[c] = inv255 / stdv[c];
-    shift[c] = -mean[c] / stdv[c];
-  }
-  float* p = out;
-  for (int i = 0; i < out_h * out_w; ++i, p += 3) {
-    p[0] = p[0] * scale[0] + shift[0];
-    p[1] = p[1] * scale[1] + shift[1];
-    p[2] = p[2] * scale[2] + shift[2];
-  }
+  normalize_store(resized, out_h * out_w, mean, stdv, out, elem);
   return OK;
 }
 
 int decode_file(const char* path, int out_h, int out_w, const float* mean,
-                const float* stdv, float* out, int prescale_margin,
-                std::vector<uint8_t>& filebuf, std::vector<uint8_t>& pixels,
-                std::vector<float>& rscratch, LapClock& clock) {
+                const float* stdv, void* out, int elem, int prescale_margin,
+                Scratch& scratch, LapClock& clock) {
+  std::vector<uint8_t>& filebuf = scratch.filebuf;
   FILE* f = std::fopen(path, "rb");
   if (!f) return ERR_OPEN;
   std::fseek(f, 0, SEEK_END);
@@ -291,7 +349,7 @@ int decode_file(const char* path, int out_h, int out_w, const float* mean,
   std::fclose(f);
   if (got != filebuf.size()) return ERR_OPEN;
   return decode_buffer(filebuf.data(), filebuf.size(), out_h, out_w, mean, stdv,
-                       out, prescale_margin, pixels, rscratch, clock);
+                       out, elem, prescale_margin, scratch, clock);
 }
 
 // Process-wide counters of what the batch entry point's worker threads did,
@@ -311,44 +369,50 @@ std::atomic<long long> g_lap_ns[N_LAPS];  // zero-initialized (static storage)
 
 extern "C" {
 
-// Decode one in-memory JPEG into out[out_h*out_w*3] (normalized f32 HWC).
+// Decode one in-memory JPEG into out[out_h*out_w*3] (normalized HWC of
+// out_elem, an Elem).
 int mpt_decode_one(const uint8_t* buf, size_t len, int out_h, int out_w,
-                   const float* mean, const float* stdv, float* out,
-                   int prescale_margin) {
+                   const float* mean, const float* stdv, void* out,
+                   int out_elem, int prescale_margin) {
+  if (elem_size(out_elem) == 0) return ERR_FORMAT;
   try {
-    std::vector<uint8_t> pixels;
-    std::vector<float> rs;
+    Scratch scratch;
     LapClock clock;  // not counted: the counters are the batch entry point's
-    return decode_buffer(buf, len, out_h, out_w, mean, stdv, out,
-                         prescale_margin, pixels, rs, clock);
+    return decode_buffer(buf, len, out_h, out_w, mean, stdv, out, out_elem,
+                         prescale_margin, scratch, clock);
   } catch (...) {
     return ERR_DECODE;  // allocation failure: per-item error, never a throw
   }
 }
 
-// Decode n files into out[n*out_h*out_w*3] on n_threads C++ threads.
-// statuses[i] receives a Status per item; failed items leave zeros for the
-// caller's PIL fallback. The GIL is released for the whole call (ctypes).
+// Decode n files into out[n*out_h*out_w*3] of out_elem (an Elem: the stride
+// follows it) on n_threads C++ threads. statuses[i] receives a Status per
+// item; failed items leave zeros for the caller's PIL fallback. Returns the
+// number of failed items, -1 for an element type it does not know. The GIL is
+// released for the whole call (ctypes).
 int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
-                     const float* mean, const float* stdv, float* out,
-                     int n_threads, int prescale_margin, int* statuses) {
+                     const float* mean, const float* stdv, void* out,
+                     int out_elem, int n_threads, int prescale_margin,
+                     int* statuses) {
+  if (elem_size(out_elem) == 0) return -1;
   if (n <= 0) return 0;
   if (n_threads < 1) n_threads = 1;
   if (n_threads > n) n_threads = n;
-  const size_t stride = static_cast<size_t>(out_h) * out_w * 3;
+  const size_t stride =
+      static_cast<size_t>(out_h) * out_w * 3 * elem_size(out_elem);  // bytes
+  uint8_t* const rows = static_cast<uint8_t*>(out);
   std::atomic<int> next(0);
   std::atomic<int> failures(0);
   auto worker = [&]() {
-    std::vector<uint8_t> filebuf, pixels;
-    std::vector<float> rs;
+    Scratch scratch;
     for (;;) {
       const int i = next.fetch_add(1);
       if (i >= n) return;
       int st;
       LapClock clock;
       try {
-        st = decode_file(paths[i], out_h, out_w, mean, stdv, out + stride * i,
-                         prescale_margin, filebuf, pixels, rs, clock);
+        st = decode_file(paths[i], out_h, out_w, mean, stdv, rows + stride * i,
+                         out_elem, prescale_margin, scratch, clock);
       } catch (...) {
         // e.g. std::bad_alloc from a header declaring absurd dimensions
         // (libjpeg permits up to 65500x65500). The contract is per-item
@@ -363,8 +427,9 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
       if (st != OK) {
         // A failed decode may have partially written its slot; zero it so
         // the documented contract (failed items leave zeros) holds even for
-        // callers that skip the per-item fallback.
-        std::memset(out + stride * i, 0, stride * sizeof(float));
+        // callers that skip the per-item fallback. (All-zero bytes are 0.0 in
+        // either element type.)
+        std::memset(rows + stride * i, 0, stride);
         failures.fetch_add(1);
         g_refused.fetch_add(1, std::memory_order_relaxed);
       }
@@ -400,6 +465,15 @@ void mpt_decode_counters(long long* out7) {
   out7[6] = lap[LAP_JPEG_SCAN];
 }
 
-int mpt_abi_version() { return 4; }
+// The normalize pass alone, as the decoder runs it: src[n_pixels*3] float32 in
+// [0,255] -> dst of out_elem. For tests of the store's rounding.
+int mpt_normalize_store(const float* src, int n_pixels, const float* mean,
+                        const float* stdv, void* dst, int out_elem) {
+  if (elem_size(out_elem) == 0) return -1;
+  normalize_store(src, n_pixels, mean, stdv, dst, out_elem);
+  return 0;
+}
+
+int mpt_abi_version() { return 5; }
 
 }  // extern "C"

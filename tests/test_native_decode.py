@@ -10,12 +10,16 @@ workers, ``data_loader.py:29-39``; MPI preprocessing ranks,
   fixed-point rounding (<1.5/255 per pixel)
 - DCT prescale modes trade PIL-exactness for IDCT work, with bounded deviation
 - corrupt / non-JPEG items fall back to PIL one at a time
+- the output dtype is the caller's: a bfloat16 batch has the bits of the
+  float32 batch converted afterwards (round to nearest, ties to even)
 """
 
 from __future__ import annotations
 
+import ctypes
 import os
 
+import ml_dtypes
 import numpy as np
 import pytest
 from PIL import Image
@@ -32,6 +36,7 @@ from mpi_pytorch_tpu.data.pipeline import (
 
 MEAN = np.asarray(IMAGENET_MEAN, dtype=np.float32)
 STD = np.asarray(IMAGENET_STD, dtype=np.float32)
+BF16 = np.dtype(ml_dtypes.bfloat16)
 
 pytestmark = pytest.mark.skipif(
     not native.available(), reason=f"native decode unavailable: {native.build_error()}"
@@ -178,6 +183,156 @@ def test_loader_host_cache_matches_direct_decode(tmp_path):
         np.testing.assert_array_equal(di, fi)
         np.testing.assert_array_equal(fi, ai)
         np.testing.assert_array_equal(fl, al)
+
+
+# ---------------------------------------------------------------------------
+# the output dtype is the caller's (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+
+def _float_ptr(a):
+    return a.ctypes.data_as(ctypes.POINTER(ctypes.c_float))
+
+
+def _source_jpeg(tmp_path, kind):
+    """A JPEG that takes the decoder's ``kind`` of way to 64 x 64: ``resized``
+    (400 x 300: each prescale margin picks another IDCT scale), ``exact``
+    (already 64 x 64: the no-resize branch) or ``gray`` (one component)."""
+    p = tmp_path / f"{kind}.jpg"
+    if kind == "gray":
+        gray = (synthetic_image(2, (150, 150))[:, :, 0] * 255).astype(np.uint8)
+        Image.fromarray(gray, mode="L").save(p, quality=95)
+    else:
+        size = (300, 400) if kind == "resized" else (64, 64)
+        _write_jpeg(p, (synthetic_image(4, size) * 255).astype(np.uint8))
+    return str(p)
+
+
+@pytest.mark.parametrize("threads", [1, 3])
+@pytest.mark.parametrize("kind", ["resized", "exact", "gray"])
+@pytest.mark.parametrize("prescale_margin", [0, 1, 2])
+def test_bfloat16_batch_is_the_float32_batch_converted_bit_for_bit(
+    tmp_path, prescale_margin, kind, threads
+):
+    """The workers' store rounds as ``astype`` does: what the step receives
+    is what it received when the producer thread converted the batch."""
+    paths = [_source_jpeg(tmp_path, kind)] * 4
+    kw = dict(threads=threads, prescale_margin=prescale_margin)
+    f32 = native.decode_batch(paths, (64, 64), MEAN, STD, **kw)
+    bf16 = native.decode_batch(paths, (64, 64), MEAN, STD, dtype=BF16, **kw)
+    assert f32.dtype == np.float32 and bf16.dtype == BF16 and bf16.shape == f32.shape
+    assert np.abs(f32).max() > 0.5  # a picture, not a row of zeros
+    np.testing.assert_array_equal(bf16.view(np.uint16), f32.astype(BF16).view(np.uint16))
+
+
+# float32 bit patterns around bfloat16's rounding points, and the bfloat16
+# bits nearest-even gives: exact ties go to the even neighbour, whichever side.
+_ROUNDING = [
+    (0x3F808000, 0x3F80),  # tie, lower neighbour even: down
+    (0x3F818000, 0x3F82),  # tie, lower neighbour odd: up
+    (0x3F808001, 0x3F81),  # just over the tie: up
+    (0x3F817FFF, 0x3F81),  # just under the tie: down
+    (0xBF808000, 0xBF80),  # the same ties, negative
+    (0xBF818000, 0xBF82),
+    (0x3F7F8000, 0x3F80),  # a tie that carries into the exponent
+    (0x40490FDB, 0x4049),  # pi
+    (0x00000000, 0x0000),
+]
+
+
+@pytest.mark.parametrize("elem,dtype", [(0, np.float32), (1, BF16)])
+def test_the_store_rounds_to_nearest_ties_to_even(elem, dtype):
+    """``mpt_normalize_store`` is the decoder's own normalize pass; with mean
+    0 and std 1/255 its arithmetic is ``x * 1 + -0``, so hand-picked float32
+    bits reach the store unchanged."""
+    src = np.array([b for b, _ in _ROUNDING], np.uint32).view(np.float32)
+    assert len(src) % 3 == 0
+    mean = np.zeros(3, np.float32)
+    std = np.full(3, np.float32(1) / np.float32(255), np.float32)
+    dst = np.full(len(src), 1, dtype)
+    rc = native.load().mpt_normalize_store(
+        _float_ptr(src), len(src) // 3, _float_ptr(mean), _float_ptr(std),
+        dst.ctypes.data_as(ctypes.c_void_p), elem,
+    )
+    assert rc == 0
+    if dtype == np.float32:
+        np.testing.assert_array_equal(dst.view(np.uint32), src.view(np.uint32))
+    else:
+        assert [hex(b) for b in dst.view(np.uint16)] == [hex(b) for _, b in _ROUNDING]
+        np.testing.assert_array_equal(dst.view(np.uint16), src.astype(BF16).view(np.uint16))
+
+
+def test_an_element_type_the_decoder_does_not_write_is_refused(tmp_path):
+    path = _source_jpeg(tmp_path, "exact")
+    with pytest.raises(ValueError, match="float32 or bfloat16"):
+        native.decode_batch([path], (64, 64), MEAN, STD, dtype=np.float16)
+    lib = native.load()
+    out = np.zeros(3, np.float32)
+    assert lib.mpt_normalize_store(
+        _float_ptr(out), 1, _float_ptr(MEAN), _float_ptr(STD),
+        out.ctypes.data_as(ctypes.c_void_p), 7,
+    ) == -1
+
+
+@pytest.mark.parametrize("dtype", [np.dtype(np.float32), BF16], ids=str)
+def test_corrupt_item_in_a_batch_of_either_dtype(tmp_path, dtype):
+    """The fallback's float32 row lands converted in the batch's dtype; and
+    the C entry point, with no fallback after it, leaves the failed item's
+    row zeroed in the dtype's width — its neighbours untouched."""
+    good = _source_jpeg(tmp_path, "exact")
+    bad = tmp_path / "bad.jpg"
+    bad.write_bytes(b"this is not a jpeg")
+    row = normalize_image(synthetic_image(9, (64, 64)))
+    paths = [good, str(bad), good]
+    out = native.decode_batch(
+        paths, (64, 64), MEAN, STD, fallback=lambda p: row, dtype=dtype
+    )
+    assert out.dtype == dtype
+    np.testing.assert_array_equal(out[1], row.astype(dtype))
+    np.testing.assert_array_equal(out[0], out[2])
+    with pytest.raises(RuntimeError, match="native decode failed for 1 item"):
+        native.decode_batch(paths, (64, 64), MEAN, STD, dtype=dtype)
+    # The same call as decode_batch makes, on a buffer that starts as ones.
+    raw = np.ones((3, 64, 64, 3), dtype)
+    statuses = np.zeros(3, np.int32)
+    c_paths = (ctypes.c_char_p * 3)(*[os.fsencode(p) for p in paths])
+    failures = native.load().mpt_decode_batch(
+        c_paths, 3, 64, 64, _float_ptr(MEAN), _float_ptr(STD),
+        raw.ctypes.data_as(ctypes.c_void_p), native._elem(dtype), 2, 2,
+        statuses.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
+    )
+    assert failures == 1 and list(statuses != 0) == [False, True, False]
+    assert not raw[1].view(np.uint8).any()
+    np.testing.assert_array_equal(raw[0], out[0])
+    np.testing.assert_array_equal(raw[2], out[2])
+
+
+@pytest.mark.parametrize("host_cache", [False, True])
+def test_bfloat16_loader_yields_the_bits_the_producers_cast_gave(tmp_path, host_cache):
+    """A bfloat16 epoch from the native source, whose workers store the
+    dtype, is bit for bit the float32 decode converted afterwards (what the
+    producer thread did before ISSUE 36), and the PIL source's epoch up to
+    the decoders' known pixel difference plus one bfloat16 rounding."""
+    m = _jpeg_manifest(tmp_path)
+    kw = dict(batch_size=4, image_size=(128, 128), shuffle=False, drop_remainder=False,
+              image_dtype="bfloat16", decode_prescale=0)
+    loader = DataLoader(m, **kw, native_decode=True, host_cache=host_cache)
+    native_batches = list(loader.epoch(0))
+    pil_batches = list(DataLoader(m, **kw, native_decode=False).epoch(0))
+    assert len(native_batches) == len(pil_batches) == 3
+    paths = [os.path.join(m.img_dir, f) for f in m.filenames]
+    before = native.decode_batch(paths, (128, 128), MEAN, STD, prescale_margin=0).astype(BF16)
+    for b, ((ni, nl), (pi, pl)) in enumerate(zip(native_batches, pil_batches)):
+        assert ni.dtype == pi.dtype == BF16
+        np.testing.assert_array_equal(nl, pl)
+        np.testing.assert_array_equal(
+            ni.view(np.uint16), before[4 * b : 4 * b + 4].view(np.uint16)
+        )
+        # bfloat16 near 2.7 steps by 2**-7: under half a pixel at std 0.229.
+        assert _pixel_diff(ni.astype(np.float32), pi.astype(np.float32)) < 1.5 + 0.5
+    if host_cache:  # filled without a conversion, served as it was filled
+        for (ni, _), (ci, _) in zip(native_batches, loader.epoch(0)):
+            np.testing.assert_array_equal(ni.view(np.uint16), ci.view(np.uint16))
 
 
 def test_env_kill_switch():
@@ -337,6 +492,7 @@ def _traced_epoch(spans_of, loader):
     "source,kw",
     [
         ("native", dict(native_decode=True, image_dtype="bfloat16")),
+        ("native", dict(native_decode=True, image_dtype="float32")),
         ("pil", dict(native_decode=False, image_dtype="bfloat16")),
         ("pil", dict(native_decode=False, image_dtype="float32")),
     ],
@@ -364,6 +520,9 @@ def test_loader_spans_per_batch_with_their_args(tmp_path, spans_of, source, kw):
     for e in decodes:
         args = e["args"]
         assert args["images"] == 4 and args["source"] == source and args["threads"] == 3
+        # The dtype the source produced: the C decoder's workers store the
+        # batch dtype, the PIL pool float32 (ISSUE 36).
+        assert args["wrote"] == (kw["image_dtype"] if source == "native" else "float32")
         assert args["fallbacks"] == 0 and args["quarantined"] == 0
         assert 0 < args["thread_busy_s"] <= 3 * e["dur"] / 1e6 * 1.05
         assert life["ts"] <= e["ts"] and e["ts"] + e["dur"] <= life["ts"] + life["dur"]
@@ -381,8 +540,12 @@ def test_loader_spans_per_batch_with_their_args(tmp_path, spans_of, source, kw):
     # The Python paths time themselves as decode.cpp does; the C path does
     # not touch the Python counter.
     assert (loader._py_busy_ns > 0) == (source == "pil")
-    # The cast runs only where the decode's float32 is not the batch dtype.
-    assert len(spans.get("loader/cast", [])) == (3 if kw["image_dtype"] == "bfloat16" else 0)
+    # ``loader/cast`` a batch where the batch dtype is not float32, with the
+    # rows the producer thread converted itself: none on the native path.
+    casts = spans.get("loader/cast", [])
+    assert len(casts) == (3 if kw["image_dtype"] == "bfloat16" else 0)
+    assert {e["args"]["converted"] for e in casts} <= {0 if source == "native" else 4}
+    assert all(b[0].dtype == loader.image_dtype for b in batches)
     assert len(spans["loader/put"]) == 4  # three batches and the sentinel
     assert len(spans["loader/get"]) == 4
     # Producer and consumer run on two threads.
