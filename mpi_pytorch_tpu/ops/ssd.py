@@ -44,14 +44,14 @@ from jax import lax
 DECAY_DTYPE = jnp.float32
 
 
-def chunk_of(seq_len: int, chunk: int) -> int:
+def chunk_of(seq_len: int, chunk: int, op: str = "ssd", key: str = "mamba_chunk_size") -> int:
     """The chunk a sequence of ``seq_len`` runs in, or a refusal that names
-    both numbers."""
+    both numbers (and the operation and configuration key that set them)."""
     size = min(chunk, seq_len)
     if seq_len % size:
         raise ValueError(
-            f"ssd: a sequence of {seq_len} positions is not a whole number of "
-            f"chunks of {chunk} (mamba_chunk_size); it is not padded"
+            f"{op}: a sequence of {seq_len} positions is not a whole number of "
+            f"chunks of {chunk} ({key}); it is not padded"
         )
     return size
 

@@ -44,8 +44,17 @@ TINY_NEMOTRON = json.dumps({
     "moe_latent_size": 32, "moe_intermediate_size": 48, "moe_shared_expert_intermediate_size": 80,
     "num_nextn_predict_layers": 0, "vocab_size": 128,
 })
+TINY_OLMO = json.dumps({
+    "hidden_size": 64, "intermediate_size": 96, "num_attention_heads": 4, "num_key_value_heads": 4,
+    "layer_types": ["linear_attention", "full_attention"], "linear_num_key_heads": 2,
+    "linear_num_value_heads": 2, "linear_key_head_dim": 16, "linear_value_head_dim": 32,
+    "linear_chunk_size": 16, "vocab_size": 128,
+})
 # A configured model's own tiny ``--model-config`` (each reads its source's keys).
-TINY_CONFIGS = {"lfm2_moe": TINY_LFM2, "granitemoehybrid": TINY_GRANITE, "nemotron_h": TINY_NEMOTRON}
+TINY_CONFIGS = {
+    "lfm2_moe": TINY_LFM2, "granitemoehybrid": TINY_GRANITE, "nemotron_h": TINY_NEMOTRON,
+    "olmo_hybrid": TINY_OLMO,
+}
 
 # The (flag, value) -> models table of the parent commit's eleven name lists
 # (ATTN_IMPL_MODELS, SP_MODELS, MOE_MODELS, REMAT_BLOCKS_MODELS, S2D_MODELS,
@@ -53,14 +62,14 @@ TINY_CONFIGS = {"lfm2_moe": TINY_LFM2, "granitemoehybrid": TINY_GRANITE, "nemotr
 # fused-small refusal): what is accepted; every other pair is refused.
 _VITS = {"vit_s16", "vit_b16", "vit_moe_s16"}
 ACCEPTED = {
-    ("attn_impl", "flash"): _VITS | {"lfm2_moe", "granitemoehybrid", "nemotron_h"},
+    ("attn_impl", "flash"): _VITS | {"lfm2_moe", "granitemoehybrid", "nemotron_h", "olmo_hybrid"},
     ("attn_impl", "fused-small"): _VITS,
     ("sp_strategy", "ring"): _VITS,
     ("qkv_fused", True): _VITS,
     ("ep_mesh", "mesh"): {"vit_moe_s16"},
     ("remat_blocks", True): {
         "resnet18", "resnet34", "densenet121", "vit_s16", "vit_b16", "lfm2_moe",
-        "granitemoehybrid", "nemotron_h",
+        "granitemoehybrid", "nemotron_h", "olmo_hybrid",
     },
     ("stem_s2d", True): {"resnet18", "resnet34"},
     ("fused_stem", True): {"resnet18", "resnet34", "densenet121"},

@@ -285,6 +285,35 @@ def test_state_space_scan_compiles_for_v5e_at_the_benchmark_cells_shape(one_v5e)
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30  # 461 MB when written
 
 
+def test_gated_delta_rule_compiles_for_v5e_at_the_benchmark_cells_shape(one_v5e):
+    """``ops/gated_delta.gated_delta`` and its five gradients at
+    ``olmohybrid_train_hbm_8k``'s linear-attention layer (one sequence of
+    8 192 positions, 6 heads of 96 / 192, chunks of 64, bf16 q / k / v,
+    float32 beta and decays): the chunked form fits a chip as XLA einsums with
+    the one loop over the 128 chunk states, forward and backward — no loop over
+    positions, and none from the inverse's diagonal blocks (``jnp.diagonal``
+    is a gather, a loop on a TPU) — and the chunks' float32 64 x 64 triangles.
+    Nothing runs; no time comes out of this."""
+    import jax.numpy as jnp
+
+    from mpi_pytorch_tpu.ops.gated_delta import gated_delta
+
+    s, h, dk, dv = 8_192, 6, 96, 192
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+
+    def loss(q, k, v, beta, g):
+        return jnp.sum(gated_delta(q, k, v, beta, g, chunk=64).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(5)))).lower(
+        shape(1, s, h, dk, dtype=jnp.bfloat16), shape(1, s, h, dk, dtype=jnp.bfloat16),
+        shape(1, s, h, dv, dtype=jnp.bfloat16), shape(1, s, h), shape(1, s, h),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 2  # the 128 chunk states, forward and backward
+    assert "f32[128,6,64,64]" in text  # the chunks' decays and triangles, float32, all at once
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
 def test_nemotron_h_step_lowers_for_a_tpu_at_the_benchmark_cells_shapes(monkeypatch):
     """Loss and gradients of ``nemotron3s_train_hbm_8k``'s model — the
     configuration file's cut, 16 384 tokens, bf16, flash attention, per-block
