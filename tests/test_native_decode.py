@@ -7,7 +7,9 @@ workers, ``data_loader.py:29-39``; MPI preprocessing ranks,
 
 - decode parity: same libjpeg, so exact-size decode is bit-identical to PIL
 - resize parity: the separable triangle filter matches PIL's BILINEAR within
-  fixed-point rounding (<1.5/255 per pixel)
+  fixed-point rounding (<1.5/255 per pixel); its vertical pass runs first,
+  so its float32 sums run in another order than a horizontal pass first
+  would give them, and stay within 1e-3 of the same filter in float64
 - DCT prescale modes trade PIL-exactness for IDCT work, with bounded deviation
 - corrupt / non-JPEG items fall back to PIL one at a time
 - the output dtype is the caller's: a bfloat16 batch has the bits of the
@@ -80,6 +82,76 @@ def test_upscale_matches_pil(tmp_path):
     _write_jpeg(p, img)
     out = native.decode_batch([str(p)], (128, 128), MEAN, STD)
     assert _pixel_diff(out[0], _pil(p)) < 1.5
+
+
+def _triangle_weights(in_size, out_size):
+    """[out_size, in_size] float64 weights of Pillow's BILINEAR: a triangle
+    of support max(1, in/out) around each output's centre, cut at the edges
+    and normalized to sum 1."""
+    scale = in_size / out_size
+    support = max(scale, 1.0)
+    centre = (np.arange(out_size) + 0.5) * scale
+    lo = np.maximum((centre - support + 0.5).astype(np.int64), 0)
+    hi = np.minimum((centre + support + 0.5).astype(np.int64), in_size)
+    src = np.arange(in_size)
+    arg = np.abs((src[None, :] - centre[:, None] + 0.5) / support)
+    inside = (src[None, :] >= lo[:, None]) & (src[None, :] < hi[:, None])
+    w = np.where(inside, np.maximum(1.0 - arg, 0.0), 0.0)
+    return w / w.sum(axis=1, keepdims=True)
+
+
+def _decoded_pixels(path):
+    """The uint8 pixels the native decoder resizes: the full decode, through
+    its equal-size branch, with the normalization undone."""
+    with Image.open(path) as im:
+        w, h = im.size
+    out = native.decode_batch([path], (h, w), MEAN, STD, prescale_margin=0)[0]
+    pixels = (out.astype(np.float64) * STD + MEAN) * 255.0
+    assert np.abs(pixels - np.round(pixels)).max() < 1e-3
+    return np.round(pixels)
+
+
+@pytest.mark.parametrize(
+    "src_hw,out_hw",
+    [
+        ((500, 334), (128, 128)),  # the streaming cell's prescaled portrait
+        ((334, 500), (128, 128)),  # landscape
+        ((257, 131), (128, 64)),  # the last columns' taps run past the row
+        ((9, 5), (4, 2)),  # a row narrower than a column's padded taps
+        ((100, 90), (128, 128)),  # upscale: three taps
+    ],
+    ids=["portrait", "landscape", "right_edge", "narrow", "upscale"],
+)
+def test_resize_matches_the_triangle_filter_in_float64(tmp_path, src_hw, out_hw):
+    """The C resize against the same separable filter computed in float64:
+    only the order of float32 sums may differ."""
+    p = str(tmp_path / "a.jpg")
+    _write_jpeg(p, (synthetic_image(6, src_hw) * 255).astype(np.uint8))
+    pixels = _decoded_pixels(p)
+    wv = _triangle_weights(src_hw[0], out_hw[0])
+    wh = _triangle_weights(src_hw[1], out_hw[1])
+    ref = np.einsum("yi,ijc,xj->yxc", wv, pixels, wh)
+    out = native.decode_batch([p], out_hw, MEAN, STD, prescale_margin=0)[0]
+    got = (out.astype(np.float64) * STD + MEAN) * 255.0
+    assert np.abs(got - ref).max() < 1e-3
+
+
+@pytest.mark.parametrize("threads", [1, 2])
+def test_a_batch_of_mixed_source_sizes_is_each_image_decoded_alone(tmp_path, threads):
+    """A worker that meets one source size after another resizes each as if
+    it were the only one: nothing of one image's kernels or scratch leaks
+    into the next."""
+    paths = []
+    for i, hw in enumerate([(500, 334), (334, 500), (140, 140)]):
+        paths.append(str(tmp_path / f"{i}.jpg"))
+        _write_jpeg(paths[-1], (synthetic_image(i, hw) * 255).astype(np.uint8))
+    order = [0, 1, 0, 2, 1, 2, 0]
+    batch = native.decode_batch(
+        [paths[i] for i in order], (128, 128), MEAN, STD, threads=threads
+    )
+    alone = [native.decode_batch([p], (128, 128), MEAN, STD, threads=1)[0] for p in paths]
+    for row, i in zip(batch, order):
+        np.testing.assert_array_equal(row, alone[i])
 
 
 def test_prescale_margin0_full_parity_on_large_source(tmp_path):
