@@ -25,7 +25,8 @@ process over that life, and ``host_cpus``, the cores it may run on),
 — the seconds the decode workers were inside a decode —, ``wrote``, the
 dtype the source produced, and, for the native
 source, ``stage_s``: those seconds by stage of the C decoder, with
-``jpeg_scan_s``, the scanline loop's part of its ``jpeg`` stage; all from
+``jpeg_scan_s``, the scanline loop's part of its ``jpeg`` stage, and
+``opened``: the images it decoded by how their file was reached; all from
 counters kept where the decode happens and read only while a tracer records),
 ``loader/cast`` (in a loader whose batch dtype is not float32: what the
 producer thread converts itself — the PIL and pack sources' float32 batches,
@@ -348,23 +349,26 @@ class DataLoader:
         self._quarantine(i, err)
         return None
 
-    def _decode_counters(self) -> tuple[int, int, dict[str, int]]:
+    def _decode_counters(self) -> tuple[int, int, dict[str, int], dict[str, int]]:
         """``(images the C decoder refused, nanoseconds inside a decode, the
-        C decoder's nanoseconds by stage)`` so far: the native library's
-        process-wide counters (another loader decoding at the same time shows
-        in them) plus this loader's Python decodes, which have no stages (an
-        empty dict where the pixels do not come from the C decoder). A
-        batch's share is the difference around its ``_load_batch``."""
+        C decoder's nanoseconds by stage, the images it decoded by how their
+        file was reached)`` so far: the native library's process-wide
+        counters (another loader decoding at the same time shows in them)
+        plus this loader's Python decodes, which have no stages and open
+        nothing through it (empty dicts where the pixels do not come from
+        the C decoder). A batch's share is the difference around its
+        ``_load_batch``."""
         refused = busy_ns = 0
         stage_ns: dict[str, int] = {}
+        opened: dict[str, int] = {}
         if self.native_decode:
             from mpi_pytorch_tpu import native
 
-            refused, busy_ns, stage_ns = native.counters()
+            refused, busy_ns, stage_ns, opened = native.counters()
             if self._pack is not None:
-                stage_ns = {}  # a pack's rows are read, not decoded
+                stage_ns = opened = {}  # a pack's rows are read, not decoded
         with self._bad_lock:
-            return refused, busy_ns + self._py_busy_ns, stage_ns
+            return refused, busy_ns + self._py_busy_ns, stage_ns, opened
 
     @property
     def decode_source(self) -> str:
@@ -462,16 +466,19 @@ class DataLoader:
         if self.native_decode:
             from mpi_pytorch_tpu import native
 
-            paths = [
-                os.path.join(self.manifest.img_dir, self.manifest.filenames[i]) for i in idx
-            ]
-            # Items the C decoder refuses fall back per path; the fallback
-            # rides the same retry/quarantine discipline as the PIL pool
-            # (a quarantined item returns a zero image — its label is
-            # masked by _masked_labels, so the content never trains).
+            # The names stay relative to ``img_dir``: the C decoder opens
+            # the root once and each directory the batch's names share, and
+            # an image by its last component, where a whole path would be
+            # walked component by component for every image.
+            img_dir = self.manifest.img_dir
+            names = [self.manifest.filenames[i] for i in idx]
+            # Items the C decoder refuses fall back per path (the joined
+            # one); the fallback rides the same retry/quarantine discipline
+            # as the PIL pool (a quarantined item returns a zero image — its
+            # label is masked by _masked_labels, so the content never trains).
             row_of = {}
-            for k, p in enumerate(paths):
-                row_of.setdefault(p, int(idx[k]))
+            for k, name in enumerate(names):
+                row_of.setdefault(os.path.join(img_dir, name), int(idx[k]))
 
             def robust_fallback(p):
                 img = self._decode_with_retries(row_of[p])
@@ -480,10 +487,11 @@ class DataLoader:
                 return img
 
             return native.decode_batch(
-                paths,
+                names,
                 self.image_size,
                 _MEAN,
                 _STD,
+                root=img_dir,
                 threads=self.num_workers,
                 prescale_margin=self.decode_prescale,
                 fallback=robust_fallback,
@@ -626,13 +634,13 @@ class DataLoader:
                 "threads": self.num_workers,
             }
             with tracer.span("loader/decode", args=args):
-                refused, busy_ns, stage_ns = self._decode_counters()
+                refused, busy_ns, stage_ns, opened = self._decode_counters()
                 quarantined = self.bad_samples
                 t0 = time.perf_counter()
                 stacked = self._load_batch(idx, pool)
                 call_s = time.perf_counter() - t0
                 args["wrote"] = str(stacked.dtype)
-                refused_now, busy_ns_now, stage_ns_now = self._decode_counters()
+                refused_now, busy_ns_now, stage_ns_now, opened_now = self._decode_counters()
                 args["fallbacks"] = refused_now - refused
                 args["quarantined"] = self.bad_samples - quarantined
                 # A pack is read by this one thread: its busy time is the
@@ -647,6 +655,10 @@ class DataLoader:
                     took = {k: (ns - stage_ns[k]) / 1e9 for k, ns in stage_ns_now.items()}
                     args["jpeg_scan_s"] = took.pop("jpeg_scan")
                     args["stage_s"] = took
+                if opened_now:
+                    # How the decoded images' files were reached: whether the
+                    # directories the call held served the batch.
+                    args["opened"] = {k: n - opened[k] for k, n in opened_now.items()}
             return stacked
 
         # ``loader/cast`` in every batch of a loader whose floats are not

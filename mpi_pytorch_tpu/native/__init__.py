@@ -36,13 +36,15 @@ import numpy as np
 _SRC = os.path.join(os.path.dirname(__file__), "decode.cpp")
 _LIB_PREFIX = "_mptnative_"
 
-# What decode.cpp's ``mpt_abi_version`` must answer, and the names of the
+# What decode.cpp's ``mpt_abi_version`` must answer; the names of the
 # nanoseconds ``mpt_decode_counters`` writes after its first two values: the
 # four stages of an image, which are its busy time, then ``jpeg_scan``, the
-# scanline loop inside ``jpeg``.
-_ABI_VERSION = 5
+# scanline loop inside ``jpeg``; and the names of the image counts it writes
+# last, by how each decoded image's file was reached (its ``Opened``).
+_ABI_VERSION = 6
 STAGES = ("file", "jpeg", "resize", "normalize")
 _COUNTER_NAMES = STAGES + ("jpeg_scan",)
+OPENED = ("dir", "root", "path")
 
 
 def _elem(dtype) -> int:
@@ -150,6 +152,7 @@ def _try_load() -> ctypes.CDLL | None:
             continue
         lib.mpt_decode_batch.restype = ctypes.c_int
         lib.mpt_decode_batch.argtypes = [
+            ctypes.c_char_p,
             ctypes.POINTER(ctypes.c_char_p),
             ctypes.c_int,
             ctypes.c_int,
@@ -211,22 +214,28 @@ def build_error() -> str | None:
     return _build_error
 
 
-def counters() -> tuple[int, int, dict[str, int]]:
+def counters() -> tuple[int, int, dict[str, int], dict[str, int]]:
     """``(images refused, nanoseconds the worker threads spent inside a
-    decode, those nanoseconds by name)`` over every ``decode_batch`` call of
-    this process so far — seven values kept where the decode happens (atomics
-    in decode.cpp). The names are ``STAGES`` (read the file, libjpeg, the
-    resize, the normalize pass), which partition the busy time — a refused or
-    failed image leaves what it spent on the stage it stopped in — and then
-    ``jpeg_scan``, the scanline loop's share of ``jpeg``. A caller reads
-    before and after a call and takes the differences; zeros when the
-    library is unavailable."""
+    decode, those nanoseconds by name, images decoded by how their file was
+    reached)`` over every ``decode_batch`` call of this process so far — ten
+    values kept where the decode happens (atomics in decode.cpp). The names
+    are ``STAGES`` (read the file, libjpeg, the resize, the normalize pass),
+    which partition the busy time — a refused or failed image leaves what it
+    spent on the stage it stopped in — and then ``jpeg_scan``, the scanline
+    loop's share of ``jpeg``. The counts are by ``OPENED``: ``dir`` (by its
+    last component from a directory the call held), ``root`` (from the
+    call's ``root``), ``path`` (by the whole name). A caller reads before
+    and after a call and takes the differences; zeros when the library is
+    unavailable."""
     lib = load()
     if lib is None:
-        return 0, 0, dict.fromkeys(_COUNTER_NAMES, 0)
-    out = (ctypes.c_longlong * (2 + len(_COUNTER_NAMES)))()
+        return 0, 0, dict.fromkeys(_COUNTER_NAMES, 0), dict.fromkeys(OPENED, 0)
+    out = (ctypes.c_longlong * (2 + len(_COUNTER_NAMES) + len(OPENED)))()
     lib.mpt_decode_counters(out)
-    return int(out[0]), int(out[1]), dict(zip(_COUNTER_NAMES, map(int, out[2:])))
+    values = list(map(int, out))
+    stages = values[2 : 2 + len(_COUNTER_NAMES)]
+    opened = values[2 + len(_COUNTER_NAMES) :]
+    return values[0], values[1], dict(zip(_COUNTER_NAMES, stages)), dict(zip(OPENED, opened))
 
 
 def decode_batch(
@@ -235,6 +244,7 @@ def decode_batch(
     mean: np.ndarray,
     std: np.ndarray,
     *,
+    root: str | None = None,
     threads: int = 8,
     prescale_margin: int = 2,
     fallback=None,
@@ -246,10 +256,19 @@ def decode_batch(
     is float32 or ``ml_dtypes.bfloat16``: the worker threads store it in
     their normalize pass (bfloat16 rounded to nearest, ties to even — the
     bits ``astype`` would give), so the caller converts nothing afterwards.
-    Items the native path refuses (corrupt file, CMYK, ...) are retried
-    through ``fallback(path) -> normalized HWC f32`` (e.g. the PIL path;
-    numpy converts that one row) so odd files degrade one at a time instead
-    of failing the batch.
+
+    With ``root``, ``paths`` are names relative to it (an absolute one
+    stands as it is, as ``os.path.join`` treats it): the call opens the root
+    once and each directory that two or more of the names share, and opens
+    an image by its last component from the directory it holds, so the
+    kernel does not walk the whole path once an image. Without, each path
+    is opened as it stands.
+
+    Items the native path refuses (unreadable or corrupt file, CMYK, ...)
+    are retried through ``fallback(path) -> normalized HWC f32`` (e.g. the
+    PIL path; numpy converts that one row) so odd files degrade one at a
+    time instead of failing the batch; ``path`` is ``os.path.join(root,
+    name)`` where a root was given.
 
     ``prescale_margin`` controls libjpeg DCT prescaling for large sources:
     0 = full-resolution decode (PIL bit-parity, slowest), 1 = decode just past
@@ -268,7 +287,10 @@ def decode_batch(
     std32 = np.ascontiguousarray(std, dtype=np.float32)
     encoded = [os.fsencode(p) for p in paths]
     c_paths = (ctypes.c_char_p * n)(*encoded)
+    # os.path.join("", name) is name, from the working directory: so is ".".
+    c_root = None if root is None else os.fsencode(root or ".")
     failures = lib.mpt_decode_batch(
+        c_root,
         c_paths,
         n,
         h,
@@ -283,11 +305,12 @@ def decode_batch(
     )
     if failures:
         bad = np.nonzero(statuses)[0]
+        joined = [paths[i] if root is None else os.path.join(root, paths[i]) for i in bad]
         if fallback is None:
             raise RuntimeError(
-                f"native decode failed for {len(bad)} item(s), e.g. {paths[bad[0]]!r} "
+                f"native decode failed for {len(bad)} item(s), e.g. {joined[0]!r} "
                 f"(status {statuses[bad[0]]})"
             )
-        for i in bad:
-            out[i] = fallback(paths[i])
+        for i, path in zip(bad, joined):
+            out[i] = fallback(path)
     return out

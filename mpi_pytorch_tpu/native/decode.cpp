@@ -24,22 +24,35 @@
 // pass first, summed in another order (within 1e-4 of a float64 filter on
 // the 0-255 scale).
 //
+// A batch call may name a root directory, the batch's names then relative to
+// it. The call opens the root once, and once more each directory that two or
+// more of the batch's names share, and a worker opens an image by its last
+// component from the directory held for it: the kernel walks one path
+// component an image, not the whole path, whose every component costs tens
+// of microseconds on some hosts. The bytes read are the same file's either
+// way.
+//
 // C ABI only (no pybind11 in this image); consumed via ctypes from
 // mpi_pytorch_tpu/native/__init__.py.
 
 #include <cstddef>  // jpeglib.h uses size_t/FILE without including them
 #include <cstdio>
 
+#include <fcntl.h>
 #include <jpeglib.h>
+#include <sys/stat.h>
+#include <unistd.h>
 
 #include <algorithm>
 #include <atomic>
+#include <cerrno>
 #include <chrono>
 #include <cmath>
 #include <csetjmp>
 #include <cstdint>
-#include <cstdio>
 #include <cstring>
+#include <string>
+#include <string_view>
 #include <thread>
 #include <vector>
 
@@ -73,7 +86,8 @@ void on_output_message(j_common_ptr) {}  // swallow warnings
 using Clock = std::chrono::steady_clock;
 
 enum Lap {
-  LAP_FILE = 0,   // fopen .. fclose: the encoded bytes into memory
+  LAP_FILE = 0,   // openat from the directory held for the image .. close:
+                  // the encoded bytes into memory
   LAP_JPEG_HEAD,  // jpeg_create_decompress, the header, jpeg_start_decompress
   LAP_JPEG_SCAN,  // the scanline loop (entropy decode, scaled IDCT, colour
                   // conversion) .. jpeg_destroy_decompress
@@ -363,23 +377,107 @@ int decode_buffer(const uint8_t* buf, size_t len, int out_h, int out_w,
   return OK;
 }
 
-int decode_file(const char* path, int out_h, int out_w, const float* mean,
+// How an image's file was reached (mirrored in native/__init__.py).
+enum Opened {
+  OPENED_DIR = 0,  // by its last component, from a leaf directory the call holds
+  OPENED_ROOT,     // by its name, from the call's root
+  OPENED_PATH,     // by the whole name: no root, or an absolute name
+  N_OPENED,
+};
+
+// Names of a batch in one directory from which the call holds the directory.
+constexpr int HOLD_FROM = 2;
+
+// A descriptor the batch call owns: closed when the call returns, whichever
+// way it returns.
+struct OwnedFd {
+  int fd;
+  explicit OwnedFd(int f) : fd(f) {}
+  OwnedFd(OwnedFd&& o) noexcept : fd(o.fd) { o.fd = -1; }
+  OwnedFd(const OwnedFd&) = delete;
+  OwnedFd& operator=(const OwnedFd&) = delete;
+  ~OwnedFd() {
+    if (fd >= 0) close(fd);
+  }
+};
+
+// Where an item's file is: openat(dirfd, name).
+struct FileRef {
+  int dirfd;
+  const char* name;
+  int opened;  // an Opened
+};
+
+// Each name's FileRef. Without a root a name is opened as it stands (openat
+// from AT_FDCWD is open). With one, a relative name is opened from the root,
+// or by its last component from its directory where HOLD_FROM or more names
+// share that directory; an absolute name as it stands, as os.path.join
+// treats it. A root that cannot be opened fails its relative names
+// (openat(-1, ...) is EBADF); a leaf that cannot be opened leaves its names
+// to the root. Every descriptor opened lands in `held`. (Grouped by a sort,
+// not a hash map: std::unordered_map's operator[] can leave a GNU-unique
+// symbol in the library, and dlclose then never unmaps it.)
+std::vector<FileRef> plan_files(const char* root, const char* const* names,
+                                int n, std::vector<OwnedFd>& held) {
+  std::vector<FileRef> files(n);
+  int root_fd = -1;
+  if (root != nullptr) {
+    held.emplace_back(open(root, O_RDONLY | O_DIRECTORY | O_CLOEXEC));
+    root_fd = held.back().fd;
+  }
+  // The relative names with a directory part: (that directory, item).
+  std::vector<std::pair<std::string_view, int>> by_dir;
+  for (int i = 0; i < n; ++i) {
+    const char* name = names[i];
+    if (root == nullptr || name[0] == '/') {
+      files[i] = {AT_FDCWD, name, OPENED_PATH};
+      continue;
+    }
+    files[i] = {root_fd, name, OPENED_ROOT};
+    if (const char* slash = std::strrchr(name, '/')) {
+      by_dir.emplace_back(std::string_view(name, slash - name), i);
+    }
+  }
+  if (root_fd < 0) return files;
+  std::sort(by_dir.begin(), by_dir.end());
+  for (size_t lo = 0, hi; lo < by_dir.size(); lo = hi) {
+    const std::string_view dir = by_dir[lo].first;
+    for (hi = lo + 1; hi < by_dir.size() && by_dir[hi].first == dir; ++hi) {
+    }
+    if (hi - lo < HOLD_FROM) continue;
+    held.emplace_back(
+        openat(root_fd, std::string(dir).c_str(), O_RDONLY | O_DIRECTORY | O_CLOEXEC));
+    const int leaf_fd = held.back().fd;
+    if (leaf_fd < 0) continue;
+    for (size_t k = lo; k < hi; ++k) {
+      FileRef& file = files[by_dir[k].second];
+      file = {leaf_fd, file.name + dir.size() + 1, OPENED_DIR};
+    }
+  }
+  return files;
+}
+
+int decode_file(const FileRef& file, int out_h, int out_w, const float* mean,
                 const float* stdv, void* out, int elem, int prescale_margin,
                 Scratch& scratch, LapClock& clock) {
   std::vector<uint8_t>& filebuf = scratch.filebuf;
-  FILE* f = std::fopen(path, "rb");
-  if (!f) return ERR_OPEN;
-  std::fseek(f, 0, SEEK_END);
-  const long sz = std::ftell(f);
-  std::fseek(f, 0, SEEK_SET);
-  if (sz <= 0) {
-    std::fclose(f);
+  const int fd = openat(file.dirfd, file.name, O_RDONLY | O_CLOEXEC);
+  if (fd < 0) return ERR_OPEN;
+  struct stat st;
+  if (fstat(fd, &st) != 0 || st.st_size <= 0) {
+    close(fd);
     return ERR_OPEN;
   }
-  filebuf.resize(static_cast<size_t>(sz));
-  const size_t got = std::fread(filebuf.data(), 1, filebuf.size(), f);
-  std::fclose(f);
-  if (got != filebuf.size()) return ERR_OPEN;
+  filebuf.resize(static_cast<size_t>(st.st_size));
+  size_t got = 0;
+  while (got < filebuf.size()) {
+    const ssize_t r = read(fd, filebuf.data() + got, filebuf.size() - got);
+    if (r < 0 && errno == EINTR) continue;
+    if (r <= 0) break;
+    got += static_cast<size_t>(r);
+  }
+  close(fd);
+  if (got != filebuf.size()) return ERR_OPEN;  // short or unreadable
   return decode_buffer(filebuf.data(), filebuf.size(), out_h, out_w, mean, stdv,
                        out, elem, prescale_margin, scratch, clock);
 }
@@ -392,10 +490,12 @@ int decode_file(const char* path, int out_h, int out_w, const float* mean,
 // says whether N workers were N cores' worth of work); and those nanoseconds
 // by lap -> `stage_s` (which of file, jpeg, resize, normalize an image's
 // milliseconds go to) and `jpeg_scan_s` (how much of jpeg is its scanline
-// loop). Always on: six clock reads and five relaxed adds an image (~0.15 us)
-// against milliseconds of decode.
+// loop); and the images decoded by how their file was reached -> `opened`
+// (whether the held directories serve the batch). Always on: six clock reads
+// and six relaxed adds an image (~0.15 us) against milliseconds of decode.
 std::atomic<long long> g_refused(0);
 std::atomic<long long> g_lap_ns[N_LAPS];  // zero-initialized (static storage)
+std::atomic<long long> g_opened[N_OPENED];
 
 }  // namespace
 
@@ -418,16 +518,19 @@ int mpt_decode_one(const uint8_t* buf, size_t len, int out_h, int out_w,
 }
 
 // Decode n files into out[n*out_h*out_w*3] of out_elem (an Elem: the stride
-// follows it) on n_threads C++ threads. statuses[i] receives a Status per
-// item; failed items leave zeros for the caller's PIL fallback. Returns the
-// number of failed items, -1 for an element type it does not know. The GIL is
-// released for the whole call (ctypes).
-int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
-                     const float* mean, const float* stdv, void* out,
+// follows it) on n_threads C++ threads. names[i] is relative to root, or a
+// path as it stands where root is null (plan_files). statuses[i] receives a
+// Status per item; failed items leave zeros for the caller's PIL fallback.
+// Returns the number of failed items, -1 for an element type it does not
+// know. The GIL is released for the whole call (ctypes).
+int mpt_decode_batch(const char* root, const char** names, int n, int out_h,
+                     int out_w, const float* mean, const float* stdv, void* out,
                      int out_elem, int n_threads, int prescale_margin,
                      int* statuses) {
   if (elem_size(out_elem) == 0) return -1;
   if (n <= 0) return 0;
+  std::vector<OwnedFd> held;  // the root and the leaves, closed on return
+  const std::vector<FileRef> files = plan_files(root, names, n, held);
   if (n_threads < 1) n_threads = 1;
   if (n_threads > n) n_threads = n;
   const size_t stride =
@@ -443,7 +546,7 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
       int st;
       LapClock clock;
       try {
-        st = decode_file(paths[i], out_h, out_w, mean, stdv, rows + stride * i,
+        st = decode_file(files[i], out_h, out_w, mean, stdv, rows + stride * i,
                          out_elem, prescale_margin, scratch, clock);
       } catch (...) {
         // e.g. std::bad_alloc from a header declaring absurd dimensions
@@ -456,7 +559,9 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
         g_lap_ns[s].fetch_add(clock.ns[s], std::memory_order_relaxed);
       }
       statuses[i] = st;
-      if (st != OK) {
+      if (st == OK) {
+        g_opened[files[i].opened].fetch_add(1, std::memory_order_relaxed);
+      } else {
         // A failed decode may have partially written its slot; zero it so
         // the documented contract (failed items leave zeros) holds even for
         // callers that skip the per-item fallback. (All-zero bytes are 0.0 in
@@ -478,23 +583,27 @@ int mpt_decode_batch(const char** paths, int n, int out_h, int out_w,
   return failures.load();
 }
 
-// out7 = {images refused, nanoseconds inside a decode, then those nanoseconds
-// by stage: file, jpeg, resize, normalize (they sum to the second value),
-// then the part of jpeg inside its scanline loop}, all since the library was
-// loaded, over every mpt_decode_batch call.
-void mpt_decode_counters(long long* out7) {
+// out10 = {images refused, nanoseconds inside a decode, then those
+// nanoseconds by stage: file, jpeg, resize, normalize (they sum to the second
+// value), then the part of jpeg inside its scanline loop, then the images
+// decoded by how their file was reached: dir, root, path}, all since the
+// library was loaded, over every mpt_decode_batch call.
+void mpt_decode_counters(long long* out10) {
   long long lap[N_LAPS], busy = 0;
   for (int s = 0; s < N_LAPS; ++s) {
     lap[s] = g_lap_ns[s].load(std::memory_order_relaxed);
     busy += lap[s];
   }
-  out7[0] = g_refused.load(std::memory_order_relaxed);
-  out7[1] = busy;
-  out7[2] = lap[LAP_FILE];
-  out7[3] = lap[LAP_JPEG_HEAD] + lap[LAP_JPEG_SCAN];
-  out7[4] = lap[LAP_RESIZE];
-  out7[5] = lap[LAP_NORMALIZE];
-  out7[6] = lap[LAP_JPEG_SCAN];
+  out10[0] = g_refused.load(std::memory_order_relaxed);
+  out10[1] = busy;
+  out10[2] = lap[LAP_FILE];
+  out10[3] = lap[LAP_JPEG_HEAD] + lap[LAP_JPEG_SCAN];
+  out10[4] = lap[LAP_RESIZE];
+  out10[5] = lap[LAP_NORMALIZE];
+  out10[6] = lap[LAP_JPEG_SCAN];
+  for (int k = 0; k < N_OPENED; ++k) {
+    out10[7 + k] = g_opened[k].load(std::memory_order_relaxed);
+  }
 }
 
 // The normalize pass alone, as the decoder runs it: src[n_pixels*3] float32 in
@@ -506,6 +615,6 @@ int mpt_normalize_store(const float* src, int n_pixels, const float* mean,
   return 0;
 }
 
-int mpt_abi_version() { return 5; }
+int mpt_abi_version() { return 6; }
 
 }  // extern "C"

@@ -209,12 +209,15 @@ def test_missing_file_raises_without_fallback(tmp_path):
         native.decode_batch([str(tmp_path / "nope.jpg")], (128, 128), MEAN, STD)
 
 
-def _jpeg_manifest(tmp_path, n=12):
+def _jpeg_manifest(tmp_path, n=12, dirs=0):
+    """``n`` JPEGs under ``img_dir``: at its top, or spread over ``dirs``
+    directories ``images/NNN/`` as the benchmark's dataset lays them out."""
     img_dir = tmp_path / "img"
     img_dir.mkdir()
     names, labels = [], []
     for i in range(n):
-        name = f"im_{i}.jpg"
+        name = f"images/{i % dirs:03d}/im_{i}.jpg" if dirs else f"im_{i}.jpg"
+        (img_dir / name).parent.mkdir(parents=True, exist_ok=True)
         _write_jpeg(img_dir / name, (synthetic_image(i % 3, (160, 140)) * 255).astype(np.uint8))
         names.append(name)
         labels.append(i % 3)
@@ -369,7 +372,7 @@ def test_corrupt_item_in_a_batch_of_either_dtype(tmp_path, dtype):
     statuses = np.zeros(3, np.int32)
     c_paths = (ctypes.c_char_p * 3)(*[os.fsencode(p) for p in paths])
     failures = native.load().mpt_decode_batch(
-        c_paths, 3, 64, 64, _float_ptr(MEAN), _float_ptr(STD),
+        None, c_paths, 3, 64, 64, _float_ptr(MEAN), _float_ptr(STD),
         raw.ctypes.data_as(ctypes.c_void_p), native._elem(dtype), 2, 2,
         statuses.ctypes.data_as(ctypes.POINTER(ctypes.c_int)),
     )
@@ -447,6 +450,128 @@ def test_library_name_is_keyed_on_the_source_hash(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# names relative to a root: opened from the directories the call holds
+# ---------------------------------------------------------------------------
+
+
+def _tree(tmp_path):
+    """``(root, names)``: a root with two directories that several names
+    share (one two levels down), one that holds a single name, and a name
+    at the top — each kind of open a rooted call makes."""
+    root = tmp_path / "root"
+    names = ["a/0.jpg", "b/c/1.jpg", "a/2.jpg", "d/3.jpg", "b/c/4.jpg", "a/5.jpg", "6.jpg"]
+    for k, name in enumerate(names):
+        (root / name).parent.mkdir(parents=True, exist_ok=True)
+        _write_jpeg(root / name, (synthetic_image(k, (90 + 7 * k, 140)) * 255).astype(np.uint8))
+    return str(root), names
+
+
+def _open_fds():
+    return len(os.listdir("/proc/self/fd"))
+
+
+@pytest.mark.parametrize("dtype", [np.dtype(np.float32), BF16], ids=str)
+def test_root_and_relative_names_decode_the_bits_of_whole_paths(tmp_path, dtype):
+    root, names = _tree(tmp_path)
+    paths = [os.path.join(root, name) for name in names]
+    whole = native.decode_batch(paths, (64, 64), MEAN, STD, threads=3, dtype=dtype)
+    rooted = native.decode_batch(names, (64, 64), MEAN, STD, root=root, threads=3, dtype=dtype)
+    assert rooted.dtype == dtype and np.abs(whole.astype(np.float32)).max() > 0.5
+    view = np.uint32 if dtype == np.float32 else np.uint16
+    np.testing.assert_array_equal(rooted.view(view), whole.view(view))
+
+
+@pytest.mark.parametrize("threads", [1, 3])
+def test_opened_counts_follow_the_batch_directories(tmp_path, threads):
+    """Names in a directory another name of the batch shares are opened
+    from it (``dir``), a lone one and a name at the top from the root
+    (``root``); without a root every name is a whole path (``path``). The
+    counts sum to the images decoded: a refused one is not counted."""
+    root, names = _tree(tmp_path)
+    (tmp_path / "root" / "a" / "bad.jpg").write_bytes(b"this is not a jpeg")
+    names = names + ["a/bad.jpg"]
+
+    def opened(**kw):
+        before = native.counters()[3]
+        native.decode_batch(
+            names if "root" in kw else [os.path.join(root, n) for n in names], (32, 32),
+            MEAN, STD, threads=threads, fallback=lambda p: np.zeros((32, 32, 3), np.float32),
+            **kw,
+        )
+        return {k: v - before[k] for k, v in native.counters()[3].items()}
+
+    assert native.OPENED == ("dir", "root", "path")
+    assert opened(root=root) == {"dir": 5, "root": 2, "path": 0}
+    assert opened() == {"dir": 0, "root": 0, "path": 7}
+
+
+def test_an_absolute_name_in_a_rooted_call_decodes_as_itself(tmp_path):
+    root, names = _tree(tmp_path)
+    elsewhere = _source_jpeg(tmp_path, "exact")
+    assert os.path.isabs(elsewhere) and not elsewhere.startswith(root)
+    before = native.counters()[3]
+    out = native.decode_batch([names[0], elsewhere, names[2]], (64, 64), MEAN, STD, root=root)
+    took = {k: v - before[k] for k, v in native.counters()[3].items()}
+    np.testing.assert_array_equal(out[1], native.decode_batch([elsewhere], (64, 64), MEAN, STD)[0])
+    assert took == {"dir": 2, "root": 0, "path": 1}
+
+
+@pytest.mark.parametrize(
+    "case", ["missing_name", "missing_shared_dir", "missing_root", "root_is_a_file"]
+)
+def test_what_a_rooted_call_cannot_open_falls_back_by_its_joined_path(tmp_path, case):
+    """An item whose file cannot be opened goes to the fallback with
+    ``os.path.join(root, name)``, whatever could not be opened: the name,
+    the directory it shares with another name (the root then tries it), or
+    the root itself (every relative name fails). The rest decode as whole
+    paths do, and the call leaves no descriptor open."""
+    root, names = _tree(tmp_path)
+    if case == "missing_name":
+        names = names + ["a/nope.jpg"]
+    elif case == "missing_shared_dir":
+        names = names + ["nodir/x.jpg", "nodir/y.jpg"]
+    elif case == "missing_root":
+        root = str(tmp_path / "no-such-root")
+    else:
+        root = os.path.join(root, "6.jpg")
+    expected = native.decode_batch(
+        [os.path.join(str(tmp_path / "root"), n) for n in names], (32, 32), MEAN, STD,
+        fallback=lambda p: np.full((32, 32, 3), -1.0, np.float32),
+    )
+    calls = []
+
+    def fallback(path):
+        calls.append(path)
+        return np.full((32, 32, 3), -1.0, np.float32)
+
+    fds = _open_fds()
+    out = native.decode_batch(names, (32, 32), MEAN, STD, root=root, threads=3, fallback=fallback)
+    assert _open_fds() == fds
+    failed = {
+        "missing_name": ["a/nope.jpg"],
+        "missing_shared_dir": ["nodir/x.jpg", "nodir/y.jpg"],
+    }.get(case, names)
+    assert sorted(calls) == sorted(os.path.join(root, n) for n in failed)
+    if case in ("missing_name", "missing_shared_dir"):
+        np.testing.assert_array_equal(out, expected)
+    else:
+        assert np.all(out == -1.0)
+
+
+def test_the_stage_and_open_counters_stay_apart(tmp_path):
+    """The open counts are images, not nanoseconds: they sit beside the
+    stages, and a rooted batch's four stages still sum to its busy time."""
+    root, names = _tree(tmp_path)
+    fds = _open_fds()
+    _, busy_ns, stage_ns, _ = _counters_around(
+        lambda: native.decode_batch(names, (32, 32), MEAN, STD, root=root, threads=2)
+    )
+    assert _open_fds() == fds
+    assert list(stage_ns) == list(native.STAGES)
+    assert abs(sum(stage_ns.values()) - busy_ns) <= 0.01 * busy_ns + 5_000 * len(names)
+
+
+# ---------------------------------------------------------------------------
 # the counter where the decode happens, and the loader's spans (ISSUE 24)
 # ---------------------------------------------------------------------------
 
@@ -475,10 +600,11 @@ def test_native_counter_counts_refusals_and_busy_time(tmp_path):
 
 
 def _counters_around(call):
-    """``(refused, busy_ns, {stage: ns})`` that ``call()`` added."""
-    refused, busy_ns, stage_ns = native.counters()
+    """``(refused, busy_ns, {stage: ns}, jpeg_scan ns)`` that ``call()``
+    added."""
+    refused, busy_ns, stage_ns, _ = native.counters()
     call()
-    refused_now, busy_ns_now, stage_ns_now = native.counters()
+    refused_now, busy_ns_now, stage_ns_now, _ = native.counters()
     assert native.STAGES == ("file", "jpeg", "resize", "normalize")
     assert list(stage_ns_now) == [*native.STAGES, "jpeg_scan"]
     took = {k: stage_ns_now[k] - stage_ns[k] for k in stage_ns_now}
@@ -527,31 +653,34 @@ def test_refused_image_leaves_its_time_on_the_stage_it_stopped_in(tmp_path):
 
 def test_library_of_the_old_abi_is_rebuilt_once(tmp_path, monkeypatch):
     """A cached library that loads but answers another ABI version (3: two
-    counters where this source writes seven) is unmapped, deleted and rebuilt
-    from the source — never skipped, and never answered by the stale mapping
-    dlopen keeps for a path it has already loaded."""
+    counters where this source writes ten; 5: seven, and no root before the
+    names) is unmapped, deleted and rebuilt from the source — never skipped,
+    and never answered by the stale mapping dlopen keeps for a path it has
+    already loaded."""
     import subprocess
 
     with open(native._SRC) as f:
         src = f.read()
     current = f"int mpt_abi_version() {{ return {native._ABI_VERSION}; }}"
     assert current in src
-    old_src = tmp_path / "old.cpp"
-    old_src.write_text(src.replace(current, "int mpt_abi_version() { return 3; }"))
-    cached = str(tmp_path / native._lib_name())
-    subprocess.run(
-        ["g++", "-O0", "-shared", "-fPIC", "-std=c++17", str(old_src), "-o", cached,
-         "-ljpeg", "-pthread"],
-        check=True, capture_output=True, timeout=120,
-    )
-    monkeypatch.setattr(native, "_candidate_paths", lambda name: [cached])
-    monkeypatch.setattr(native, "_build_error", None)
-    lib = native._try_load()
-    assert lib is not None, native._build_error
-    assert native._abi_version(lib) == native._ABI_VERSION
-    out = (native.ctypes.c_longlong * 7)()
-    lib.mpt_decode_counters(out)  # seven values, not two: no write past the end
-    assert list(out) == [0] * 7
+    for old in (3, 5):
+        old_src = tmp_path / f"old{old}.cpp"
+        old_src.write_text(src.replace(current, f"int mpt_abi_version() {{ return {old}; }}"))
+        os.makedirs(tmp_path / str(old))
+        cached = str(tmp_path / str(old) / native._lib_name())
+        subprocess.run(
+            ["g++", "-O0", "-shared", "-fPIC", "-std=c++17", str(old_src), "-o", cached,
+             "-ljpeg", "-pthread"],
+            check=True, capture_output=True, timeout=120,
+        )
+        monkeypatch.setattr(native, "_candidate_paths", lambda name: [cached])
+        monkeypatch.setattr(native, "_build_error", None)
+        lib = native._try_load()
+        assert lib is not None, native._build_error
+        assert native._abi_version(lib) == native._ABI_VERSION == 6
+        out = (native.ctypes.c_longlong * 10)()
+        lib.mpt_decode_counters(out)  # ten values, not two or seven: no write past the end
+        assert list(out) == [0] * 10
 
 
 def _traced_epoch(spans_of, loader):
@@ -607,8 +736,11 @@ def test_loader_spans_per_batch_with_their_args(tmp_path, spans_of, source, kw):
                 args["thread_busy_s"], rel=0.01, abs=5e-6 * args["images"]
             )
             assert 0 < args["jpeg_scan_s"] < args["stage_s"]["jpeg"]
+            # How the files were reached: every name sits at img_dir's top.
+            assert args["opened"] == {"dir": 0, "root": 4, "path": 0}
         else:
             assert "stage_s" not in args and "jpeg_scan_s" not in args
+            assert "opened" not in args
     # The Python paths time themselves as decode.cpp does; the C path does
     # not touch the Python counter.
     assert (loader._py_busy_ns > 0) == (source == "pil")
@@ -622,6 +754,30 @@ def test_loader_spans_per_batch_with_their_args(tmp_path, spans_of, source, kw):
     assert len(spans["loader/get"]) == 4
     # Producer and consumer run on two threads.
     assert {e["tid"] for e in spans["loader/put"]} != {e["tid"] for e in spans["loader/get"]}
+
+
+def test_loader_opens_a_batch_from_the_directories_its_names_share(tmp_path, spans_of):
+    """The loader hands the C decoder ``img_dir`` and the manifest's names
+    as they are: a batch whose names share their directories is opened from
+    them (``opened.dir`` = its images), gives the bits the whole paths gave,
+    and ``stage_s`` keeps exactly the four stages."""
+    m = _jpeg_manifest(tmp_path, n=12, dirs=2)
+    loader = DataLoader(
+        m, batch_size=4, image_size=(64, 64), shuffle=False, num_workers=3,
+        native_decode=True, image_dtype="bfloat16",
+    )
+    batches, spans = _traced_epoch(spans_of, loader)
+    paths = [os.path.join(m.img_dir, f) for f in m.filenames]
+    whole = native.decode_batch(paths, (64, 64), MEAN, STD, dtype=BF16)
+    for b, (images, _) in enumerate(batches):
+        np.testing.assert_array_equal(
+            images.view(np.uint16), whole[4 * b : 4 * b + 4].view(np.uint16)
+        )
+    decodes = spans["loader/decode"]
+    assert len(decodes) == 3
+    for e in decodes:
+        assert e["args"]["opened"] == {"dir": 4, "root": 0, "path": 0}
+        assert list(e["args"]["stage_s"]) == ["file", "jpeg", "resize", "normalize"]
 
 
 def test_loader_decode_span_counts_fallbacks_and_quarantines(tmp_path, spans_of):
